@@ -58,6 +58,33 @@ def write_bench_artifact(name: str, rows: List[Dict],
     return path
 
 
+def run_multidevice(module: str, n_devices: int, args: List[str],
+                    measure) -> List[Dict]:
+    """Rows of a benchmark that needs several devices.
+
+    On an accelerator ``measure()`` runs in this process over
+    ``jax.devices()``: the process holds the chips, and a child could not
+    get them. On the CPU, ``module`` is re-run in a child with
+    ``n_devices`` forced host devices (forcing must precede JAX's
+    initialization, which this process may already have done), called as
+    ``python -m <module> --emit <json> <args>``; its rows are read back."""
+    import subprocess
+    import sys
+    import tempfile
+    if jax.devices()[0].platform != "cpu":
+        return measure()
+    env = dict(os.environ)
+    force = f"--xla_force_host_platform_device_count={n_devices}"
+    if "xla_force_host_platform_device_count" not in env.get("XLA_FLAGS", ""):
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + force).strip()
+    with tempfile.NamedTemporaryFile("r", suffix=".json") as f:
+        subprocess.run([sys.executable, "-m", module, "--emit", f.name]
+                       + list(args), env=env, check=True,
+                       cwd=os.path.dirname(os.path.dirname(
+                           os.path.abspath(__file__))))
+        return json.load(f)
+
+
 @dataclass
 class BenchSetting:
     n_clients: int = 40          # paper: 100 (scaled for CPU wall-time;

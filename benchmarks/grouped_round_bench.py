@@ -28,10 +28,7 @@ like every other tracked artifact); ``... full`` writes
 from __future__ import annotations
 
 import json
-import os
-import subprocess
 import sys
-import tempfile
 import time
 
 MODEL_SIZE_FLOOR = 4097
@@ -149,20 +146,14 @@ def _measure_full() -> list:
 
 
 def run(tier: str = "full") -> list:
-    """benchmarks.run entry: re-exec with the tier's forced host device
-    count (jax may already be initialized single-device in the caller)."""
+    """benchmarks.run entry: in-process over an accelerator's devices,
+    else a child with the tier's forced host device count
+    (``common.run_multidevice``)."""
+    from benchmarks.common import run_multidevice
     _, _, (pods, data) = _TIERS[tier]
-    env = dict(os.environ)
-    force = f"--xla_force_host_platform_device_count={pods * data}"
-    if "xla_force_host_platform_device_count" not in env.get("XLA_FLAGS", ""):
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + force).strip()
-    with tempfile.NamedTemporaryFile("r", suffix=".json") as f:
-        cmd = [sys.executable, "-m", "benchmarks.grouped_round_bench",
-               "--emit", f.name, tier]
-        subprocess.run(cmd, env=env, check=True,
-                       cwd=os.path.dirname(os.path.dirname(
-                           os.path.abspath(__file__))))
-        return json.load(open(f.name))
+    return run_multidevice(
+        "benchmarks.grouped_round_bench", pods * data, [tier],
+        _measure_smoke if tier == "smoke" else _measure_full)
 
 
 def main():

@@ -35,12 +35,9 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
-import tempfile
 import time
 
-FORCE_FLAG = "--xla_force_host_platform_device_count=8"
 _ROUNDS = {16: 20, 1000: 5}          # scan length R per federation size
 _BATCH, _STEPS, _SIZES = 1, 1, (16, 24)
 
@@ -147,17 +144,11 @@ def _measure_sharded(ks) -> list:
 
 
 def run(ks=(16, 1000)) -> list:
+    from benchmarks.common import run_multidevice
     rows = _measure_local(ks)
-    env = dict(os.environ)
-    if "xla_force_host_platform_device_count" not in env.get("XLA_FLAGS", ""):
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + FORCE_FLAG).strip()
-    with tempfile.NamedTemporaryFile("r", suffix=".json") as f:
-        cmd = [sys.executable, "-m", "benchmarks.round_perf_bench",
-               "--emit", f.name] + [str(k) for k in ks]
-        subprocess.run(cmd, env=env, check=True,
-                       cwd=os.path.dirname(os.path.dirname(
-                           os.path.abspath(__file__))))
-        rows += json.load(open(f.name))
+    rows += run_multidevice("benchmarks.round_perf_bench", 8,
+                            [str(k) for k in ks],
+                            lambda: _measure_sharded(ks))
     return rows
 
 

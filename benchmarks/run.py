@@ -57,6 +57,8 @@ ALIASES = {"kernels": "kernels_bench", "roofline": "roofline_bench",
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     wanted = sys.argv[1:] or MODULES
     wanted = [ALIASES.get(w, w) for w in wanted]
     print("name,us_per_call,derived")

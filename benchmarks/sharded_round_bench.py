@@ -27,10 +27,12 @@ dispatch; the smoke now scans R=24 and ALSO reports the single-round
 dispatch cost as an explicit ``..._dispatch`` row so both numbers stay
 tracked instead of blended.
 
-Host-device forcing must happen before jax initializes, so ``run()``
-re-execs this module in a subprocess with ``XLA_FLAGS=--xla_force_host_
-platform_device_count=8`` and parses the rows back — callable from
-``benchmarks.run`` no matter what the parent process already imported.
+On the CPU, host-device forcing must happen before jax initializes, so
+``run()`` re-execs this module in a subprocess with ``XLA_FLAGS=--xla_
+force_host_platform_device_count=8`` and parses the rows back — callable
+from ``benchmarks.run`` no matter what the parent process already
+imported. On an accelerator it measures in-process over ``jax.devices()``
+(``common.run_multidevice``).
 
 ``python -m benchmarks.sharded_round_bench smoke`` runs the K=16 pairing
 (the CI guard that keeps the shard_map path compiling) and writes the
@@ -39,13 +41,9 @@ platform_device_count=8`` and parses the rows back — callable from
 from __future__ import annotations
 
 import json
-import os
-import subprocess
 import sys
-import tempfile
 import time
 
-FORCE_FLAG = "--xla_force_host_platform_device_count=8"
 _SETTINGS = {  # K -> (size ladder, batch, local steps, scan rounds)
     16: ((48, 64), 32, 5, 24),       # smoke: R large enough to amortize
     125: ((48, 64), 32, 5, 10),      # weak-scaling reference for K=1000
@@ -156,19 +154,13 @@ def _measure(ks, dispatch_rows: bool = False) -> list:
 
 
 def run(ks=(1000, 10000), dispatch_rows: bool = False) -> list:
-    """benchmarks.run entry: re-exec with forced host devices (jax may
-    already be initialized single-device in the caller)."""
-    env = dict(os.environ)
-    if "xla_force_host_platform_device_count" not in env.get("XLA_FLAGS", ""):
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + FORCE_FLAG).strip()
-    with tempfile.NamedTemporaryFile("r", suffix=".json") as f:
-        cmd = [sys.executable, "-m", "benchmarks.sharded_round_bench",
-               "--emit", f.name] + (["--dispatch"] if dispatch_rows else []) \
-            + [str(k) for k in ks]
-        subprocess.run(cmd, env=env, check=True,
-                       cwd=os.path.dirname(os.path.dirname(
-                           os.path.abspath(__file__))))
-        return json.load(open(f.name))
+    """benchmarks.run entry: in-process over an accelerator's devices,
+    else a child with forced host devices (``common.run_multidevice``)."""
+    from benchmarks.common import run_multidevice
+    return run_multidevice(
+        "benchmarks.sharded_round_bench", 8,
+        (["--dispatch"] if dispatch_rows else []) + [str(k) for k in ks],
+        lambda: _measure(ks, dispatch_rows=dispatch_rows))
 
 
 def main():

@@ -30,10 +30,7 @@ every other tracked artifact); ``... full`` writes ``BENCH_tp_round.json``.
 from __future__ import annotations
 
 import json
-import os
-import subprocess
 import sys
-import tempfile
 import time
 
 MODEL_SIZE_FLOOR = 4097     # above the 4096 water-filling grid psum
@@ -167,19 +164,11 @@ def _measure(tier: str) -> list:
 
 
 def run(tier: str = "full") -> list:
-    """benchmarks.run entry: re-exec with forced host devices (jax may
-    already be initialized single-device in the caller)."""
-    env = dict(os.environ)
-    force = f"--xla_force_host_platform_device_count={_DEVICES}"
-    if "xla_force_host_platform_device_count" not in env.get("XLA_FLAGS", ""):
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + force).strip()
-    with tempfile.NamedTemporaryFile("r", suffix=".json") as f:
-        cmd = [sys.executable, "-m", "benchmarks.tp_round_bench",
-               "--emit", f.name, tier]
-        subprocess.run(cmd, env=env, check=True,
-                       cwd=os.path.dirname(os.path.dirname(
-                           os.path.abspath(__file__))))
-        return json.load(open(f.name))
+    """benchmarks.run entry: in-process over an accelerator's devices,
+    else a child with forced host devices (``common.run_multidevice``)."""
+    from benchmarks.common import run_multidevice
+    return run_multidevice("benchmarks.tp_round_bench", _DEVICES, [tier],
+                           lambda: _measure(tier))
 
 
 def main():

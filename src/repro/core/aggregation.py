@@ -155,7 +155,8 @@ def paota_aggregate_stacked(stacked_models, powers: jnp.ndarray,
         for leaf in leaves:
             l2 = leaf.reshape((leaf.shape[0], -1))
             acc = jnp.einsum("k,kd->d", bp.astype(jnp.float32),
-                             l2.astype(jnp.float32))
+                             l2.astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
             agg.append((acc / varsigma).reshape(leaf.shape[1:]))
         return jax.tree_util.tree_unflatten(treedef, agg), varsigma
     # fused superpose-and-normalize per leaf (sweep 2 of the round): b*p

@@ -80,7 +80,8 @@ def aircomp_aggregate(stacked: jnp.ndarray, powers: jnp.ndarray,
         from repro.kernels.ops import aircomp_sum
         agg = aircomp_sum(stacked, bp, noise)
     else:
-        agg = (jnp.einsum("k,kd->d", bp.astype(stacked.dtype), stacked)
+        agg = (jnp.einsum("k,kd->d", bp.astype(stacked.dtype), stacked,
+                          precision=jax.lax.Precision.HIGHEST)
                + noise) / varsigma.astype(stacked.dtype)
     return agg, varsigma
 

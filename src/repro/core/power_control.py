@@ -82,7 +82,8 @@ def client_sq_norms(tree, tp_axes=None):
     partial is psum'd over them so every TP shard returns the full-model
     norm. Callers with mixed sharded/replicated leaves split the tree
     first (``repro.kernels.round_stats.round_stats_tp`` does)."""
-    out = _accumulate([jnp.einsum("kd,kd->k", _leaf2d(l), _leaf2d(l))
+    out = _accumulate([jnp.einsum("kd,kd->k", _leaf2d(l), _leaf2d(l),
+                                  precision=jax.lax.Precision.HIGHEST)
                        for l in jax.tree_util.tree_leaves(tree)])
     return out if not tp_axes else jax.lax.psum(out, tp_axes)
 
@@ -91,7 +92,8 @@ def client_dots(tree, vec_tree, tp_axes=None):
     """(K,) per-client <leaf_k, vec> accumulated across leaves;
     ``tp_axes`` as in ``client_sq_norms`` (vec_tree leaves must be the
     matching TP-local blocks)."""
-    out = _accumulate([_leaf2d(l) @ g.reshape(-1)
+    out = _accumulate([jnp.matmul(_leaf2d(l), g.reshape(-1),
+                                  precision=jax.lax.Precision.HIGHEST)
                        for l, g in zip(jax.tree_util.tree_leaves(tree),
                                        jax.tree_util.tree_leaves(vec_tree))])
     return out if not tp_axes else jax.lax.psum(out, tp_axes)

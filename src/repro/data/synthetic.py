@@ -4,15 +4,15 @@ MNIST is not available offline in this container (DESIGN.md §3), so
 ``make_mnist_like`` procedurally generates a deterministic 10-class 28x28
 dataset with MNIST-like difficulty: each class has a smoothed stroke
 prototype; samples add jitter (shift) and pixel noise. A loader hook
-(`load_mnist_npz`) accepts a real ``mnist.npz`` if one is present, keeping
-the pipeline identical.
+(`load_mnist_npz`) reads a real ``mnist.npz`` when its path is given
+explicitly, keeping the pipeline identical; without a path the data comes
+from the seed.
 
 ``token_stream`` provides synthetic LM token batches for the transformer
 examples (power-law unigram with Markov structure so the loss has signal).
 """
 from __future__ import annotations
 
-import os
 from typing import Optional, Tuple
 
 import numpy as np
@@ -66,22 +66,21 @@ def make_mnist_like(n_train: int = 20000, n_test: int = 4000,
     return x_tr, y_tr, x_te, y_te
 
 
-def load_mnist_npz(path: str = "mnist.npz"):
-    """Optional hook: real MNIST if a .npz with x_train/y_train/x_test/y_test
-    exists (same interface as make_mnist_like). Returns None if absent."""
-    if not os.path.exists(path):
-        return None
-    z = np.load(path)
-    x_tr = z["x_train"].reshape(len(z["x_train"]), -1).astype(np.float32) / 255.0
-    x_te = z["x_test"].reshape(len(z["x_test"]), -1).astype(np.float32) / 255.0
-    return x_tr, z["y_train"].astype(np.int32), x_te, z["y_test"].astype(np.int32)
+def load_mnist_npz(path: str):
+    """Real MNIST from a .npz with x_train/y_train/x_test/y_test (same
+    interface as make_mnist_like)."""
+    with np.load(path) as z:
+        x_tr = z["x_train"].reshape(len(z["x_train"]), -1).astype(np.float32) / 255.0
+        x_te = z["x_test"].reshape(len(z["x_test"]), -1).astype(np.float32) / 255.0
+        return (x_tr, z["y_train"].astype(np.int32), x_te,
+                z["y_test"].astype(np.int32))
 
 
-def get_dataset(prefer_real: bool = True, **kw):
-    if prefer_real:
-        real = load_mnist_npz()
-        if real is not None:
-            return real
+def get_dataset(path: Optional[str] = None, **kw):
+    """Real MNIST from ``path`` when one is given; otherwise the seeded
+    ``make_mnist_like`` data (``kw`` are its arguments)."""
+    if path is not None:
+        return load_mnist_npz(path)
     return make_mnist_like(**kw)
 
 

@@ -332,7 +332,8 @@ def eq25_factors(pending, starts, global_vec, prev_global, stal, omega,
     (pending, starts), not carried deltas): derive the deltas, then run
     the same fused one-sweep stats the on-device core uses. ``use_kernel``
     is accepted for interface compatibility; kernel-vs-jnp routing is
-    backend-resolved inside ``repro.kernels.ops.round_stats``.
+    resolved by the lowering platform inside
+    ``repro.kernels.ops.round_stats``.
 
     Returns (deltas pytree, rho, theta)."""
     del use_kernel
@@ -902,8 +903,15 @@ def _cohort_round_step(carry: RoundCarry, x, y, *, rcfg: RoundCfg,
         else:
             payload = _zero_rows(payload, ok)
     p_max = jnp.full((m,), rcfg.p_max_watts, jnp.float32)
-    beta, p2_obj = waterfill_beta_jnp(rho, theta, p_max, b, rcfg.c1, rcfg.c0,
-                                      axis_name=axis_name)
+    # P2 is solved over the slots in client-id order. Its objective is
+    # flat near the optimum (cells a float ulp apart), so the K-sums'
+    # association order picks beta; in slot order that order followed the
+    # refill history and the host's SIMD width, not the client set.
+    order = jnp.argsort(jnp.where(live, occ, k_local))
+    beta_o, p2_obj = waterfill_beta_jnp(rho[order], theta[order], p_max,
+                                        b[order], rcfg.c1, rcfg.c0,
+                                        axis_name=axis_name)
+    beta = jnp.zeros_like(beta_o).at[order].set(beta_o)
     powers = power_from_beta(beta, rho, theta, p_max)
     h = jnp.where(live, streams.channel(carry.t)[occ], 0.0)
     powers = constraint7_powers(powers, payload, h, rcfg.p_max_watts,

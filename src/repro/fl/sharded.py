@@ -99,11 +99,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-try:                                    # jax >= 0.6 exports it at top level
-    from jax import shard_map
-except ImportError:                     # 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map
-
 import numpy as np
 
 from repro.core.aircomp import ChannelConfig, sample_channel_gains
@@ -123,6 +118,22 @@ from repro.sharding.rules import batch_specs, stack_client_specs
 
 OUT_KEYS = ("n_participants", "time", "mean_staleness", "beta_mean",
             "varsigma", "p2_objective", "n_screened", "rolled_back")
+
+
+def vary_over(tree, axes):
+    """Type every leaf of ``tree`` as varying over the mesh ``axes``.
+
+    Local SGD differentiates the broadcast globals, which ``shard_map``
+    types as invariant over the client axes. Differentiating an invariant
+    input against per-shard data makes ``jax.grad`` psum the cotangent
+    over those axes (the transpose of the implicit invariant-to-varying
+    cast), which would mix every shard's clients into each client's
+    gradient. Casting the globals to varying BEFORE the gradient keeps
+    each client's local step its own, with no collective."""
+    def leaf(x):
+        missing = tuple(a for a in axes if a not in jax.typeof(x).vma)
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
+    return jax.tree_util.tree_map(leaf, tree)
 
 
 class ShardedPAOTA(FusedPAOTA):
@@ -538,6 +549,7 @@ class ShardedPAOTA(FusedPAOTA):
             return slice_k(full)
 
         def local_train(global_state, x, y, r):
+            global_state = vary_over(global_state, self.client_axes)
             cids = (offset.astype(jnp.uint32)
                     + jnp.arange(k_loc, dtype=jnp.uint32))
             idx = self.engine.round_plan(r, client_ids=cids,
@@ -553,6 +565,7 @@ class ShardedPAOTA(FusedPAOTA):
             # slot ids are shard-LOCAL rows of (x, y); every draw keys on
             # the GLOBAL client id, so a client's trained row is identical
             # whichever shard/slot computes it
+            global_state = vary_over(global_state, self.client_axes)
             gids = (offset.astype(jnp.uint32) + ids.astype(jnp.uint32))
             idx = self.engine.round_plan(r, client_ids=gids,
                                          n_samples=n_dev[gids])
@@ -688,11 +701,10 @@ class ShardedPAOTA(FusedPAOTA):
                     keep_pending=not self._rcfg.transmit_delta,
                     rcfg=self._rcfg)
 
-            smap = shard_map(body, self.mesh,
-                             in_specs=(glob_spec, self._x_spec,
-                                       self._y_spec),
-                             out_specs=self._carry_specs,
-                             check_rep=True)
+            smap = jax.shard_map(body, mesh=self.mesh,
+                                 in_specs=(glob_spec, self._x_spec,
+                                           self._y_spec),
+                                 out_specs=self._carry_specs)
             return smap(vec, x, y)
         carry = super()._init_carry(vec, x, y)
         if self._grouping is not None:
@@ -718,25 +730,13 @@ class ShardedPAOTA(FusedPAOTA):
             raise ValueError(
                 f"grouped aggregation advances whole windows: n_rounds="
                 f"{n_rounds} is not a multiple of group_period={n}")
-        smap = shard_map(body, self.mesh,
-                         in_specs=(self._carry_specs, self._x_spec,
-                                   self._y_spec),
-                         out_specs=(self._carry_specs, self._out_specs),
-                         check_rep=True)
+        smap = jax.shard_map(body, mesh=self.mesh,
+                             in_specs=(self._carry_specs, self._x_spec,
+                                       self._y_spec),
+                             out_specs=(self._carry_specs, self._out_specs))
         carry, outs = smap(carry, x, y)
         if grouping is not None:
             # window-stacked (n_windows, N) metrics back to the flat
             # (n_rounds,) timeline the driver's history expects
             outs = {k: v.reshape((n_rounds,)) for k, v in outs.items()}
         return carry, outs
-
-    def compiled_scan_hlo(self, n_rounds: int) -> str:
-        """Compiled HLO of the n-round advance (builds the round-0 carry
-        if needed, does NOT run the scan) — what the grouped benchmark's
-        cross-pod collective count inspects."""
-        if self._carry is None:
-            self._carry = self._jit_init(self._init_global, self.engine._x,
-                                         self.engine._y)
-        return self._jit_scan.lower(self._carry, self.engine._x,
-                                    self.engine._y,
-                                    n_rounds=n_rounds).compile().as_text()

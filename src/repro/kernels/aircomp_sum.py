@@ -28,25 +28,23 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels.round_stats import out_struct
+
 
 DEFAULT_BLOCK_D = 512
-
-
-def backend_interpret_default() -> bool:
-    """Pallas lowering policy: compile for real on TPU, fall back to
-    interpret mode everywhere else (CPU/GPU containers). Passing
-    ``interpret=True`` unconditionally would mean the "fused" kernel never
-    actually compiles even on TPU."""
-    return jax.default_backend() != "tpu"
+# Every superposition contracts f32 weights b_k p_k at full precision. At
+# a TPU's default precision the MXU takes f32 operands as one bf16 pass,
+# which rounds the weights (and an f32 payload) to 8 mantissa bits.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _kernel(bp_ref, x_ref, noise_ref, out_ref):
     bp = bp_ref[...]                       # (1, K)
-    x = x_ref[...]                         # (K, BLOCK_D)
+    x = x_ref[...].astype(jnp.float32)     # (K, BLOCK_D)
     n = noise_ref[...]                     # (1, BLOCK_D)
     varsigma = jnp.maximum(jnp.sum(bp), 1e-12)
     acc = jax.lax.dot_general(
-        bp, x, (((1,), (0,)), ((), ())),
+        bp, x, (((1,), (0,)), ((), ())), precision=HIGHEST,
         preferred_element_type=jnp.float32)       # (1, BLOCK_D)
     # noise joins the reduction in the accumulator dtype, not its own
     out_ref[...] = ((acc + n.astype(acc.dtype)) / varsigma).astype(out_ref.dtype)
@@ -55,7 +53,7 @@ def _kernel(bp_ref, x_ref, noise_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def aircomp_sum_pallas(stacked: jnp.ndarray, bp: jnp.ndarray,
                        noise: jnp.ndarray, *, block_d: int = DEFAULT_BLOCK_D,
-                       interpret: bool | None = None) -> jnp.ndarray:
+                       interpret: bool = False) -> jnp.ndarray:
     """stacked: (K, D); bp: (K,); noise: (D,) -> (D,) f32 aggregate.
 
     The payload may be bf16; the contraction accumulates in f32, the AWGN
@@ -64,10 +62,8 @@ def aircomp_sum_pallas(stacked: jnp.ndarray, bp: jnp.ndarray,
     ``superpose_normalize_pallas`` / ``aircomp_sum_tree_psum`` (a bf16
     carry stores its planes rounded, but the received y must not be).
 
-    ``interpret=None`` resolves from the active backend (compiled on TPU,
-    interpret elsewhere)."""
-    if interpret is None:
-        interpret = backend_interpret_default()
+    ``interpret=True`` runs the kernel body in the Pallas interpreter
+    (tests on hosts without a TPU)."""
     k, d = stacked.shape
     noise = noise.astype(jnp.float32)
     pad = (-d) % block_d
@@ -85,7 +81,7 @@ def aircomp_sum_pallas(stacked: jnp.ndarray, bp: jnp.ndarray,
             pl.BlockSpec((1, block_d), lambda i: (0, i)),     # noise stripe
         ],
         out_specs=pl.BlockSpec((1, block_d), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, dp), jnp.float32),
+        out_shape=out_struct((1, dp), jnp.float32, stacked, bp, noise),
         interpret=interpret,
     )(bp[None, :].astype(jnp.float32), stacked, noise[None, :])
     return out[0, :d]
@@ -102,10 +98,12 @@ def _superpose_kernel(vs_min, p_ref, m_ref, x_ref, noise_ref, out_ref,
     bp = p_ref[...] * m_ref[...]                # (1, K) f32, masked in-kernel
     raw = jnp.sum(bp)
     varsigma = jnp.maximum(raw, vs_min)
-    x = x_ref[...]                              # (K, BLOCK_D), f32 or bf16
+    # a bf16 payload is widened here: a mixed bf16 x f32 contraction
+    # rounds the f32 weights b_k p_k to bf16 on the MXU
+    x = x_ref[...].astype(jnp.float32)          # (K, BLOCK_D)
     n = noise_ref[...]                          # (1, BLOCK_D)
     acc = jax.lax.dot_general(
-        bp, x, (((1,), (0,)), ((), ())),
+        bp, x, (((1,), (0,)), ((), ())), precision=HIGHEST,
         preferred_element_type=jnp.float32)     # f32 accumulation always
     out_ref[...] = (acc + n.astype(acc.dtype)) / varsigma
 
@@ -120,7 +118,7 @@ def superpose_normalize_pallas(stacked: jnp.ndarray, powers: jnp.ndarray,
                                mask: jnp.ndarray, noise: jnp.ndarray, *,
                                vs_min: float = 1e-12,
                                block_d: int = DEFAULT_BLOCK_D,
-                               interpret: bool | None = None):
+                               interpret: bool = False):
     """Eqs. (6)+(8) in one sweep: stacked (K, D) payloads, powers/mask (K,)
     -> ``(agg (D,) f32, varsigma f32 scalar)`` where
 
@@ -134,10 +132,8 @@ def superpose_normalize_pallas(stacked: jnp.ndarray, powers: jnp.ndarray,
     zero-uploader guard needs no second reduction. ``stacked`` may be
     bf16; the contraction always accumulates in f32.
 
-    ``interpret=None`` resolves from the backend (compiled on TPU,
-    interpret elsewhere)."""
-    if interpret is None:
-        interpret = backend_interpret_default()
+    ``interpret=True`` runs the kernel body in the Pallas interpreter
+    (tests on hosts without a TPU)."""
     k, d = stacked.shape
     noise = noise.astype(jnp.float32)
     pad = (-d) % block_d
@@ -146,6 +142,7 @@ def superpose_normalize_pallas(stacked: jnp.ndarray, powers: jnp.ndarray,
         noise = jnp.pad(noise, (0, pad))
     dp = d + pad
     kern = functools.partial(_superpose_kernel, float(vs_min))
+    ops_ = (stacked, powers, mask, noise)
     agg, vs = pl.pallas_call(
         kern,
         grid=(dp // block_d,),
@@ -157,8 +154,8 @@ def superpose_normalize_pallas(stacked: jnp.ndarray, powers: jnp.ndarray,
         ],
         out_specs=[pl.BlockSpec((1, block_d), lambda i: (0, i)),
                    pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, dp), jnp.float32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.float32)],
+        out_shape=[out_struct((1, dp), jnp.float32, *ops_),
+                   out_struct((1, 1), jnp.float32, powers, mask)],
         interpret=interpret,
     )(powers[None, :].astype(jnp.float32), mask[None, :].astype(jnp.float32),
       stacked, noise[None, :])
@@ -197,7 +194,8 @@ def aircomp_sum_psum(stacked: jnp.ndarray, bp: jnp.ndarray,
         from repro.core.aircomp import VARSIGMA_MIN
         varsigma_min = VARSIGMA_MIN
     acc = jax.lax.dot_general(
-        bp[None, :].astype(jnp.float32), stacked, (((1,), (0,)), ((), ())),
+        bp[None, :].astype(jnp.float32), stacked.astype(jnp.float32),
+        (((1,), (0,)), ((), ())), precision=HIGHEST,
         preferred_element_type=jnp.float32)[0]            # (D,) local partial
     acc = jax.lax.psum(acc, axis_name)
     varsigma = jnp.maximum(jax.lax.psum(jnp.sum(bp), axis_name), varsigma_min)
@@ -249,7 +247,8 @@ def aircomp_partial_tree(stacked_leaves, bp: jnp.ndarray, axis_name=None):
     bp32 = bp[None, :].astype(jnp.float32)
     parts = [jax.lax.dot_general(
         bp32, leaf.reshape((leaf.shape[0], -1)).astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)[0]
+        (((1,), (0,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32)[0]
         for leaf in stacked_leaves]
     parts.append(jnp.sum(bp).astype(jnp.float32)[None])
     flat = jnp.concatenate(parts)
@@ -280,7 +279,7 @@ def aircomp_partial_tree_tp(stacked_leaves, bp: jnp.ndarray, tp):
     for leaf, dim in zip(stacked_leaves, tp.leaf_dims):
         acc = jax.lax.dot_general(
             bp32, leaf.reshape((leaf.shape[0], -1)).astype(jnp.float32),
-            (((1,), (0,)), ((), ())),
+            (((1,), (0,)), ((), ())), precision=HIGHEST,
             preferred_element_type=jnp.float32)[0]
         trail = leaf.shape[1:]
         acc = acc.reshape(trail)
@@ -353,7 +352,7 @@ def _gather_superpose_kernel(vs_min, n_blocks, block_n, block_d,
     # shape, f32 accumulation) — the revisited out stripe accumulates
     # across the j blocks
     out_ref[...] += jax.lax.dot_general(
-        a, onehot, (((0,), (0,)), ((), ())),
+        a, onehot, (((0,), (0,)), ((), ())), precision=HIGHEST,
         preferred_element_type=jnp.float32)                  # (1, BLOCK_D)
 
     @pl.when(j == n_blocks - 1)
@@ -373,7 +372,7 @@ def gather_superpose_pallas(values: jnp.ndarray, idx: jnp.ndarray,
                             vs_min: float = 1e-12,
                             block_d: int = DEFAULT_BLOCK_D,
                             block_n: int = 1024,
-                            interpret: bool | None = None):
+                            interpret: bool = False):
     """AirComp over compressed cohort rows, fused: slot gather + b*p
     masking + compressed superposition + AWGN + varsigma in one pass.
 
@@ -390,10 +389,8 @@ def gather_superpose_pallas(values: jnp.ndarray, idx: jnp.ndarray,
     normalized on the last block — the dense (m, d) plane never exists.
     Returns ((d,) f32 aggregate, raw varsigma).
 
-    ``interpret=None`` resolves from the backend (compiled on TPU,
-    interpret elsewhere)."""
-    if interpret is None:
-        interpret = backend_interpret_default()
+    ``interpret=True`` runs the kernel body in the Pallas interpreter
+    (tests on hosts without a TPU)."""
     m, s = values.shape
     n = m * s
     bp32 = bp.astype(jnp.float32)
@@ -416,6 +413,7 @@ def gather_superpose_pallas(values: jnp.ndarray, idx: jnp.ndarray,
     n_blocks = np_ // block_n
     kern = functools.partial(_gather_superpose_kernel, float(vs_min),
                              n_blocks, block_n, block_d)
+    ops_ = (wflat, vflat, iflat, noise)
     agg, vs = pl.pallas_call(
         kern,
         grid=(dp // block_d, n_blocks),
@@ -428,8 +426,8 @@ def gather_superpose_pallas(values: jnp.ndarray, idx: jnp.ndarray,
         ],
         out_specs=[pl.BlockSpec((1, block_d), lambda i, j: (0, i)),
                    pl.BlockSpec((1, 1), lambda i, j: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, dp), jnp.float32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.float32)],
+        out_shape=[out_struct((1, dp), jnp.float32, *ops_),
+                   out_struct((1, 1), jnp.float32, bp32)],
         interpret=interpret,
     )(bp32[None, :], wflat, vflat, iflat, noise[None, :])
     return agg[0, :d], vs[0, 0]
@@ -458,7 +456,7 @@ def gather_superpose_psum(values: jnp.ndarray, idx: jnp.ndarray,
     dense = jnp.zeros((m, d), jnp.float32).at[rows, idx].add(
         values.astype(jnp.float32))
     acc = jax.lax.dot_general(
-        w[None, :], dense, (((1,), (0,)), ((), ())),
+        w[None, :], dense, (((1,), (0,)), ((), ())), precision=HIGHEST,
         preferred_element_type=jnp.float32)[0]               # (d,) partial
     flat = jnp.concatenate([acc, jnp.sum(bp32)[None]])
     flat = jax.lax.psum(flat, axis_name)

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.round_stats import out_struct
+
 DEFAULT_BLOCK_D = 512
 
 
@@ -41,7 +43,7 @@ def _kernel(x_ref, g_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def cosine_partials_pallas(deltas: jnp.ndarray, g: jnp.ndarray, *,
                            block_d: int = DEFAULT_BLOCK_D,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool = False) -> jnp.ndarray:
     """deltas: (K, D); g: (D,) -> (K, 2) [dot_k, ||delta_k||^2]."""
     k, d = deltas.shape
     pad = (-d) % block_d
@@ -57,7 +59,7 @@ def cosine_partials_pallas(deltas: jnp.ndarray, g: jnp.ndarray, *,
             pl.BlockSpec((1, block_d), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((k, 2), lambda i: (0, 0)),  # revisited accumulator
-        out_shape=jax.ShapeDtypeStruct((k, 2), jnp.float32),
+        out_shape=out_struct((k, 2), jnp.float32, deltas, g),
         interpret=interpret,
     )(deltas, g[None, :])
 

@@ -30,7 +30,8 @@ Two implementations, same contract:
   scanned round XLA's own fusion of the plain ops wins (dot operands
   materialize per chunk), so the round core uses ``chunk=None``.
 
-``repro.kernels.ops.round_stats`` picks between them by backend;
+``repro.kernels.ops.round_stats`` picks between them by the platform the
+computation is lowered for;
 ``repro.kernels.ref.round_stats_ref`` is the allclose oracle.
 
 The leading axis is whatever client plane the round carries: the dense
@@ -58,6 +59,17 @@ DEFAULT_BLOCK_D = 512
 # lax.scan whose dot operands must materialize per chunk — the explicit
 # form is kept for the kernel tests and for experimentation.
 CHUNK_D = 8192
+# The stats contract f32 rows at full precision: at a TPU's default
+# precision an f32 dot is one bf16 pass (8 mantissa bits).
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def out_struct(shape, dtype, *operands):
+    """A kernel output's type: varying over the mesh axes its operands
+    vary over (inside ``jax.shard_map``, whose replication check needs the
+    kernel's outputs typed), invariant outside one."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 # ---------------------------------------------------------------------------
@@ -69,6 +81,7 @@ def _kernel(d_ref, g_ref, out_ref, gn2_ref):
     x = d_ref[...].astype(jnp.float32)          # (K, BLOCK_D) deltas stripe
     g = g_ref[...].astype(jnp.float32)          # (1, BLOCK_D)
     dot = jax.lax.dot_general(x, g, (((1,), (1,)), ((), ())),
+                              precision=HIGHEST,
                               preferred_element_type=jnp.float32)   # (K, 1)
     dn2 = jnp.sum(x * x, axis=1, keepdims=True)                     # (K, 1)
     partial = jnp.concatenate([dot, dn2], axis=1)                   # (K, 2)
@@ -91,6 +104,7 @@ def _kernel_payload(d_ref, p_ref, g_ref, out_ref, gn2_ref):
     p = p_ref[...].astype(jnp.float32)          # (K, BLOCK_D) payload stripe
     g = g_ref[...].astype(jnp.float32)
     dot = jax.lax.dot_general(x, g, (((1,), (1,)), ((), ())),
+                              precision=HIGHEST,
                               preferred_element_type=jnp.float32)
     dn2 = jnp.sum(x * x, axis=1, keepdims=True)
     pn2 = jnp.sum(p * p, axis=1, keepdims=True)
@@ -112,7 +126,7 @@ def _kernel_payload(d_ref, p_ref, g_ref, out_ref, gn2_ref):
 def round_stats_pallas(deltas: jnp.ndarray, g: jnp.ndarray,
                        payload: jnp.ndarray | None = None, *,
                        block_d: int = DEFAULT_BLOCK_D,
-                       interpret: bool = True):
+                       interpret: bool = False):
     """deltas: (K, D); g: (D,); payload: optional (K, D).
 
     Returns ``(stats, gn2)`` where stats is (K, 2) ``[dot_k, dn2_k]`` (or
@@ -133,8 +147,9 @@ def round_stats_pallas(deltas: jnp.ndarray, g: jnp.ndarray,
     ncol = 2 if payload is None else 3
     out_specs = [pl.BlockSpec((k, ncol), lambda i: (0, 0)),   # revisited acc
                  pl.BlockSpec((1, 1), lambda i: (0, 0))]
-    out_shape = [jax.ShapeDtypeStruct((k, ncol), jnp.float32),
-                 jax.ShapeDtypeStruct((1, 1), jnp.float32)]
+    ops_ = (deltas, g) if payload is None else (deltas, g, payload)
+    out_shape = [out_struct((k, ncol), jnp.float32, *ops_),
+                 out_struct((1, 1), jnp.float32, g)]
     if payload is None:
         stats, gn2 = pl.pallas_call(
             _kernel, grid=grid, in_specs=[stripe, gspec],
@@ -287,13 +302,14 @@ def compressed_round_stats(values, idx, resid, resid_idx, g,
     v32 = values.astype(jnp.float32)
     if scale is not None:
         v32 = v32 * scale.astype(jnp.float32)[:, None]
-    dots = jnp.einsum("ms,ms->m", v32, g32[idx])
-    pn2 = jnp.einsum("ms,ms->m", v32, v32)
+    dots = jnp.einsum("ms,ms->m", v32, g32[idx], precision=HIGHEST)
+    pn2 = jnp.einsum("ms,ms->m", v32, v32, precision=HIGHEST)
     dn2 = pn2
     if resid is not None:
         r32 = resid.astype(jnp.float32)
-        dots = dots + jnp.einsum("ms,ms->m", r32, g32[resid_idx])
-        dn2 = dn2 + jnp.einsum("ms,ms->m", r32, r32)
+        dots = dots + jnp.einsum("ms,ms->m", r32, g32[resid_idx],
+                                 precision=HIGHEST)
+        dn2 = dn2 + jnp.einsum("ms,ms->m", r32, r32, precision=HIGHEST)
     return dots, dn2, pn2, jnp.sum(g32 * g32)
 
 
@@ -304,7 +320,7 @@ def round_stats_tp(deltas, g, payload, tp, stats_fn):
     TP-local blocks (trailing dim ``tp.leaf_dims[i]`` holds 1/``shards``
     of the model) while ``g`` is the full replicated global direction —
     so the sweep slices ``g`` down to the matching block per sharded
-    leaf, runs ``stats_fn`` (the backend-dispatched dense sweep) over the
+    leaf, runs ``stats_fn`` (the platform-dispatched dense sweep) over the
     sharded and TP-replicated leaf groups separately, and reduces ONE
     concatenated ``[dots | dn2 (| pn2) | gn2]`` vector over ``tp.axes``.
     TP-replicated leaves (no dividing trailing dim) are accumulated

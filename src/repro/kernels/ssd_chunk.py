@@ -55,7 +55,7 @@ def _kernel(cum_ref, b_ref, c_ref, xdt_ref, y_ref, state_ref, decay_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_intra_chunk_pallas(cum, b, c, xdt, *, interpret: bool = True):
+def ssd_intra_chunk_pallas(cum, b, c, xdt, *, interpret: bool = False):
     """cum: (G, Q) cumulative log-decay; b, c: (G, Q, N); xdt: (G, Q, P)
     where G = batch*chunks*heads (wrapper-flattened).
 

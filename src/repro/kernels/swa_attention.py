@@ -92,7 +92,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def swa_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                          window: Optional[int] = None, causal: bool = True,
                          block_q: int = 128, block_k: int = 128,
-                         interpret: bool = True) -> jnp.ndarray:
+                         interpret: bool = False) -> jnp.ndarray:
     """q: (BH, T, D); k, v: (BH, S, D) -> (BH, T, D).
 
     window: sliding-window width (None = full); causal: apply causal mask.

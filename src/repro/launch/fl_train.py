@@ -68,6 +68,8 @@ continues the killed run bit-for-bit (counter RNG replays identical
 streams).
 """
 from examples.fl_noniid_mnist import main
+from repro.launch.compile_cache import enable_compile_cache
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

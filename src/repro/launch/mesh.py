@@ -18,12 +18,22 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with automatic (GSPMD) axes. The round core and
+    the model code place arrays with ``PartitionSpec``s and let the
+    partitioner propagate the rest; ``jax.make_mesh`` defaults to
+    Explicit axes, under which sharding becomes part of every array's
+    type and that code no longer type-checks."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_cpu_mesh(*, data: int = 1, model: int = 1):
@@ -42,7 +52,7 @@ def make_cpu_mesh(*, data: int = 1, model: int = 1):
             f"virtual devices with XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n} before jax "
             f"initializes (set it in the environment, not after import)")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_pod_mesh(*, pods: int = 2, data: int = 256, tp: int = 1):
@@ -62,8 +72,8 @@ def make_pod_mesh(*, pods: int = 2, data: int = 256, tp: int = 1):
             f"--xla_force_host_platform_device_count={n} before jax "
             f"initializes (set it in the environment, not after import)")
     if tp == 1:
-        return jax.make_mesh((pods, data), ("pod", "data"))
-    return jax.make_mesh((pods, data, tp), ("pod", "data", "tp"))
+        return make_mesh((pods, data), ("pod", "data"))
+    return make_mesh((pods, data, tp), ("pod", "data", "tp"))
 
 
 def make_client_mesh(shards: int | None = None):

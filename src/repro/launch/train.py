@@ -40,7 +40,8 @@ def main():
         import dataclasses
         cfg = dataclasses.replace(cfg, remat="block")
         shape = InputShape("demo", seq_len=128, global_batch=8, kind="train")
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        from repro.launch.mesh import make_cpu_mesh
+        mesh = make_cpu_mesh(data=1, model=1)
         client_axes = ("data",)
     else:
         cfg = runtime_config(get_config(args.arch), SHAPES[args.shape])
@@ -49,7 +50,7 @@ def main():
         mesh = make_production_mesh()
         client_axes = None
 
-    with mesh:
+    with jax.set_mesh(mesh):
         step, structs, _ = make_paota_train_step(
             cfg, mesh, shape, lr=args.lr, local_steps=args.local_steps,
             client_axes=client_axes, donate=False)

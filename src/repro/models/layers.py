@@ -85,11 +85,21 @@ def rope_rotate(x, positions, theta: float):
 # attention (GQA, optional sliding window) — train / prefill / decode
 # ---------------------------------------------------------------------------
 
+def shard_hint(x, spec):
+    """``with_sharding_constraint`` against the mesh set by
+    ``jax.set_mesh``. With no mesh set there is nothing to constrain to
+    and ``x`` passes through; any other error propagates."""
+    if jax.sharding.get_abstract_mesh().empty:
+        return x
+    return jax.lax.with_sharding_constraint(x, spec)
+
+
 def constrain(x, cfg: ModelConfig, kind: str):
     """Activation sharding constraint (no-op unless launch.steps set the
-    hints). kind: 'btd' (batch,seq,d) | 'bthd' (batch,seq,heads,hd) |
-    'btf' (batch,seq,ffn). Leading batch dim -> cfg.act_dp axes; head/ffn
-    dim -> cfg.act_tp. See EXPERIMENTS.md §Perf iter 1."""
+    hints and a mesh is set). kind: 'btd' (batch,seq,d) | 'bthd'
+    (batch,seq,heads,hd) | 'btf' (batch,seq,ffn). Leading batch dim ->
+    cfg.act_dp axes; head/ffn dim -> cfg.act_tp. See EXPERIMENTS.md §Perf
+    iter 1."""
     if not cfg.act_dp and cfg.act_tp is None:
         return x
     from jax.sharding import PartitionSpec as P
@@ -106,10 +116,7 @@ def constrain(x, cfg: ModelConfig, kind: str):
         "bthd": P(dp, None, tp, None),
         "btf": P(dp, None, tp),
     }[kind]
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except (ValueError, RuntimeError):  # no ambient mesh (unit tests)
-        return x
+    return shard_hint(x, spec)
 
 
 def init_attention(key, cfg: ModelConfig, dtype):

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.config import ModelConfig
-from repro.models.layers import _normal, apply_dense, constrain
+from repro.models.layers import _normal, apply_dense, constrain, shard_hint
 
 
 def init_moe(key, cfg: ModelConfig, dtype):
@@ -132,11 +132,7 @@ def _constrain_ep4(x, cfg: ModelConfig):
     if cfg.act_ep is None:
         return x
     from jax.sharding import PartitionSpec as P
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, P(None, cfg.act_ep, None, None))
-    except (ValueError, RuntimeError):
-        return x
+    return shard_hint(x, P(None, cfg.act_ep, None, None))
 
 
 def _expert_compute_shardmap(exp_in, params, cfg: ModelConfig):
@@ -175,7 +171,4 @@ def _constrain_g(x, cfg: ModelConfig):
     from jax.sharding import PartitionSpec as P
     dp = tuple(cfg.act_dp)
     dp = dp[0] if len(dp) == 1 else dp
-    try:
-        return jax.lax.with_sharding_constraint(x, P(dp, None, None))
-    except (ValueError, RuntimeError):
-        return x
+    return shard_hint(x, P(dp, None, None))

@@ -84,9 +84,9 @@ def ssd_chunked(x, dt, a, B, C, cfg: ModelConfig, init_state=None,
 
     x: (Bz, T, H, P)  dt: (Bz, T, H)  a: (H,) negative
     B, C: (Bz, T, G, N). Returns (y (Bz,T,H,P), final_state (Bz,H,P,N)).
-    use_kernel: route the intra-chunk quadratic part through the Pallas
-    kernel (repro.kernels.ssd_chunk) — the TPU hot path; default stays
-    pure-jnp on CPU.
+    use_kernel: route the intra-chunk quadratic part through
+    ``repro.kernels.ops.ssd_intra_chunk`` — the Pallas kernel where the
+    computation is lowered for a TPU, its jnp oracle elsewhere.
     """
     bz, t, h, p = x.shape
     g, n = B.shape[2], B.shape[3]
@@ -114,13 +114,13 @@ def ssd_chunked(x, dt, a, B, C, cfg: ModelConfig, init_state=None,
     xdt = xc * dtc[..., None]
 
     if use_kernel:
-        from repro.kernels.ssd_chunk import ssd_intra_chunk_pallas
+        from repro.kernels.ops import ssd_intra_chunk
         gsz = bz * nc * h
         cum_f = cum.transpose(0, 1, 3, 2).reshape(gsz, q)
         b_f = Bh.transpose(0, 1, 3, 2, 4).reshape(gsz, q, n)
         c_f = Ch.transpose(0, 1, 3, 2, 4).reshape(gsz, q, n)
         x_f = xdt.transpose(0, 1, 3, 2, 4).reshape(gsz, q, p)
-        y_f, st_f, dec_f = ssd_intra_chunk_pallas(cum_f, b_f, c_f, x_f)
+        y_f, st_f, dec_f = ssd_intra_chunk(cum_f, b_f, c_f, x_f)
         y_intra = y_f.reshape(bz, nc, h, q, p).transpose(0, 1, 3, 2, 4)
         chunk_state = st_f.reshape(bz, nc, h, n, p).transpose(0, 1, 2, 4, 3)
         chunk_decay = dec_f.reshape(bz, nc, h)

@@ -98,7 +98,6 @@ def test_shard_aware_kernel_entries_match_reference(client_mesh_8):
     """The kernels' shard-aware entry points (aircomp psum reduction,
     shard-local cosines) inside shard_map equal the single-device
     reductions on the gathered arrays."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.power_control import cosine_similarity
@@ -117,8 +116,8 @@ def test_shard_aware_kernel_entries_match_reference(client_mesh_8):
         cos = cosine_sim_shard(s, gg, "data")
         return agg, varsigma, cos
 
-    smap = jax.jit(shard_map(
-        body, client_mesh_8,
+    smap = jax.jit(jax.shard_map(
+        body, mesh=client_mesh_8,
         in_specs=(P("data"), P("data"), P(), P()),
         out_specs=(P(), P(), P("data"))))
     agg, varsigma, cos = smap(stacked, bp, noise, g)
@@ -136,7 +135,6 @@ def test_shard_aware_kernel_entries_match_reference(client_mesh_8):
 def test_sharded_waterfill_matches_single_device(client_mesh_8):
     """P2 water-filling with psum'd grid reductions returns the same beta
     (each shard its slice) and objective as the single-device solve."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.boxqp import waterfill_beta_jnp
@@ -151,10 +149,10 @@ def test_sharded_waterfill_matches_single_device(client_mesh_8):
 
     beta_ref, obj_ref = waterfill_beta_jnp(rho, theta, p_max, b, c1, c0)
 
-    smap = jax.jit(shard_map(
+    smap = jax.jit(jax.shard_map(
         lambda r, t, p, m: waterfill_beta_jnp(r, t, p, m, c1, c0,
                                               axis_name="data"),
-        client_mesh_8,
+        mesh=client_mesh_8,
         in_specs=(P("data"), P("data"), P("data"), P("data")),
         out_specs=(P("data"), P())))
     beta_sh, obj_sh = smap(rho, theta, p_max, b)

@@ -1,0 +1,137 @@
+"""Compile rehearsals of the round kernels for a TPU v5e.
+
+Each case lowers and compiles one Pallas round kernel for a v5e chip
+that is described, not attached: the chip's kernel compiler (Mosaic)
+refuses here what interpret mode cannot see — blocks that do not tile,
+more VMEM than a kernel may use. Shapes are those of the paper
+federation (MLP 784-10-10-10, K=100, raveled d=8070 and its largest
+pytree leaf), the compressed int8 cohort (m=32, s=d/16), smollm-135m
+client leaves at K=4 in bf16, and a cohort plane of m=256, d=16384.
+
+The topology is described inside a module fixture: describing it loads
+the TPU library, which one process at a time may hold, so nothing here
+touches it while modules are imported.
+
+One whole program is compiled too: the one-round advance of a small
+paper federation, whose data plane must stay out of a cross-program
+prefetch (``repro.fl.fused.TPU_SCAN_OPTIONS``).
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels.aircomp_sum import (gather_superpose_pallas,
+                                       superpose_normalize_pallas)
+from repro.kernels.round_stats import round_stats_pallas
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+# (K, d, payload dtype): rows x flattened leaf width
+PLANES = {
+    "paper_raveled": (100, 8070, F32),
+    "paper_leaf_784x10": (100, 7840, F32),
+    "smollm_embed_k4": (4, 49152 * 576, BF16),
+    "smollm_mlp_k4": (4, 576 * 1536, BF16),
+    "cohort_m256": (256, 16384, F32),
+}
+# (m, d, s, value dtype, int8 scale)
+COMPRESSED = {
+    "paper_randmask16_int8": (32, 8070, 504, jnp.int8, True),
+    "cohort_m256_int8": (256, 16384, 1024, jnp.int8, True),
+    "cohort_m256_f32": (256, 16384, 1024, F32, False),
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                   # no TPU compiler on this host
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _spec(sharding, shape, dtype=F32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_kernels(fn, *args) -> int:
+    """Compile ``fn`` for the described chip; the count of Mosaic kernels
+    in the compiled program."""
+    return jax.jit(fn).lower(*args).compile().as_text().count(
+        "tpu_custom_call")
+
+
+@pytest.mark.parametrize("payload", [False, True], ids=["delta", "model"])
+@pytest.mark.parametrize("plane", list(PLANES))
+def test_round_stats_compiles(one_chip, plane, payload):
+    k, d, dt = PLANES[plane]
+    args = [_spec(one_chip, (k, d), dt), _spec(one_chip, (d,))]
+    if payload:
+        args.append(_spec(one_chip, (k, d), dt))
+    assert _compiled_kernels(
+        lambda de, g, *p: round_stats_pallas(de, g, *p), *args) == 1
+
+
+@pytest.mark.parametrize("plane", list(PLANES))
+def test_superpose_normalize_compiles(one_chip, plane):
+    k, d, dt = PLANES[plane]
+    assert _compiled_kernels(
+        superpose_normalize_pallas, _spec(one_chip, (k, d), dt),
+        _spec(one_chip, (k,)), _spec(one_chip, (k,)),
+        _spec(one_chip, (d,))) == 1
+
+
+@pytest.mark.parametrize("case", list(COMPRESSED))
+def test_gather_superpose_compiles(one_chip, case):
+    m, d, s, vdt, scaled = COMPRESSED[case]
+    args = [_spec(one_chip, (m, s), vdt), _spec(one_chip, (m, s), jnp.int32),
+            _spec(one_chip, (m,)), _spec(one_chip, (d,))]
+    if scaled:
+        args.append(_spec(one_chip, (m,)))
+    assert _compiled_kernels(
+        lambda v, i, bp, n, *sc: gather_superpose_pallas(
+            v, i, bp, n, d=d, scale=sc[0] if sc else None), *args) == 1
+
+
+def test_one_round_scan_has_no_cross_program_prefetch(one_chip):
+    """A one-round scan compiles without its while loop, and the TPU
+    compiler would then prefetch the (K, n, 784) data plane into VMEM
+    across programs; that program hung on a v5e. Under the options the
+    driver compiles with on a TPU, the plane stays in HBM and the round
+    kernels are still in the program."""
+    from repro.core import ChannelConfig, SchedulerConfig
+    from repro.data.partition import partition_noniid
+    from repro.data.pipeline import build_federation
+    from repro.data.synthetic import make_mnist_like
+    from repro.fl import FLClient, FusedPAOTA, PAOTAConfig
+    from repro.fl.fused import TPU_SCAN_OPTIONS
+    from repro.models.mlp import init_mlp_params, mlp_loss
+
+    k = 8
+    x, y, _, _ = make_mnist_like(n_train=400, n_test=10)
+    clients = [FLClient(d, mlp_loss, batch_size=32, lr=0.1, local_steps=2)
+               for d in build_federation(x, y, partition_noniid(
+                   y, n_clients=k, seed=0))]
+    srv = FusedPAOTA(init_mlp_params(jax.random.PRNGKey(0)), clients,
+                     ChannelConfig(), SchedulerConfig(n_clients=k, seed=1),
+                     PAOTAConfig())
+    put = lambda t: jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, a.dtype), t)
+    carry = jax.eval_shape(srv._init_carry, srv._init_global,
+                           srv.engine._x, srv.engine._y)
+    text = srv._jit_scan.lower(
+        put(carry), put(srv.engine._x), put(srv.engine._y),
+        n_rounds=1).compile(compiler_options=TPU_SCAN_OPTIONS).as_text()
+    assert "cross_program_prefetch_index" not in text
+    assert text.count("tpu_custom_call") > 0

@@ -19,14 +19,15 @@ pytestmark = pytest.mark.slow  # arch-zoo/serving/integration tier (scripts/ci.s
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_cpu_mesh
+    return make_cpu_mesh(data=1, model=1)
 
 
 def _setup(arch="smollm-135m", k=3, m=2, mb=2, seq=32, sigma=0.0):
     cfg = get_reduced(arch)
     shape = InputShape("t", seq_len=seq, global_batch=k * mb, kind="train")
     mesh = _mesh11()
-    with mesh:
+    with jax.set_mesh(mesh):
         step, structs, _ = make_paota_train_step(
             cfg, mesh, shape, lr=0.05, local_steps=m,
             sigma_over_varsigma=sigma, client_axes=("data",), donate=False)
@@ -91,7 +92,7 @@ def test_jitted_round_step_runs_and_improves_loss():
     stacked = jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(x[None], (1,) + x.shape), params)
     shape1 = InputShape("t", seq_len=seq, global_batch=mb, kind="train")
-    with mesh:
+    with jax.set_mesh(mesh):
         step1, structs, _ = make_paota_train_step(
             cfg, mesh, shape1, lr=0.05, local_steps=m,
             sigma_over_varsigma=0.0, client_axes=("data",), donate=False)
@@ -101,7 +102,7 @@ def test_jitted_round_step_runs_and_improves_loss():
     mask = jnp.ones((1,), jnp.float32)
     seed = jax.random.key_data(jax.random.PRNGKey(0)).astype(jnp.uint32)
     losses = []
-    with mesh:
+    with jax.set_mesh(mesh):
         for r in range(4):
             stacked, metrics = step1(stacked, {"tokens": toks}, powers, mask,
                                      seed)
