@@ -30,6 +30,7 @@ from typing import List
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint import io as ckpt_io
 from repro.core.aircomp import ChannelConfig, sample_channel_gains
@@ -524,23 +525,34 @@ class FusedPAOTA:
         return rows
 
     def _advance(self, n_rounds: int) -> List[dict]:
-        """One uninterrupted ``lax.scan`` device call of ``n_rounds``."""
-        self._ensure_carry()
-        self._carry, outs = self._jit_scan(self._carry, self.engine._x,
-                                           self.engine._y, n_rounds=n_rounds)
-        outs = {k: np.asarray(v) for k, v in outs.items()}
-        base = len(self.history)
-        rows = [{"round": base + i,
-                 "time": float(outs["time"][i]),
-                 "n_participants": int(outs["n_participants"][i]),
-                 "mean_staleness": float(outs["mean_staleness"][i]),
-                 "beta_mean": float(outs["beta_mean"][i]),
-                 "varsigma": float(outs["varsigma"][i]),
-                 "p2_objective": float(outs["p2_objective"][i]),
-                 "n_screened": float(outs["n_screened"][i]),
-                 "rolled_back": float(outs["rolled_back"][i])}
-                for i in range(n_rounds)]
-        self.history.extend(rows)
+        """One uninterrupted ``lax.scan`` device call of ``n_rounds``.
+
+        The host's work is marked for the profiler: ``paota.advance``
+        around the call, and inside it ``paota.dispatch`` (the dispatch of
+        the scan, and of the round-0 carry where none is built yet),
+        ``paota.fetch`` (the wait for the per-round metrics) and
+        ``paota.rows`` (the history rows)."""
+        with TraceAnnotation("paota.advance"):
+            with TraceAnnotation("paota.dispatch"):
+                self._ensure_carry()
+                self._carry, outs = self._jit_scan(
+                    self._carry, self.engine._x, self.engine._y,
+                    n_rounds=n_rounds)
+            with TraceAnnotation("paota.fetch"):
+                outs = {k: np.asarray(v) for k, v in outs.items()}
+            with TraceAnnotation("paota.rows"):
+                base = len(self.history)
+                rows = [{"round": base + i,
+                         "time": float(outs["time"][i]),
+                         "n_participants": int(outs["n_participants"][i]),
+                         "mean_staleness": float(outs["mean_staleness"][i]),
+                         "beta_mean": float(outs["beta_mean"][i]),
+                         "varsigma": float(outs["varsigma"][i]),
+                         "p2_objective": float(outs["p2_objective"][i]),
+                         "n_screened": float(outs["n_screened"][i]),
+                         "rolled_back": float(outs["rolled_back"][i])}
+                        for i in range(n_rounds)]
+                self.history.extend(rows)
         return rows
 
     def round(self) -> dict:

@@ -75,6 +75,13 @@ rounds, carry donated between scans), ``repro.fl.sharded.ShardedPAOTA``
 host-path ``repro.fl.server.PAOTAServer`` whose numpy round consumes the
 shared stage helpers (``eq25_factors`` / ``constraint7_powers``) so the
 three implementations cannot drift apart stage by stage.
+
+Each stage of the round runs under one of ``STAGE_SCOPES``
+(``jax.named_scope``), in the dense and the cohort step alike, so every
+device operation of the compiled scan carries its stage in its
+``op_name`` metadata and a profiler trace can be split by stage. The
+scopes are metadata only: without them the compiled program differs in
+instruction names alone.
 """
 from __future__ import annotations
 
@@ -96,6 +103,13 @@ from repro.core.compress import (dequantize_int8, ef_residual, gather_rows,
 from repro.core.power_control import (client_sq_norms, power_from_beta,
                                       similarity_factor, staleness_factor)
 from repro.core.scheduler import sched_advance, sched_broadcast
+
+# The round's stages as named scopes: scheduler advance, broadcast and
+# slot turnover; the eq.-25 stats sweep and screening; water-filling and
+# the cap (7); superposition, AWGN, the guarded update and rollback; local
+# training; the writes of the trained rows into the carry's planes.
+STAGE_SCOPES = ("paota.schedule", "paota.stats", "paota.power",
+                "paota.superpose", "paota.train", "paota.carry_write")
 
 
 class RoundCarry(NamedTuple):
@@ -457,6 +471,58 @@ def _cast_rows(tree, dtype):
     return jax.tree_util.tree_map(lambda l: l.astype(dtype), tree)
 
 
+def _carry_write(trained, new_global, carry: RoundCarry, row_select, dtype,
+                 tp=None):
+    """The restarters' trained rows into the carry's uncompressed planes:
+    ``(pending, deltas)``, each row taken from ``trained`` where
+    ``row_select`` picks it and kept from the carry elsewhere. The delta
+    rows are f32 ``trained - new_global`` before the storage cast."""
+    if tp is not None:
+        # TP-active carry writes: the payload planes hold only this
+        # device's TP-local block of each leaf, so the (TP-replicated)
+        # trained rows and new global are sliced down to the block first
+        # — after this the write is the general delta form below
+        from repro.sharding.tp import tp_slice
+        tdef = jax.tree_util.tree_structure(carry.deltas)
+        tr_l = jax.tree_util.tree_leaves(trained)
+        g_l = jax.tree_util.tree_leaves(new_global)
+        dl_l = jax.tree_util.tree_leaves(carry.deltas)
+        p_l = (jax.tree_util.tree_leaves(carry.pending)
+               if carry.pending is not None else [None] * len(tr_l))
+        new_p, new_d = [], []
+        for tr, g, dl, p, dim in zip(tr_l, g_l, dl_l, p_l, tp.leaf_dims):
+            if dim >= 0:
+                tr = tp_slice(tr, dim + 1, tp)
+                g = tp_slice(g, dim, tp)
+            if p is not None:
+                new_p.append(row_select(tr.astype(p.dtype), p))
+            new_d.append(row_select((tr - g[None]).astype(dl.dtype), dl))
+        pending = (jax.tree_util.tree_unflatten(tdef, new_p)
+                   if carry.pending is not None else None)
+        return pending, jax.tree_util.tree_unflatten(tdef, new_d)
+    pending = None if carry.pending is None else jax.tree_util.tree_map(
+        lambda tr, p: row_select(tr.astype(p.dtype), p),
+        trained, carry.pending)
+    if dtype == jnp.float32 and pending is not None:
+        # derive the delta rows from the NEW pending (identical values:
+        # ready rows of `pending` ARE the trained rows) — this lets XLA
+        # fuse the raveled concat straight into both carry writes
+        # instead of materializing a separate (K, d) trained plane
+        deltas = jax.tree_util.tree_map(
+            lambda p, dl, g: row_select(p - g[None], dl),
+            pending, carry.deltas, new_global)
+    else:
+        # bf16 storage (the delta MUST come from the f32 trained rows —
+        # deriving it from the already-rounded pending would cancel two
+        # large rounded models instead of rounding one small delta),
+        # and the pending-less transmit='delta' carry
+        deltas = jax.tree_util.tree_map(
+            lambda tr, dl, g: row_select((tr - g[None]).astype(dl.dtype),
+                                         dl),
+            trained, carry.deltas, new_global)
+    return pending, deltas
+
+
 def _slot_dtype(rcfg: RoundCfg) -> str:
     """Resolved compressed slot-value storage dtype."""
     return rcfg.slot_dtype or rcfg.pending_dtype
@@ -594,173 +660,143 @@ def paota_round_step(carry: RoundCarry, x, y, *, rcfg: RoundCfg,
     # carried latency draws (repro.core.scheduler.slot_ready) — one f32
     # rounding, bit-identical to the host reference's mask at any horizon;
     # `time` is report-only.
-    time = (carry.t + 1).astype(jnp.float32) * jnp.float32(rcfg.delta_t)
-    ready, stal = sched_advance(carry.ready, carry.busy_lat,
-                                carry.model_round, carry.t, rcfg.delta_t)
-    if streams.scenario is None:
-        # no scenario: uploaders = restarters = the ready set — this branch
-        # is the historical program, bit-identical op for op
-        upl = restart = ready
-    else:
-        # scenario masks (trace-time branch: the callback is None unless a
-        # scenario can actually mask): unavailable-but-ready clients HOLD
-        # their finished update and stay ready for a later slot (staleness
-        # keeps growing); dropped uploads are lost in transit but the
-        # client still restarts from the fresh broadcast
-        avail, drop = streams.scenario(carry.t)
-        upl = ready & avail & ~drop
-        restart = ready & avail
-    b = upl.astype(jnp.float32)
-    stal = jnp.where(upl, stal, 0).astype(jnp.float32)
+    with jax.named_scope("paota.schedule"):
+        time = (carry.t + 1).astype(jnp.float32) * jnp.float32(rcfg.delta_t)
+        ready, stal = sched_advance(carry.ready, carry.busy_lat,
+                                    carry.model_round, carry.t, rcfg.delta_t)
+        if streams.scenario is None:
+            # no scenario: uploaders = restarters = the ready set — this
+            # branch is the historical program, bit-identical op for op
+            upl = restart = ready
+        else:
+            # scenario masks (trace-time branch: the callback is None
+            # unless a scenario can actually mask): unavailable-but-ready
+            # clients HOLD their finished update and stay ready for a later
+            # slot (staleness keeps growing); dropped uploads are lost in
+            # transit but the client still restarts from the fresh broadcast
+            avail, drop = streams.scenario(carry.t)
+            upl = ready & avail & ~drop
+            restart = ready & avail
+        b = upl.astype(jnp.float32)
+        stal = jnp.where(upl, stal, 0).astype(jnp.float32)
 
     # 2. staleness + gradient-similarity factors (eq. 25) + the payload
     # norms for constraint (7): ONE sweep over the carried delta plane
     # (sweep 1 of 2)
-    payload = carry.deltas if rcfg.transmit_delta else carry.pending
-    rho, theta, w_norm2 = round_factors(
-        carry.deltas, None if rcfg.transmit_delta else carry.pending,
-        carry.global_vec, carry.prev_global, stal, rcfg.omega, tp=tp)
+    with jax.named_scope("paota.stats"):
+        payload = carry.deltas if rcfg.transmit_delta else carry.pending
+        rho, theta, w_norm2 = round_factors(
+            carry.deltas, None if rcfg.transmit_delta else carry.pending,
+            carry.global_vec, carry.prev_global, stal, rcfg.omega, tp=tp)
 
-    # 2b. containment (trace-time branch — screen=False emits the
-    # historical program op for op): rows the stats sweep exposed as
-    # corrupt (non-finite) or norm-fenced are masked out of this round's
-    # superposition exactly like phantom clients — b = 0, the payload row
-    # zeroed so every contraction sees exact +0.0, and the per-row scalars
-    # sanitized so the water-filling bounds never touch a NaN. The masking
-    # is shard-local and happens BEFORE the collective, so the sharded
-    # round still compiles to ONE cross-client psum.
-    n_screened = jnp.float32(0.0)
-    if rcfg.screen:
-        ok, theta, w_norm2 = _screen_ok(theta, w_norm2, rcfg)
-        n_screened = ksum(b * (~ok).astype(jnp.float32))
-        b = b * ok.astype(jnp.float32)
-        payload = _zero_rows(payload, ok)
+        # 2b. containment (trace-time branch — screen=False emits the
+        # historical program op for op): rows the stats sweep exposed as
+        # corrupt (non-finite) or norm-fenced are masked out of this
+        # round's superposition exactly like phantom clients — b = 0, the
+        # payload row zeroed so every contraction sees exact +0.0, and the
+        # per-row scalars sanitized so the water-filling bounds never touch
+        # a NaN. The masking is shard-local and happens BEFORE the
+        # collective, so the sharded round still compiles to ONE
+        # cross-client psum.
+        n_screened = jnp.float32(0.0)
+        if rcfg.screen:
+            ok, theta, w_norm2 = _screen_ok(theta, w_norm2, rcfg)
+            n_screened = ksum(b * (~ok).astype(jnp.float32))
+            b = b * ok.astype(jnp.float32)
+            payload = _zero_rows(payload, ok)
 
     # 3. P2 -> beta -> powers (exact water-filling, pure jnp; the grid and
     # golden-section reductions over K run as psums under sharding). At a
     # grouped non-sync period only the pod's own clients superpose, so the
     # P2 reductions stay intra-pod (per-pod water level) — no cross-pod
     # collective outside the sync.
-    wf_axes = axis_name if sync else (grouping.intra_axes or None)
-    p_max = jnp.full((k_local,), rcfg.p_max_watts, jnp.float32)
-    beta, p2_obj = waterfill_beta_jnp(rho, theta, p_max, b, rcfg.c1, rcfg.c0,
-                                      axis_name=wf_axes)
-    powers = power_from_beta(beta, rho, theta, p_max)
+    with jax.named_scope("paota.power"):
+        wf_axes = axis_name if sync else (grouping.intra_axes or None)
+        p_max = jnp.full((k_local,), rcfg.p_max_watts, jnp.float32)
+        beta, p2_obj = waterfill_beta_jnp(rho, theta, p_max, b, rcfg.c1,
+                                          rcfg.c0, axis_name=wf_axes)
+        powers = power_from_beta(beta, rho, theta, p_max)
 
-    # 4. instantaneous power constraint (7) under the sampled channel —
-    # the payload norms came with the stats sweep, no extra pass
-    h = streams.channel(carry.t)
-    powers = constraint7_powers(powers, payload, h, rcfg.p_max_watts,
-                                w_norm2=w_norm2)
+        # 4. instantaneous power constraint (7) under the sampled channel —
+        # the payload norms came with the stats sweep, no extra pass
+        h = streams.channel(carry.t)
+        powers = constraint7_powers(powers, payload, h, rcfg.p_max_watts,
+                                    w_norm2=w_norm2)
 
     # 5+6. AirComp superposition + AWGN + normalization (eqs. 6+8, sweep 2
     # of 2) and the zero-uploader-guarded update
-    held = carry.held
-    if not grouped:
-        # flat path: the superposition is ONE psum over the client axes
-        # (or the single-device einsum) with the noise joining once after
-        agg, varsigma = paota_aggregate_stacked(
-            payload, powers, b, streams.noise_key(carry.t), rcfg.sigma_n,
-            axis_name=axis_name, tp=tp)
-        new_global, new_prev = guarded_global_update(
-            carry.global_vec, carry.prev_global, agg, varsigma,
-            delta=rcfg.transmit_delta)
-    elif sync:
-        partial = paota_partial_stacked(payload, powers, b)
-        # held is replicated over the intra-pod shards, so scaling by
-        # 1/intra_shards makes the all-axes psum reproduce its cross-pod
-        # sum; at N=1 held == 0 and `partial + 0` is bit-exact — the sync
-        # psum IS the flat path's. This is the window's ONE cross-pod
-        # model-sized collective.
-        scale = jnp.float32(1.0 / grouping.intra_shards)
-        agg, varsigma = paota_finalize_stacked(
-            partial + held[0] * scale, payload, streams.noise_key(carry.t),
-            rcfg.sigma_n, axis_name=axis_name)
-        new_global, new_prev = guarded_global_update(
-            carry.global_vec, carry.prev_global, agg, varsigma,
-            delta=rcfg.transmit_delta)
-        held = jnp.zeros_like(held)
-    else:
-        # non-sync period: intra-pod partial only, weighted by the eq.-25
-        # staleness factor of its age at the sync slot (a static Python
-        # float — the window position is unrolled); the global holds.
-        partial = paota_partial_stacked(payload, powers, b,
-                                        axis_name=grouping.intra_axes or None)
-        age = float(rcfg.group_period - 1 - window_j)
-        held = held + jnp.float32(staleness_factor(age, rcfg.omega)) \
-            * partial[None, :]
-        varsigma = jnp.float32(0.0)
-        new_global, new_prev = carry.global_vec, carry.prev_global
+    with jax.named_scope("paota.superpose"):
+        held = carry.held
+        if not grouped:
+            # flat path: the superposition is ONE psum over the client axes
+            # (or the single-device einsum) with the noise joining once
+            # after
+            agg, varsigma = paota_aggregate_stacked(
+                payload, powers, b, streams.noise_key(carry.t), rcfg.sigma_n,
+                axis_name=axis_name, tp=tp)
+            new_global, new_prev = guarded_global_update(
+                carry.global_vec, carry.prev_global, agg, varsigma,
+                delta=rcfg.transmit_delta)
+        elif sync:
+            partial = paota_partial_stacked(payload, powers, b)
+            # held is replicated over the intra-pod shards, so scaling by
+            # 1/intra_shards makes the all-axes psum reproduce its
+            # cross-pod sum; at N=1 held == 0 and `partial + 0` is
+            # bit-exact — the sync psum IS the flat path's. This is the
+            # window's ONE cross-pod model-sized collective.
+            scale = jnp.float32(1.0 / grouping.intra_shards)
+            agg, varsigma = paota_finalize_stacked(
+                partial + held[0] * scale, payload,
+                streams.noise_key(carry.t), rcfg.sigma_n,
+                axis_name=axis_name)
+            new_global, new_prev = guarded_global_update(
+                carry.global_vec, carry.prev_global, agg, varsigma,
+                delta=rcfg.transmit_delta)
+            held = jnp.zeros_like(held)
+        else:
+            # non-sync period: intra-pod partial only, weighted by the
+            # eq.-25 staleness factor of its age at the sync slot (a static
+            # Python float — the window position is unrolled); the global
+            # holds.
+            partial = paota_partial_stacked(
+                payload, powers, b, axis_name=grouping.intra_axes or None)
+            age = float(rcfg.group_period - 1 - window_j)
+            held = held + jnp.float32(staleness_factor(age, rcfg.omega)) \
+                * partial[None, :]
+            varsigma = jnp.float32(0.0)
+            new_global, new_prev = carry.global_vec, carry.prev_global
 
-    # 6b. divergence rollback (trace-time branch; grouped non-sync periods
-    # hold the global, so only update periods are checked) — happens BEFORE
-    # the broadcast so a rolled-back round retrains from the restored model
-    good, good_n2 = carry.good_global, carry.good_norm2
-    rolled = jnp.float32(0.0)
-    if rcfg.divergence_factor > 0.0 and sync:
-        new_global, new_prev, good, good_n2, rolled = _divergence_rollback(
-            new_global, new_prev, carry, rcfg)
+        # 6b. divergence rollback (trace-time branch; grouped non-sync
+        # periods hold the global, so only update periods are checked) —
+        # happens BEFORE the broadcast so a rolled-back round retrains from
+        # the restored model
+        good, good_n2 = carry.good_global, carry.good_norm2
+        rolled = jnp.float32(0.0)
+        if rcfg.divergence_factor > 0.0 and sync:
+            new_global, new_prev, good, good_n2, rolled = \
+                _divergence_rollback(new_global, new_prev, carry, rcfg)
 
     # 7. broadcast w^{r+1}: every restarter — uploader, or dropped uploader
     # whose update was lost in transit — begins fresh local training (at a
     # grouped non-sync period the rebroadcast model is the held global).
     # The carry's delta rows are refreshed as f32 ``trained - w_g^{r+1}``
     # BEFORE the storage cast.
-    t_next = carry.t + 1
-    lat = streams.latencies(t_next)
-    n_ready, n_lat, n_model = sched_broadcast(
-        ready, carry.busy_lat, carry.model_round, restart, lat, t_next)
-    trained = streams.local_train(new_global, x, y, t_next)
+    with jax.named_scope("paota.schedule"):
+        t_next = carry.t + 1
+        lat = streams.latencies(t_next)
+        n_ready, n_lat, n_model = sched_broadcast(
+            ready, carry.busy_lat, carry.model_round, restart, lat, t_next)
+    with jax.named_scope("paota.train"):
+        trained = streams.local_train(new_global, x, y, t_next)
     dtype = _storage_dtype(rcfg)
 
     def row_select(new, old):
         m = restart.reshape((k_local,) + (1,) * (new.ndim - 1))
         return jnp.where(m, new, old)
 
-    if tp is not None:
-        # TP-active carry writes: the payload planes hold only this
-        # device's TP-local block of each leaf, so the (TP-replicated)
-        # trained rows and new global are sliced down to the block first
-        # — after this the write is the general delta form below
-        from repro.sharding.tp import tp_slice
-        tdef = jax.tree_util.tree_structure(carry.deltas)
-        tr_l = jax.tree_util.tree_leaves(trained)
-        g_l = jax.tree_util.tree_leaves(new_global)
-        dl_l = jax.tree_util.tree_leaves(carry.deltas)
-        p_l = (jax.tree_util.tree_leaves(carry.pending)
-               if carry.pending is not None else [None] * len(tr_l))
-        new_p, new_d = [], []
-        for tr, g, dl, p, dim in zip(tr_l, g_l, dl_l, p_l, tp.leaf_dims):
-            if dim >= 0:
-                tr = tp_slice(tr, dim + 1, tp)
-                g = tp_slice(g, dim, tp)
-            if p is not None:
-                new_p.append(row_select(tr.astype(p.dtype), p))
-            new_d.append(row_select((tr - g[None]).astype(dl.dtype), dl))
-        pending = (jax.tree_util.tree_unflatten(tdef, new_p)
-                   if carry.pending is not None else None)
-        deltas = jax.tree_util.tree_unflatten(tdef, new_d)
-    else:
-        pending = None if carry.pending is None else jax.tree_util.tree_map(
-            lambda tr, p: row_select(tr.astype(p.dtype), p),
-            trained, carry.pending)
-        if dtype == jnp.float32 and pending is not None:
-            # derive the delta rows from the NEW pending (identical values:
-            # ready rows of `pending` ARE the trained rows) — this lets XLA
-            # fuse the raveled concat straight into both carry writes
-            # instead of materializing a separate (K, d) trained plane
-            deltas = jax.tree_util.tree_map(
-                lambda p, dl, g: row_select(p - g[None], dl),
-                pending, carry.deltas, new_global)
-        else:
-            # bf16 storage (the delta MUST come from the f32 trained rows —
-            # deriving it from the already-rounded pending would cancel two
-            # large rounded models instead of rounding one small delta),
-            # and the pending-less transmit='delta' carry
-            deltas = jax.tree_util.tree_map(
-                lambda tr, dl, g: row_select((tr - g[None]).astype(dl.dtype),
-                                             dl),
-                trained, carry.deltas, new_global)
+    with jax.named_scope("paota.carry_write"):
+        pending, deltas = _carry_write(trained, new_global, carry,
+                                       row_select, dtype, tp)
 
     n_upl = ksum(b)
     denom = jnp.maximum(n_upl, 1.0)
@@ -838,20 +874,22 @@ def _cohort_round_step(carry: RoundCarry, x, y, *, rcfg: RoundCfg,
     # 1. (K,) state plane advance + scenario masks (same stages as the
     # dense step — sched_advance only ever flips clients whose carried
     # latency draw is finite, i.e. the in-flight cohort)
-    time = (carry.t + 1).astype(jnp.float32) * jnp.float32(rcfg.delta_t)
-    ready, stal_k = sched_advance(carry.ready, carry.busy_lat,
-                                  carry.model_round, carry.t, rcfg.delta_t)
-    if streams.scenario is None:
-        avail = jnp.ones((k_local,), bool)
-        upl_k = depart_k = ready
-    else:
-        avail, drop = streams.scenario(carry.t)
-        upl_k = ready & avail & ~drop
-        depart_k = ready & avail
+    with jax.named_scope("paota.schedule"):
+        time = (carry.t + 1).astype(jnp.float32) * jnp.float32(rcfg.delta_t)
+        ready, stal_k = sched_advance(carry.ready, carry.busy_lat,
+                                      carry.model_round, carry.t,
+                                      rcfg.delta_t)
+        if streams.scenario is None:
+            avail = jnp.ones((k_local,), bool)
+            upl_k = depart_k = ready
+        else:
+            avail, drop = streams.scenario(carry.t)
+            upl_k = ready & avail & ~drop
+            depart_k = ready & avail
 
-    # slot view of the (K,) state: gather by occupant, mask dead slots
-    b = (live & upl_k[occ]).astype(jnp.float32)
-    stal = jnp.where(live, stal_k[occ], 0).astype(jnp.float32)
+        # slot view of the (K,) state: gather by occupant, mask dead slots
+        b = (live & upl_k[occ]).astype(jnp.float32)
+        stal = jnp.where(live, stal_k[occ], 0).astype(jnp.float32)
 
     # 2-4. identical per-row stages over the m cohort rows (sweep 1: fused
     # stats; P2 water-filling; constraint (7) under the gathered channel).
@@ -859,63 +897,66 @@ def _cohort_round_step(carry: RoundCarry, x, y, *, rcfg: RoundCfg,
     # the PR 7 program op for op): the stats sweep runs on the (m, s)
     # compressed rows + EF residuals; at the static s == d identity the
     # dense formulations route unchanged (bit-identity with compress off).
-    payload = carry.deltas if rcfg.transmit_delta else carry.pending
-    if rcfg.compress:
-        d_model = carry.global_vec.shape[0]
-        identity = rcfg.compress_s >= d_model
-        # identity support + int8: the dense stages need the dequantized
-        # rows (f32/bf16 identity rows pass through untouched — the
-        # bit-identity claim is about THOSE)
-        v_id = (carry.deltas if carry.slot_scale is None
-                else dequantize_int8(carry.deltas, carry.slot_scale))
-        if identity:
-            rho, theta, w_norm2 = round_factors(
-                v_id, None, carry.global_vec, carry.prev_global,
-                stal, rcfg.omega)
-        else:
-            rho, theta, w_norm2 = compressed_round_factors(
-                carry.deltas, carry.slot_idx, carry.slot_resid,
-                carry.slot_resid_idx, carry.global_vec, carry.prev_global,
-                stal, rcfg.omega, scale=carry.slot_scale)
-    else:
-        rho, theta, w_norm2 = round_factors(
-            carry.deltas, None if rcfg.transmit_delta else carry.pending,
-            carry.global_vec, carry.prev_global, stal, rcfg.omega)
-
-    # 2b. containment over the cohort slots (same contract as the dense
-    # step's: corrupt/fenced rows leave the superposition as exact zeros
-    # — the phantom-slot masking — and the per-row scalars are sanitized
-    # before water-filling; trace-time branch, screen=False is the
-    # unscreened program op for op). Compressed slots zero both the value
-    # rows and the dequantization scales, so an int8 slot with a NaN
-    # absmax scale contributes 0 * 0, never 0 * NaN.
-    n_screened = jnp.float32(0.0)
-    vals_s, scale_s = carry.deltas, carry.slot_scale
-    if rcfg.screen:
-        ok, theta, w_norm2 = _screen_ok(theta, w_norm2, rcfg)
-        n_screened = ksum(b * (~ok).astype(jnp.float32))
-        b = b * ok.astype(jnp.float32)
+    with jax.named_scope("paota.stats"):
+        payload = carry.deltas if rcfg.transmit_delta else carry.pending
         if rcfg.compress:
-            vals_s = _zero_rows(vals_s, ok)
-            if scale_s is not None:
-                scale_s = jnp.where(ok, scale_s, 0.0)
-            v_id = _zero_rows(v_id, ok)
+            d_model = carry.global_vec.shape[0]
+            identity = rcfg.compress_s >= d_model
+            # identity support + int8: the dense stages need the
+            # dequantized rows (f32/bf16 identity rows pass through
+            # untouched — the bit-identity claim is about THOSE)
+            v_id = (carry.deltas if carry.slot_scale is None
+                    else dequantize_int8(carry.deltas, carry.slot_scale))
+            if identity:
+                rho, theta, w_norm2 = round_factors(
+                    v_id, None, carry.global_vec, carry.prev_global,
+                    stal, rcfg.omega)
+            else:
+                rho, theta, w_norm2 = compressed_round_factors(
+                    carry.deltas, carry.slot_idx, carry.slot_resid,
+                    carry.slot_resid_idx, carry.global_vec,
+                    carry.prev_global, stal, rcfg.omega,
+                    scale=carry.slot_scale)
         else:
-            payload = _zero_rows(payload, ok)
-    p_max = jnp.full((m,), rcfg.p_max_watts, jnp.float32)
-    # P2 is solved over the slots in client-id order. Its objective is
-    # flat near the optimum (cells a float ulp apart), so the K-sums'
-    # association order picks beta; in slot order that order followed the
-    # refill history and the host's SIMD width, not the client set.
-    order = jnp.argsort(jnp.where(live, occ, k_local))
-    beta_o, p2_obj = waterfill_beta_jnp(rho[order], theta[order], p_max,
-                                        b[order], rcfg.c1, rcfg.c0,
-                                        axis_name=axis_name)
-    beta = jnp.zeros_like(beta_o).at[order].set(beta_o)
-    powers = power_from_beta(beta, rho, theta, p_max)
-    h = jnp.where(live, streams.channel(carry.t)[occ], 0.0)
-    powers = constraint7_powers(powers, payload, h, rcfg.p_max_watts,
-                                w_norm2=w_norm2)
+            rho, theta, w_norm2 = round_factors(
+                carry.deltas, None if rcfg.transmit_delta else carry.pending,
+                carry.global_vec, carry.prev_global, stal, rcfg.omega)
+
+        # 2b. containment over the cohort slots (same contract as the dense
+        # step's: corrupt/fenced rows leave the superposition as exact
+        # zeros — the phantom-slot masking — and the per-row scalars are
+        # sanitized before water-filling; trace-time branch, screen=False
+        # is the unscreened program op for op). Compressed slots zero both
+        # the value rows and the dequantization scales, so an int8 slot
+        # with a NaN absmax scale contributes 0 * 0, never 0 * NaN.
+        n_screened = jnp.float32(0.0)
+        vals_s, scale_s = carry.deltas, carry.slot_scale
+        if rcfg.screen:
+            ok, theta, w_norm2 = _screen_ok(theta, w_norm2, rcfg)
+            n_screened = ksum(b * (~ok).astype(jnp.float32))
+            b = b * ok.astype(jnp.float32)
+            if rcfg.compress:
+                vals_s = _zero_rows(vals_s, ok)
+                if scale_s is not None:
+                    scale_s = jnp.where(ok, scale_s, 0.0)
+                v_id = _zero_rows(v_id, ok)
+            else:
+                payload = _zero_rows(payload, ok)
+    with jax.named_scope("paota.power"):
+        p_max = jnp.full((m,), rcfg.p_max_watts, jnp.float32)
+        # P2 is solved over the slots in client-id order. Its objective is
+        # flat near the optimum (cells a float ulp apart), so the K-sums'
+        # association order picks beta; in slot order that order followed
+        # the refill history and the host's SIMD width, not the client set.
+        order = jnp.argsort(jnp.where(live, occ, k_local))
+        beta_o, p2_obj = waterfill_beta_jnp(rho[order], theta[order], p_max,
+                                            b[order], rcfg.c1, rcfg.c0,
+                                            axis_name=axis_name)
+        beta = jnp.zeros_like(beta_o).at[order].set(beta_o)
+        powers = power_from_beta(beta, rho, theta, p_max)
+        h = jnp.where(live, streams.channel(carry.t)[occ], 0.0)
+        powers = constraint7_powers(powers, payload, h, rcfg.p_max_watts,
+                                    w_norm2=w_norm2)
 
     # 5+6. AirComp over the cohort rows (sweep 2) + the guarded update —
     # an all-masked cohort degenerates to the zero-uploader hold exactly
@@ -923,131 +964,131 @@ def _cohort_round_step(carry: RoundCarry, x, y, *, rcfg: RoundCfg,
     # the gather-superpose kernel decompresses INTO the superposition
     # (eq. 8 in d-space) before the global update — the stored int8 plane
     # feeds it directly with its scale folded into the weights.
-    if rcfg.compress and not identity:
-        agg, varsigma = paota_aggregate_compressed(
-            vals_s, carry.slot_idx, powers, b,
-            streams.noise_key(carry.t), rcfg.sigma_n, d_model,
-            scale=scale_s, axis_name=axis_name)
-    else:
-        agg, varsigma = paota_aggregate_stacked(
-            v_id if rcfg.compress else payload, powers, b,
-            streams.noise_key(carry.t), rcfg.sigma_n, axis_name=axis_name)
-    new_global, new_prev = guarded_global_update(
-        carry.global_vec, carry.prev_global, agg, varsigma,
-        delta=rcfg.transmit_delta)
+    with jax.named_scope("paota.superpose"):
+        if rcfg.compress and not identity:
+            agg, varsigma = paota_aggregate_compressed(
+                vals_s, carry.slot_idx, powers, b,
+                streams.noise_key(carry.t), rcfg.sigma_n, d_model,
+                scale=scale_s, axis_name=axis_name)
+        else:
+            agg, varsigma = paota_aggregate_stacked(
+                v_id if rcfg.compress else payload, powers, b,
+                streams.noise_key(carry.t), rcfg.sigma_n,
+                axis_name=axis_name)
+        new_global, new_prev = guarded_global_update(
+            carry.global_vec, carry.prev_global, agg, varsigma,
+            delta=rcfg.transmit_delta)
 
-    # 6b. divergence rollback (trace-time branch) — before the broadcast,
-    # so a rolled-back round reschedules/trains from the restored model
-    good, good_n2 = carry.good_global, carry.good_norm2
-    rolled = jnp.float32(0.0)
-    if rcfg.divergence_factor > 0.0:
-        new_global, new_prev, good, good_n2, rolled = _divergence_rollback(
-            new_global, new_prev, carry, rcfg)
+        # 6b. divergence rollback (trace-time branch) — before the
+        # broadcast, so a rolled-back round reschedules/trains from the
+        # restored model
+        good, good_n2 = carry.good_global, carry.good_norm2
+        rolled = jnp.float32(0.0)
+        if rcfg.divergence_factor > 0.0:
+            new_global, new_prev, good, good_n2, rolled = \
+                _divergence_rollback(new_global, new_prev, carry, rcfg)
 
-    # 7a. slot turnover: departing occupants (uploaded, or upload dropped
-    # in transit) free their slots; available idle clients fill them in
-    # priority order. `in_flight` scatters the retained occupancy back to
-    # (K,); dead slots contribute nothing anywhere (live = False).
-    depart = live & depart_k[occ]
-    stay = live & ~depart
-    in_flight = jnp.zeros((k_local,), bool).at[occ].max(stay,
-                                                        mode="drop")
-    prio = streams.sched_priority(carry.t)
-    score = jnp.where(avail & ~in_flight, prio, -jnp.inf)
-    top_score, top_ids = jax.lax.top_k(score, m)
-    n_cand = jnp.sum((top_score > -jnp.inf).astype(jnp.int32))
-    free = ~stay
-    free_rank = jnp.cumsum(free.astype(jnp.int32)) - 1
-    take = free & (free_rank < n_cand)
-    new_occ = jnp.where(take, top_ids[jnp.clip(free_rank, 0, m - 1)],
-                        occ).astype(jnp.int32)
-    new_live = stay | take
+    with jax.named_scope("paota.schedule"):
+        # 7a. slot turnover: departing occupants (uploaded, or upload
+        # dropped in transit) free their slots; available idle clients fill
+        # them in priority order. `in_flight` scatters the retained
+        # occupancy back to (K,); dead slots contribute nothing anywhere
+        # (live = False).
+        depart = live & depart_k[occ]
+        stay = live & ~depart
+        in_flight = jnp.zeros((k_local,), bool).at[occ].max(stay,
+                                                            mode="drop")
+        prio = streams.sched_priority(carry.t)
+        score = jnp.where(avail & ~in_flight, prio, -jnp.inf)
+        top_score, top_ids = jax.lax.top_k(score, m)
+        n_cand = jnp.sum((top_score > -jnp.inf).astype(jnp.int32))
+        free = ~stay
+        free_rank = jnp.cumsum(free.astype(jnp.int32)) - 1
+        take = free & (free_rank < n_cand)
+        new_occ = jnp.where(take, top_ids[jnp.clip(free_rank, 0, m - 1)],
+                            occ).astype(jnp.int32)
+        new_live = stay | take
 
-    # 7b. (K,) plane bookkeeping: departed-but-unscheduled clients go idle
-    # (busy_lat = +inf — never ready again until rescheduled), scheduled
-    # clients get the fresh broadcast via the SAME sched_broadcast masked
-    # update the dense path uses
-    sched_k = jnp.zeros((k_local,), bool).at[new_occ].max(take, mode="drop")
-    t_next = carry.t + 1
-    lat_full = streams.latencies(t_next)
-    departed_k = jnp.zeros((k_local,), bool).at[occ].max(depart, mode="drop")
-    idle = departed_k & ~sched_k
-    ready = jnp.where(idle, False, ready)
-    busy = jnp.where(idle, jnp.asarray(jnp.inf, carry.busy_lat.dtype),
-                     carry.busy_lat)
-    n_ready, n_lat, n_model = sched_broadcast(
-        ready, busy, carry.model_round, sched_k, lat_full, t_next)
+        # 7b. (K,) plane bookkeeping: departed-but-unscheduled clients go
+        # idle (busy_lat = +inf — never ready again until rescheduled),
+        # scheduled clients get the fresh broadcast via the SAME
+        # sched_broadcast masked update the dense path uses
+        sched_k = jnp.zeros((k_local,), bool).at[new_occ].max(take,
+                                                              mode="drop")
+        t_next = carry.t + 1
+        lat_full = streams.latencies(t_next)
+        departed_k = jnp.zeros((k_local,), bool).at[occ].max(depart,
+                                                             mode="drop")
+        idle = departed_k & ~sched_k
+        ready = jnp.where(idle, False, ready)
+        busy = jnp.where(idle, jnp.asarray(jnp.inf, carry.busy_lat.dtype),
+                         carry.busy_lat)
+        n_ready, n_lat, n_model = sched_broadcast(
+            ready, busy, carry.model_round, sched_k, lat_full, t_next)
 
-    # EF residual hand-off on slot turnover (trace-time branch): FIRST
-    # every departing slot parks its residual on the owning client's
-    # (K, s) row (the scatter half of the tentpole's "(K, s) residual
-    # row"), THEN the newly scheduled occupants pick their parked rows
-    # back up (a same-round depart -> reschedule resumes the residual it
-    # just parked), THEN the consumed rows zero — the parked plane only
-    # ever holds errors nobody is currently training against.
-    resid_val = resid_idx = pr_val = pr_idx = None
-    if rcfg.compress and rcfg.error_feedback:
-        park_row = jnp.where(depart, occ, k_local)      # OOB = no write
-        resid_val = carry.resid_val.at[park_row].set(carry.slot_resid,
-                                                     mode="drop")
-        resid_idx = carry.resid_idx.at[park_row].set(carry.slot_resid_idx,
-                                                     mode="drop")
-        pr_val = jnp.where(take[:, None], resid_val[new_occ], 0.0)
-        if rcfg.screen:
-            # a screened slot's parked residual may be the corrupt row's
-            # NaN complement — resuming it would re-poison every later
-            # round of an otherwise-recovered client
-            pr_val = jnp.where(jnp.isfinite(pr_val), pr_val, 0.0)
-        pr_idx = resid_idx[new_occ]
-        consumed = jnp.where(take, new_occ, k_local)
-        resid_val = resid_val.at[consumed].set(0.0, mode="drop")
+        # EF residual hand-off on slot turnover (trace-time branch): FIRST
+        # every departing slot parks its residual on the owning client's
+        # (K, s) row (the scatter half of the tentpole's "(K, s) residual
+        # row"), THEN the newly scheduled occupants pick their parked rows
+        # back up (a same-round depart -> reschedule resumes the residual
+        # it just parked), THEN the consumed rows zero — the parked plane
+        # only ever holds errors nobody is currently training against.
+        resid_val = resid_idx = pr_val = pr_idx = None
+        if rcfg.compress and rcfg.error_feedback:
+            park_row = jnp.where(depart, occ, k_local)      # OOB = no write
+            resid_val = carry.resid_val.at[park_row].set(carry.slot_resid,
+                                                         mode="drop")
+            resid_idx = carry.resid_idx.at[park_row].set(
+                carry.slot_resid_idx, mode="drop")
+            pr_val = jnp.where(take[:, None], resid_val[new_occ], 0.0)
+            if rcfg.screen:
+                # a screened slot's parked residual may be the corrupt
+                # row's NaN complement — resuming it would re-poison every
+                # later round of an otherwise-recovered client
+                pr_val = jnp.where(jnp.isfinite(pr_val), pr_val, 0.0)
+            pr_idx = resid_idx[new_occ]
+            consumed = jnp.where(take, new_occ, k_local)
+            resid_val = resid_val.at[consumed].set(0.0, mode="drop")
 
     # 7c. cohort training: ONLY the m slot rows materialize model-sized
     # work — the newly scheduled slots take their trained rows (f32 delta
     # before the storage cast, same rules as the dense path); retained
     # slots keep their in-flight payload; dead slots keep masked garbage
-    trained = streams.cohort_train(new_global, x, y, t_next, new_occ)
+    with jax.named_scope("paota.train"):
+        trained = streams.cohort_train(new_global, x, y, t_next, new_occ)
     dtype = _storage_dtype(rcfg)
 
     def row_select(new, old):
         msk = take.reshape((m,) + (1,) * (new.ndim - 1))
         return jnp.where(msk, new, old)
 
-    if rcfg.compress:
-        # compressed store: the f32 delta rows are EF-compensated with the
-        # resumed parked residuals (decompressed transiently — the carry
-        # never holds an (m, d) plane), then support-selected, stored, and
-        # their exact f32 residual re-sparsified. Non-take rows keep every
-        # old slot plane (garbage residual gathers for them are discarded
-        # here). Raveled single-leaf: `trained` is a bare (m, d) array.
-        comp = trained - new_global[None]
-        if pr_val is not None:
-            comp = comp + scatter_rows(pr_val, pr_idx, d_model)
-        stored, idx_new, scale_new, e_val, e_idx = _compress_plane(
-            comp, rcfg=rcfg, streams=streams, t=t_next)
-        pending = None
-        deltas = row_select(stored, carry.deltas)
-        slot_idx = row_select(idx_new, carry.slot_idx)
-        slot_scale = (None if scale_new is None
-                      else jnp.where(take, scale_new, carry.slot_scale))
-        slot_resid = (None if e_val is None
-                      else row_select(e_val, carry.slot_resid))
-        slot_resid_idx = (None if e_idx is None
-                          else row_select(e_idx, carry.slot_resid_idx))
-    else:
-        pending = None if carry.pending is None else jax.tree_util.tree_map(
-            lambda tr, p: row_select(tr.astype(p.dtype), p),
-            trained, carry.pending)
-        if dtype == jnp.float32 and pending is not None:
-            deltas = jax.tree_util.tree_map(
-                lambda p, dl, g: row_select(p - g[None], dl),
-                pending, carry.deltas, new_global)
+    with jax.named_scope("paota.carry_write"):
+        if rcfg.compress:
+            # compressed store: the f32 delta rows are EF-compensated with
+            # the resumed parked residuals (decompressed transiently — the
+            # carry never holds an (m, d) plane), then support-selected,
+            # stored, and their exact f32 residual re-sparsified. Non-take
+            # rows keep every old slot plane (garbage residual gathers for
+            # them are discarded here). Raveled single-leaf: `trained` is a
+            # bare (m, d) array.
+            comp = trained - new_global[None]
+            if pr_val is not None:
+                comp = comp + scatter_rows(pr_val, pr_idx, d_model)
+            stored, idx_new, scale_new, e_val, e_idx = _compress_plane(
+                comp, rcfg=rcfg, streams=streams, t=t_next)
+            pending = None
+            deltas = row_select(stored, carry.deltas)
+            slot_idx = row_select(idx_new, carry.slot_idx)
+            slot_scale = (None if scale_new is None
+                          else jnp.where(take, scale_new, carry.slot_scale))
+            slot_resid = (None if e_val is None
+                          else row_select(e_val, carry.slot_resid))
+            slot_resid_idx = (None if e_idx is None
+                              else row_select(e_idx, carry.slot_resid_idx))
         else:
-            deltas = jax.tree_util.tree_map(
-                lambda tr, dl, g: row_select((tr - g[None]).astype(dl.dtype),
-                                             dl),
-                trained, carry.deltas, new_global)
-        slot_idx = slot_scale = slot_resid = slot_resid_idx = None
+            pending, deltas = _carry_write(trained, new_global, carry,
+                                           row_select, dtype)
+            slot_idx = slot_scale = slot_resid = slot_resid_idx = None
 
     n_upl = ksum(b)
     denom = jnp.maximum(n_upl, 1.0)

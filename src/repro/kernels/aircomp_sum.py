@@ -83,6 +83,7 @@ def aircomp_sum_pallas(stacked: jnp.ndarray, bp: jnp.ndarray,
         out_specs=pl.BlockSpec((1, block_d), lambda i: (0, i)),
         out_shape=out_struct((1, dp), jnp.float32, stacked, bp, noise),
         interpret=interpret,
+        name="aircomp_sum_pallas",
     )(bp[None, :].astype(jnp.float32), stacked, noise[None, :])
     return out[0, :d]
 
@@ -157,6 +158,7 @@ def superpose_normalize_pallas(stacked: jnp.ndarray, powers: jnp.ndarray,
         out_shape=[out_struct((1, dp), jnp.float32, *ops_),
                    out_struct((1, 1), jnp.float32, powers, mask)],
         interpret=interpret,
+        name="superpose_normalize_pallas",
     )(powers[None, :].astype(jnp.float32), mask[None, :].astype(jnp.float32),
       stacked, noise[None, :])
     return agg[0, :d], vs[0, 0]
@@ -429,6 +431,7 @@ def gather_superpose_pallas(values: jnp.ndarray, idx: jnp.ndarray,
         out_shape=[out_struct((1, dp), jnp.float32, *ops_),
                    out_struct((1, 1), jnp.float32, bp32)],
         interpret=interpret,
+        name="gather_superpose_pallas",
     )(bp32[None, :], wflat, vflat, iflat, noise[None, :])
     return agg[0, :d], vs[0, 0]
 

@@ -61,6 +61,7 @@ def cosine_partials_pallas(deltas: jnp.ndarray, g: jnp.ndarray, *,
         out_specs=pl.BlockSpec((k, 2), lambda i: (0, 0)),  # revisited accumulator
         out_shape=out_struct((k, 2), jnp.float32, deltas, g),
         interpret=interpret,
+        name="cosine_partials_pallas",
     )(deltas, g[None, :])
 
 

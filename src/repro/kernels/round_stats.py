@@ -154,11 +154,13 @@ def round_stats_pallas(deltas: jnp.ndarray, g: jnp.ndarray,
         stats, gn2 = pl.pallas_call(
             _kernel, grid=grid, in_specs=[stripe, gspec],
             out_specs=out_specs, out_shape=out_shape, interpret=interpret,
+            name="round_stats_pallas",
         )(deltas, g[None, :])
     else:
         stats, gn2 = pl.pallas_call(
             _kernel_payload, grid=grid, in_specs=[stripe, stripe, gspec],
             out_specs=out_specs, out_shape=out_shape, interpret=interpret,
+            name="round_stats_pallas",
         )(deltas, payload, g[None, :])
     return stats, gn2[0, 0]
 

@@ -135,3 +135,61 @@ def test_one_round_scan_has_no_cross_program_prefetch(one_chip):
         n_rounds=1).compile(compiler_options=TPU_SCAN_OPTIONS).as_text()
     assert "cross_program_prefetch_index" not in text
     assert text.count("tpu_custom_call") > 0
+
+
+KERNEL_STAGES = {
+    # driver options: (kernel instruction, the stage scope it runs under)
+    "dense": ({}, [("round_stats_pallas", "paota.stats"),
+                   ("superpose_normalize_pallas", "paota.superpose")]),
+    "cohort_randmask_int8": (
+        dict(cohort_size=4, compress="randmask", compress_ratio=1 / 16,
+             slot_dtype="int8"),
+        [("gather_superpose_pallas", "paota.superpose")]),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_STAGES))
+def test_round_kernels_sit_under_their_stage_scopes(one_chip, case):
+    """In the two-round scan compiled for the chip, each round kernel's
+    custom call is named after the kernel and carries, in its ``op_name``,
+    the stage scope it runs under and the kernel's own name: the trace
+    readers find kernels and stages by these names."""
+    import re
+
+    from repro.core import ChannelConfig, SchedulerConfig
+    from repro.data.partition import partition_noniid
+    from repro.data.pipeline import build_federation
+    from repro.data.synthetic import make_mnist_like
+    from repro.fl import FLClient, FusedPAOTA, PAOTAConfig
+    from repro.fl.fused import TPU_SCAN_OPTIONS
+    from repro.models.mlp import init_mlp_params, mlp_loss
+
+    kw, kernels = KERNEL_STAGES[case]
+    k = 8
+    x, y, _, _ = make_mnist_like(n_train=400, n_test=10)
+    clients = [FLClient(d, mlp_loss, batch_size=16, lr=0.1, local_steps=2)
+               for d in build_federation(x, y, partition_noniid(
+                   y, n_clients=k, seed=0))]
+    srv = FusedPAOTA(init_mlp_params(jax.random.PRNGKey(0)), clients,
+                     ChannelConfig(), SchedulerConfig(n_clients=k, seed=1),
+                     PAOTAConfig(transmit="delta" if kw else "model"), **kw)
+    put = lambda t: jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, a.dtype), t)
+    carry = jax.eval_shape(srv._init_carry, srv._init_global,
+                           srv.engine._x, srv.engine._y)
+    text = srv._jit_scan.lower(
+        put(carry), put(srv.engine._x), put(srv.engine._y),
+        n_rounds=2).compile(compiler_options=TPU_SCAN_OPTIONS).as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for kernel, scope in kernels:
+        mine = [c for c in calls
+                if re.match(rf"\s*(ROOT\s+)?%{kernel}(\.\d+)*\s*=", c)]
+        assert mine, kernel
+        for c in mine:
+            op_name = re.search(r', metadata=\{[^}]*op_name="([^"]*)"',
+                                c).group(1)
+            # the kernel's own name (``pallas_call(name=...)``) inside
+            # the stage
+            assert f"/{scope}/" in op_name, op_name
+            assert f"/{kernel}/pallas_call" in op_name, op_name
