@@ -79,14 +79,64 @@ def stacked_tree_noise(key, stacked_leaves, sigma_n):
     split into leaves: the 4-leaf pytree form of an MLP and its raveled
     (K, D) form consume bit-identical realizations (the single-leaf split
     is exactly the historical ``normal(key, (D,))``), which is what the
-    pytree-vs-raveled equivalence tests pin."""
+    pytree-vs-raveled equivalence tests pin.
+
+    Where the flat draw hashes each element's flat index (threefry,
+    partitionable), each leaf's slice is drawn in the leaf's own shape
+    (``_normal_at``), bit for bit the same values: on a TPU a slice of a
+    flat draw reshaped to a tiled (S, C) leaf is a relayout copy of the
+    model-sized f32 noise in front of the superposition kernel. Other key
+    types take the flat draw."""
     sizes = [int(np.prod(l.shape[1:])) for l in stacked_leaves]
+    if _counter_draw(key, sum(sizes)):
+        out, off = [], 0
+        for leaf, size in zip(stacked_leaves, sizes):
+            out.append(sigma_n * _normal_at(key, off, leaf.shape[1:]))
+            off += size
+        return out
     flat = sigma_n * jax.random.normal(key, (sum(sizes),), jnp.float32)
     out, off = [], 0
     for leaf, size in zip(stacked_leaves, sizes):
         out.append(flat[off:off + size].reshape(leaf.shape[1:]))
         off += size
     return out
+
+
+def _counter_draw(key, total: int) -> bool:
+    """Whether ``jax.random.normal(key, (total,))`` is the partitionable
+    threefry draw, each element a hash of its flat index, that
+    ``_normal_at`` reproduces."""
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        impl = str(jax.random.key_impl(key))
+    else:
+        impl = jax.config.jax_default_prng_impl
+    return ("threefry" in impl and jax.config.jax_threefry_partitionable
+            and total < 2 ** 32)
+
+
+def _normal_at(key, offset: int, shape) -> jnp.ndarray:
+    """``jax.random.normal(key, (N,), float32)[offset:offset + size]
+    .reshape(shape)`` for any N past that slice, bit for bit, drawn in
+    ``shape``: the threefry hash of each element's flat index (its high
+    word is 0 below 2^32), then ``jax.random.uniform``'s mantissa fill on
+    [nextafter(-1, 0), 1) and ``normal``'s sqrt(2) erfinv — the steps of
+    ``jax.random.normal``, applied to the slice's counters only."""
+    from jax.extend.random import threefry2x32_p
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        key = jax.random.key_data(key)
+    count = jnp.full(shape, offset, jnp.uint32)
+    for dim in range(len(shape)):
+        stride = jnp.uint32(int(np.prod(shape[dim + 1:])))
+        count = count + jax.lax.broadcasted_iota(jnp.uint32, shape, dim) * stride
+    hi, lo = threefry2x32_p.bind(key[0], key[1], jnp.zeros(shape, jnp.uint32),
+                                 count)
+    one = np.array(1.0, np.float32).view(np.uint32)
+    mant = jax.lax.shift_right_logical(hi ^ lo, jnp.uint32(9)) | one
+    floats = jax.lax.bitcast_convert_type(mant, jnp.float32) - 1.0
+    lo_f = np.nextafter(np.float32(-1.0), np.float32(0.0))
+    u = jax.lax.max(jnp.float32(lo_f),
+                    floats * (np.float32(1.0) - lo_f) + lo_f)
+    return np.float32(np.sqrt(2)) * jax.lax.erf_inv(u)
 
 
 def paota_aggregate_stacked(stacked_models, powers: jnp.ndarray,
@@ -168,8 +218,7 @@ def paota_aggregate_stacked(stacked_models, powers: jnp.ndarray,
     noise = stacked_tree_noise(key, leaves, sigma_n)
     agg = []
     for leaf, nz in zip(leaves, noise):
-        out, _ = superpose_normalize(leaf.reshape((leaf.shape[0], -1)),
-                                     powers, mask, nz.reshape(-1),
+        out, _ = superpose_normalize(leaf, powers, mask, nz,
                                      vs_min=VARSIGMA_MIN)
         agg.append(out.reshape(leaf.shape[1:]))
     return jax.tree_util.tree_unflatten(treedef, agg), varsigma

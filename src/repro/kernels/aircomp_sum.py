@@ -10,9 +10,15 @@ runs this every aggregation period over the full model vector, so the fused
 streaming form is the memory-bound kernel the roofline wants: bytes moved
 = K*D + D reads + D writes, arithmetic intensity ~= 1 MAC/element.
 
-Tiling: grid over D in BLOCK_D-wide stripes (lane-dim multiples of 128);
-the K axis stays resident in VMEM per stripe ((K, BLOCK_D) tile). The
-reduction over K is a (1,K)x(K,BLOCK_D) matmul -> MXU-friendly.
+``superpose_normalize_pallas`` runs one ``pallas_call`` per leaf of the
+round's pytree and reads each leaf in its own layout
+(``repro.kernels.tiling``): a leaf of rank >= 3 in (K, br, C) blocks of
+about 2 MiB, as K f32 multiply-adds per r-row chunk on the VPU, its
+aggregate written straight into the leaf's shape; a rank-2 leaf (a
+raveled model, cohort slot rows) in byte-sized (K, block_d) lane stripes,
+the reduction over K a (1, K)x(K, block_d) f32 matmul. The host server's
+raveled ``aircomp_sum`` (``repro.kernels.ops``) is the same kernel with
+the mask all ones.
 
 The leading (client) axis is whatever plane the round carries: all K
 clients on the dense path, or the (m, d) active-cohort slot rows under
@@ -27,10 +33,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.round_stats import out_struct
+from repro.kernels.tiling import (PLANE_BLOCK_BYTES, native_rows, native_view,
+                                  per_block, stripe_lanes, sublanes)
 
 
+# d-stripe of ``gather_superpose_pallas``, the compressed cohort's kernel
 DEFAULT_BLOCK_D = 512
 # Every superposition contracts f32 weights b_k p_k at full precision. At
 # a TPU's default precision the MXU takes f32 operands as one bf16 pass,
@@ -38,130 +48,151 @@ DEFAULT_BLOCK_D = 512
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _kernel(bp_ref, x_ref, noise_ref, out_ref):
-    bp = bp_ref[...]                       # (1, K)
-    x = x_ref[...].astype(jnp.float32)     # (K, BLOCK_D)
-    n = noise_ref[...]                     # (1, BLOCK_D)
-    varsigma = jnp.maximum(jnp.sum(bp), 1e-12)
-    acc = jax.lax.dot_general(
-        bp, x, (((1,), (0,)), ((), ())), precision=HIGHEST,
-        preferred_element_type=jnp.float32)       # (1, BLOCK_D)
-    # noise joins the reduction in the accumulator dtype, not its own
-    out_ref[...] = ((acc + n.astype(acc.dtype)) / varsigma).astype(out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
-def aircomp_sum_pallas(stacked: jnp.ndarray, bp: jnp.ndarray,
-                       noise: jnp.ndarray, *, block_d: int = DEFAULT_BLOCK_D,
-                       interpret: bool = False) -> jnp.ndarray:
-    """stacked: (K, D); bp: (K,); noise: (D,) -> (D,) f32 aggregate.
-
-    The payload may be bf16; the contraction accumulates in f32, the AWGN
-    joins that f32 accumulator un-rounded, and the aggregate comes back
-    f32 — the same "f32 accumulation, f32 aggregate" contract as
-    ``superpose_normalize_pallas`` / ``aircomp_sum_tree_psum`` (a bf16
-    carry stores its planes rounded, but the received y must not be).
-
-    ``interpret=True`` runs the kernel body in the Pallas interpreter
-    (tests on hosts without a TPU)."""
-    k, d = stacked.shape
-    noise = noise.astype(jnp.float32)
-    pad = (-d) % block_d
-    if pad:
-        stacked = jnp.pad(stacked, ((0, 0), (0, pad)))
-        noise = jnp.pad(noise, (0, pad))
-    dp = d + pad
-    grid = (dp // block_d,)
-    out = pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, k), lambda i: (0, 0)),           # bp (VMEM-resident)
-            pl.BlockSpec((k, block_d), lambda i: (0, i)),     # stacked stripe
-            pl.BlockSpec((1, block_d), lambda i: (0, i)),     # noise stripe
-        ],
-        out_specs=pl.BlockSpec((1, block_d), lambda i: (0, i)),
-        out_shape=out_struct((1, dp), jnp.float32, stacked, bp, noise),
-        interpret=interpret,
-        name="aircomp_sum_pallas",
-    )(bp[None, :].astype(jnp.float32), stacked, noise[None, :])
-    return out[0, :d]
-
-
 # ---------------------------------------------------------------------------
 # fused superpose-and-normalize (mask + superposition + AWGN + varsigma in
 # one pass, varsigma returned)
 # ---------------------------------------------------------------------------
 
-def _superpose_kernel(vs_min, p_ref, m_ref, x_ref, noise_ref, out_ref,
-                      vs_ref):
+def _raw_varsigma(p_ref, m_ref, k):
+    """sum_k b_k p_k from the (K,) powers and mask in SMEM."""
+    return jax.lax.fori_loop(0, k, lambda kk, a: a + p_ref[kk] * m_ref[kk],
+                             jnp.float32(0.0), unroll=k <= 8)
+
+
+def _superpose_native_kernel(vs_min, blocks, r, p_ref, m_ref, x_ref,
+                             noise_ref, out_ref, vs_ref):
+    """One (K, br, C) block of a native-view leaf: K f32 multiply-adds of
+    r-row chunks on the VPU, the noise and the normalizer joining per
+    chunk. Rows past the leaf's end compute garbage that the write-back
+    drops."""
+    k, _, _ = x_ref.shape
+    l, i = pl.program_id(0), pl.program_id(1)
+    raw = _raw_varsigma(p_ref, m_ref, k)
+    varsigma = jnp.maximum(raw, vs_min)
+
+    def sweep(valid):
+        def rows(j, carry):
+            at = pl.ds(pl.multiple_of(j * r, r), r)
+
+            def client(kk, acc):
+                bp = p_ref[kk] * m_ref[kk]
+                return acc + bp * x_ref[kk, at, :].astype(jnp.float32)
+
+            acc = jax.lax.fori_loop(
+                0, k, client, jnp.zeros((r, x_ref.shape[2]), jnp.float32),
+                unroll=k <= 8)
+            out_ref[at, :] = (acc + noise_ref[at, :]) / varsigma
+            return carry
+
+        jax.lax.fori_loop(0, -(-valid // r), rows, 0)
+
+    per_block(blocks, i, sweep)
+
+    @pl.when((l == 0) & (i == 0))
+    def _emit_vs():
+        vs_ref[0] = raw
+
+
+def _superpose_stripe_kernel(vs_min, p_ref, m_ref, x_ref, noise_ref,
+                             out_ref, vs_ref):
+    """One (K, block_d) stripe of a rank-2 leaf: the masked powers as a
+    (1, K) row contracted with the stripe on the MXU. Lanes past the
+    leaf's end compute garbage that the write-back drops."""
     i = pl.program_id(0)
     bp = p_ref[...] * m_ref[...]                # (1, K) f32, masked in-kernel
     raw = jnp.sum(bp)
     varsigma = jnp.maximum(raw, vs_min)
     # a bf16 payload is widened here: a mixed bf16 x f32 contraction
     # rounds the f32 weights b_k p_k to bf16 on the MXU
-    x = x_ref[...].astype(jnp.float32)          # (K, BLOCK_D)
-    n = noise_ref[...]                          # (1, BLOCK_D)
+    x = x_ref[...].astype(jnp.float32)          # (K, block_d)
     acc = jax.lax.dot_general(
         bp, x, (((1,), (0,)), ((), ())), precision=HIGHEST,
-        preferred_element_type=jnp.float32)     # f32 accumulation always
-    out_ref[...] = (acc + n.astype(acc.dtype)) / varsigma
+        preferred_element_type=jnp.float32)     # (1, block_d)
+    out_ref[...] = (acc + noise_ref[...]) / varsigma
 
     @pl.when(i == 0)
     def _emit_vs():
         vs_ref[...] = raw[None, None]
 
 
-@functools.partial(jax.jit, static_argnames=("vs_min", "block_d",
+@functools.partial(jax.jit, static_argnames=("vs_min", "block_bytes",
                                              "interpret"))
 def superpose_normalize_pallas(stacked: jnp.ndarray, powers: jnp.ndarray,
                                mask: jnp.ndarray, noise: jnp.ndarray, *,
                                vs_min: float = 1e-12,
-                               block_d: int = DEFAULT_BLOCK_D,
+                               block_bytes: int = PLANE_BLOCK_BYTES,
                                interpret: bool = False):
-    """Eqs. (6)+(8) in one sweep: stacked (K, D) payloads, powers/mask (K,)
-    -> ``(agg (D,) f32, varsigma f32 scalar)`` where
+    """Eqs. (6)+(8) in one sweep: stacked (K, ...) payloads of one leaf,
+    powers/mask (K,), noise of the leaf's shape (stacked.shape[1:]) ->
+    ``(agg f32 of the leaf's shape, varsigma f32 scalar)`` where
 
         agg      = (sum_k b_k p_k stacked[k] + noise) / max(varsigma, vs_min)
         varsigma = sum_k b_k p_k                       (raw, unclamped)
 
-    Extends ``aircomp_sum_pallas`` with the two pieces the round core had
-    to compute in separate passes: the b*p masking joins the kernel (no
-    materialized bp vector... trivial, but it keeps the contract whole)
-    and the eq.-8 normalizer comes back with the aggregate, so the
-    zero-uploader guard needs no second reduction. ``stacked`` may be
-    bf16; the contraction always accumulates in f32.
+    The b*p masking joins the kernel and the eq.-8 normalizer comes back
+    with the aggregate, so the zero-uploader guard needs no second
+    reduction. ``stacked`` may be
+    bf16; products and sums are always f32.
+
+    A leaf of rank >= 3 is read, and its aggregate written, in its own
+    layout as (L, S, C) blocks of rows (``repro.kernels.tiling``); a
+    rank-2 leaf in lane stripes. ``block_bytes`` sets the bytes of one
+    block of the payload plane (tests use small blocks to reach ragged
+    tails on small leaves).
 
     ``interpret=True`` runs the kernel body in the Pallas interpreter
     (tests on hosts without a TPU)."""
-    k, d = stacked.shape
-    noise = noise.astype(jnp.float32)
-    pad = (-d) % block_d
-    if pad:
-        stacked = jnp.pad(stacked, ((0, 0), (0, pad)))
-        noise = jnp.pad(noise, (0, pad))
-    dp = d + pad
-    kern = functools.partial(_superpose_kernel, float(vs_min))
+    k = stacked.shape[0]
+    leaf = stacked.shape[1:]
+    noise = noise.astype(jnp.float32).reshape(leaf)
+    powers = powers.astype(jnp.float32)
+    mask = mask.astype(jnp.float32)
     ops_ = (stacked, powers, mask, noise)
+    blocks = None
+    if stacked.ndim >= 3:
+        _, ll, s, c = native_view(stacked.shape)
+        blocks = native_rows(k, s, c, [stacked.dtype],
+                             [jnp.float32, jnp.float32], block_bytes)
+    if blocks is not None:
+        r = max(sublanes(stacked.dtype), sublanes(jnp.float32))
+        row = pl.BlockSpec((None, blocks.size, c), lambda li, i: (li, i, 0))
+        smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+        agg, vs = pl.pallas_call(
+            functools.partial(_superpose_native_kernel, float(vs_min),
+                              blocks, r),
+            grid=(ll, blocks.count),
+            in_specs=[smem, smem,
+                      pl.BlockSpec((k, None, blocks.size, c),
+                                   lambda li, i: (0, li, i, 0)),
+                      row],
+            out_specs=[row, smem],
+            out_shape=[out_struct((ll, s, c), jnp.float32, *ops_),
+                       out_struct((1,), jnp.float32, powers, mask)],
+            interpret=interpret,
+            name="superpose_normalize_pallas",
+        )(powers, mask, stacked.reshape((k, ll, s, c)),
+          noise.reshape((ll, s, c)))
+        return agg.reshape(leaf), vs[0]
+    x2 = stacked.reshape((k, -1))
+    n = x2.shape[1]
+    blocks = stripe_lanes(k, n, [stacked.dtype], [jnp.float32, jnp.float32],
+                          block_bytes, weight_rows=2)
+    stripe = lambda i: (0, i)
+    row = pl.BlockSpec((1, k), lambda i: (0, 0))
     agg, vs = pl.pallas_call(
-        kern,
-        grid=(dp // block_d,),
-        in_specs=[
-            pl.BlockSpec((1, k), lambda i: (0, 0)),          # powers
-            pl.BlockSpec((1, k), lambda i: (0, 0)),          # mask
-            pl.BlockSpec((k, block_d), lambda i: (0, i)),    # payload stripe
-            pl.BlockSpec((1, block_d), lambda i: (0, i)),    # noise stripe
-        ],
-        out_specs=[pl.BlockSpec((1, block_d), lambda i: (0, i)),
+        functools.partial(_superpose_stripe_kernel, float(vs_min)),
+        grid=(blocks.count,),
+        in_specs=[row, row,                                  # powers, mask
+                  pl.BlockSpec((k, blocks.size), stripe),    # payload
+                  pl.BlockSpec((1, blocks.size), stripe)],   # noise
+        out_specs=[pl.BlockSpec((1, blocks.size), stripe),
                    pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_shape=[out_struct((1, dp), jnp.float32, *ops_),
+        out_shape=[out_struct((1, n), jnp.float32, *ops_),
                    out_struct((1, 1), jnp.float32, powers, mask)],
         interpret=interpret,
         name="superpose_normalize_pallas",
-    )(powers[None, :].astype(jnp.float32), mask[None, :].astype(jnp.float32),
-      stacked, noise[None, :])
-    return agg[0, :d], vs[0, 0]
+    )(powers[None, :], mask[None, :], x2, noise.reshape((1, n)))
+    return agg.reshape(leaf), vs[0, 0]
 
 
 # ---------------------------------------------------------------------------

@@ -18,8 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.aircomp_sum import (aircomp_sum_pallas,
-                                       gather_superpose_pallas,
+from repro.kernels.aircomp_sum import (gather_superpose_pallas,
                                        superpose_normalize_pallas)
 from repro.kernels.cosine_sim import cosine_partials_pallas
 from repro.kernels.round_stats import (compressed_round_stats,
@@ -37,17 +36,15 @@ def on_tpu(kernel, twin):
 
 
 def _round_stats_kernel(deltas, g, payload):
-    """One compiled ``round_stats_pallas`` call per leaf, accumulated in
-    tree_flatten order (the twin's order)."""
+    """One compiled ``round_stats_pallas`` call per leaf, each leaf in its
+    own shape, accumulated in tree_flatten order (the twin's order)."""
     d_leaves = jax.tree_util.tree_leaves(deltas)
     g_leaves = jax.tree_util.tree_leaves(g)
     p_leaves = (jax.tree_util.tree_leaves(payload) if payload is not None
                 else [None] * len(d_leaves))
     dots = dn2 = pn2 = gn2 = None
     for dl, plf, gl in zip(d_leaves, p_leaves, g_leaves):
-        d2 = dl.reshape((dl.shape[0], -1))
-        p2 = None if plf is None else plf.reshape((plf.shape[0], -1))
-        stats, g2 = round_stats_pallas(d2, gl.reshape(-1), p2)
+        stats, g2 = round_stats_pallas(dl, gl, plf)
         if dots is None:
             dots, dn2, gn2 = stats[:, 0], stats[:, 1], g2
             pn2 = stats[:, 2] if payload is not None else None
@@ -79,21 +76,31 @@ def round_stats(deltas, g, payload=None, tp=None):
 def superpose_normalize(stacked: jnp.ndarray, powers: jnp.ndarray,
                         mask: jnp.ndarray, noise: jnp.ndarray,
                         vs_min: float = 1e-12):
-    """Fused eq. (6)+(8) for one (K, D) leaf: (agg (D,) f32, raw varsigma).
-    Compiled kernel on TPU; f32-accumulating einsum elsewhere."""
+    """Fused eq. (6)+(8) for one (K, ...) leaf and its leaf-shaped noise:
+    (agg (D,) f32, raw varsigma), D the leaf's size. Compiled kernel on
+    TPU, which reads and writes the leaf in its own layout (the caller's
+    reshape of the flat aggregate back to the leaf's shape cancels
+    against this one); f32-accumulating einsum over the flattened leaf
+    elsewhere."""
     def twin():
         # one einsum with f32 accumulation (the convert of a bf16 payload
         # fuses into the contraction — no materialized f32 copy); for f32
         # payloads this is the exact historical op sequence
         bp = (powers * mask).astype(jnp.float32)
         raw = jnp.sum(bp)
-        acc = jnp.einsum("k,kd->d", bp, stacked,
+        acc = jnp.einsum("k,kd->d", bp,
+                         stacked.reshape((stacked.shape[0], -1)),
                          preferred_element_type=jnp.float32)
-        agg = (acc + noise.astype(jnp.float32)) / jnp.maximum(raw, vs_min)
+        agg = (acc + noise.reshape(-1).astype(jnp.float32)) / jnp.maximum(
+            raw, vs_min)
         return agg, raw
 
-    return on_tpu(lambda: superpose_normalize_pallas(
-        stacked, powers, mask, noise, vs_min=vs_min), twin)
+    def kernel():
+        agg, raw = superpose_normalize_pallas(stacked, powers, mask, noise,
+                                              vs_min=vs_min)
+        return agg.reshape(-1), raw
+
+    return on_tpu(kernel, twin)
 
 
 def gather_superpose(values, idx, bp, noise, *, d: int, scale=None,
@@ -132,9 +139,12 @@ def round_stats_compressed(values, idx, resid, resid_idx, g, scale=None):
 
 def aircomp_sum(stacked: jnp.ndarray, bp: jnp.ndarray,
                 noise: jnp.ndarray) -> jnp.ndarray:
-    """Fused (sum_k bp_k w_k + n)/sum bp_k. stacked (K,D) -> (D,)."""
-    return on_tpu(lambda: aircomp_sum_pallas(stacked, bp, noise),
-                  lambda: ref.aircomp_sum_ref(stacked, bp, noise))
+    """Fused (sum_k bp_k w_k + n)/sum bp_k. stacked (K,D) -> (D,) f32.
+    On TPU the round's superposition kernel, with the mask all ones."""
+    return on_tpu(
+        lambda: superpose_normalize_pallas(stacked, bp, jnp.ones_like(bp),
+                                           noise)[0],
+        lambda: ref.aircomp_sum_ref(stacked, bp, noise))
 
 
 def cosine_sim(deltas: jnp.ndarray, g: jnp.ndarray, eps: float = 1e-12):

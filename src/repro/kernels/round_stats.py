@@ -18,9 +18,14 @@ HBM passes per aggregation period.
 
 Two implementations, same contract:
 
-* ``round_stats_pallas`` — the TPU kernel: grid over d in BLOCK_D stripes,
-  K resident per stripe, f32 VMEM accumulators (revisited-output pattern,
-  like ``cosine_sim``). Inputs may be bf16; accumulation is always f32.
+* ``round_stats_pallas`` — the TPU kernel, one ``pallas_call`` per leaf.
+  A leaf of rank >= 3 is read in its own layout, in (K, br, C) blocks of
+  about 2 MiB (``repro.kernels.tiling``): per client, f32 elementwise
+  products summed into (8, C) vreg accumulators over r-row chunks, the
+  per-block totals added into a revisited (K, ncol) output accumulator
+  (like ``cosine_sim``). A rank-2 leaf (the raveled (K, D) plane) is read
+  in byte-sized (K, block_d) lane stripes. A ragged last block is masked
+  in the kernel. Inputs may be bf16; products and sums are always f32.
 * ``round_stats_jnp`` — the CPU/GPU twin: the dot is a matmul and each
   sq-norm is a batched dot (``einsum kd,kd->k``) so NOTHING K x d ever
   materializes (XLA-CPU lowers ``sum(x*x, -1)`` as a full materialized
@@ -48,7 +53,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_D = 512
+from repro.kernels.tiling import (PLANE_BLOCK_BYTES, native_rows, native_view,
+                                  per_block, stripe_lanes, sublanes)
 
 # d-chunk of the explicitly-chunked jnp variant. Leaves at or below this
 # size reduce in one shot with the historical ops (bit-identical
@@ -76,92 +82,181 @@ def out_struct(shape, dtype, *operands):
 # TPU kernel
 # ---------------------------------------------------------------------------
 
-def _kernel(d_ref, g_ref, out_ref, gn2_ref):
-    i = pl.program_id(0)
-    x = d_ref[...].astype(jnp.float32)          # (K, BLOCK_D) deltas stripe
-    g = g_ref[...].astype(jnp.float32)          # (1, BLOCK_D)
-    dot = jax.lax.dot_general(x, g, (((1,), (1,)), ((), ())),
-                              precision=HIGHEST,
-                              preferred_element_type=jnp.float32)   # (K, 1)
-    dn2 = jnp.sum(x * x, axis=1, keepdims=True)                     # (K, 1)
-    partial = jnp.concatenate([dot, dn2], axis=1)                   # (K, 2)
-    gn2 = jnp.sum(g * g, axis=1, keepdims=True)                     # (1, 1)
+def _fold(x):
+    """An (r, C) chunk summed over its 8-row groups: (8, C). The groups
+    are whole vregs, so the fold is vreg adds."""
+    out = x[:8]
+    for q in range(8, x.shape[0], 8):
+        out = out + x[q:q + 8]
+    return out
 
-    @pl.when(i == 0)
+
+def _total(acc):
+    """(8, C) partial sums -> (1, 1)."""
+    return jnp.sum(jnp.sum(acc, axis=1, keepdims=True), axis=0,
+                   keepdims=True)
+
+
+def _row_sums(valid: int, r: int, c: int, step, n_acc: int):
+    """``step(j, accs, masked)`` over the r-row chunks of a block's
+    ``valid`` rows, with ``n_acc`` (8, C) f32 accumulators in vregs: full
+    chunks in a loop, then one masked partial chunk. Returns the (1, 1)
+    totals."""
+    full, rem = divmod(valid, r)
+    zero = jnp.zeros((8, c), jnp.float32)
+    accs = jax.lax.fori_loop(0, full, lambda j, a: step(j, a, False),
+                             (zero,) * n_acc)
+    if rem:
+        accs = step(full, accs, True)
+    return [_total(a) for a in accs]
+
+
+def _native_kernel(blocks, r, d_ref, g_ref, *refs):
+    """One (K, br, C) block of a native-view leaf: per client, f32
+    elementwise products of the delta (and payload) rows and the
+    direction, summed into (8, C) vreg accumulators chunk by chunk; the
+    (K, ncol) totals join the revisited output accumulator."""
+    p_ref = refs[0] if len(refs) == 3 else None
+    out_ref, gn2_ref = refs[-2:]
+    k, _, c = d_ref.shape
+    ncol = out_ref.shape[1]
+    l, i = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((l == 0) & (i == 0))
     def _init():
-        out_ref[...] = partial
-        gn2_ref[...] = gn2
+        out_ref[...] = jnp.zeros_like(out_ref)
+        gn2_ref[...] = jnp.zeros_like(gn2_ref)
 
-    @pl.when(i != 0)
-    def _acc():
+    def sweep(valid):
+        rem = valid % r
+
+        def chunk(ref, j, masked, *row):
+            x = ref[(*row, pl.ds(pl.multiple_of(j * r, r), r),
+                     slice(None))].astype(jnp.float32)
+            if masked:       # rows past the leaf's end hold garbage
+                keep = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) < rem
+                x = jnp.where(keep, x, 0.0)
+            return x
+
+        def client(kk, partial):
+            def step(j, accs, masked):
+                g = chunk(g_ref, j, masked)
+                x = chunk(d_ref, j, masked, kk)
+                out = (accs[0] + _fold(x * g), accs[1] + _fold(x * x))
+                if p_ref is not None:
+                    p = chunk(p_ref, j, masked, kk)
+                    out += (accs[2] + _fold(p * p),)
+                return out
+
+            rows = jax.lax.broadcasted_iota(jnp.int32, (k, ncol), 0)
+            cols = jax.lax.broadcasted_iota(jnp.int32, (k, ncol), 1)
+            for col, v in enumerate(_row_sums(valid, r, c, step, ncol)):
+                partial = jnp.where((rows == kk) & (cols == col), v, partial)
+            return partial
+
+        partial = jax.lax.fori_loop(0, k, client,
+                                    jnp.zeros((k, ncol), jnp.float32),
+                                    unroll=k <= 8)
+        gn2 = _row_sums(valid, r, c,
+                        lambda j, a, m: (a[0] + _fold(
+                            chunk(g_ref, j, m) ** 2),), 1)[0]
         out_ref[...] += partial
         gn2_ref[...] += gn2
 
+    per_block(blocks, i, sweep)
 
-def _kernel_payload(d_ref, p_ref, g_ref, out_ref, gn2_ref):
+
+def _stripe_kernel(blocks, d_ref, g_ref, *refs):
+    """One (K, block_d) stripe of a rank-2 leaf: f32 elementwise products
+    reduced over the lanes; lanes past the leaf's end are masked."""
+    p_ref = refs[0] if len(refs) == 3 else None
+    out_ref, gn2_ref = refs[-2:]
     i = pl.program_id(0)
-    x = d_ref[...].astype(jnp.float32)
-    p = p_ref[...].astype(jnp.float32)          # (K, BLOCK_D) payload stripe
-    g = g_ref[...].astype(jnp.float32)
-    dot = jax.lax.dot_general(x, g, (((1,), (1,)), ((), ())),
-                              precision=HIGHEST,
-                              preferred_element_type=jnp.float32)
-    dn2 = jnp.sum(x * x, axis=1, keepdims=True)
-    pn2 = jnp.sum(p * p, axis=1, keepdims=True)
-    partial = jnp.concatenate([dot, dn2, pn2], axis=1)              # (K, 3)
-    gn2 = jnp.sum(g * g, axis=1, keepdims=True)
 
     @pl.when(i == 0)
     def _init():
-        out_ref[...] = partial
-        gn2_ref[...] = gn2
+        out_ref[...] = jnp.zeros_like(out_ref)
+        gn2_ref[...] = jnp.zeros_like(gn2_ref)
 
-    @pl.when(i != 0)
-    def _acc():
-        out_ref[...] += partial
-        gn2_ref[...] += gn2
+    def sweep(valid):
+        def load(ref):
+            x = ref[...].astype(jnp.float32)
+            if valid < x.shape[1]:
+                keep = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) < valid
+                x = jnp.where(keep, x, 0.0)
+            return x
+
+        x, g = load(d_ref), load(g_ref)              # (K, bd), (1, bd)
+        cols = [jnp.sum(x * g, axis=1, keepdims=True),
+                jnp.sum(x * x, axis=1, keepdims=True)]
+        if p_ref is not None:
+            p = load(p_ref)
+            cols.append(jnp.sum(p * p, axis=1, keepdims=True))
+        out_ref[...] += jnp.concatenate(cols, axis=1)
+        gn2_ref[...] += jnp.sum(g * g, axis=1, keepdims=True)
+
+    per_block(blocks, i, sweep)
 
 
-@functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_bytes", "interpret"))
 def round_stats_pallas(deltas: jnp.ndarray, g: jnp.ndarray,
                        payload: jnp.ndarray | None = None, *,
-                       block_d: int = DEFAULT_BLOCK_D,
+                       block_bytes: int = PLANE_BLOCK_BYTES,
                        interpret: bool = False):
-    """deltas: (K, D); g: (D,); payload: optional (K, D).
+    """deltas: (K, ...) one client-stacked leaf; g: the leaf's
+    (deltas.shape[1:]) direction; payload: optional, like deltas.
 
     Returns ``(stats, gn2)`` where stats is (K, 2) ``[dot_k, dn2_k]`` (or
     (K, 3) with ``pn2_k`` appended when ``payload`` is given) and gn2 is
     the f32 scalar ``||g||^2`` — one streaming pass over every operand.
+
+    A leaf of rank >= 3 is read in its own layout, as (K, L, S, C) blocks
+    of rows (``repro.kernels.tiling``); a rank-2 leaf in lane stripes.
+    ``block_bytes`` sets the bytes of one block of one plane (tests use
+    small blocks to reach ragged tails on small leaves).
     """
-    k, d = deltas.shape
-    pad = (-d) % block_d
-    if pad:
-        deltas = jnp.pad(deltas, ((0, 0), (0, pad)))
-        g = jnp.pad(g, (0, pad))
-        if payload is not None:
-            payload = jnp.pad(payload, ((0, 0), (0, pad)))
-    dp = d + pad
-    grid = (dp // block_d,)
-    stripe = pl.BlockSpec((k, block_d), lambda i: (0, i))
-    gspec = pl.BlockSpec((1, block_d), lambda i: (0, i))
-    ncol = 2 if payload is None else 3
-    out_specs = [pl.BlockSpec((k, ncol), lambda i: (0, 0)),   # revisited acc
-                 pl.BlockSpec((1, 1), lambda i: (0, 0))]
-    ops_ = (deltas, g) if payload is None else (deltas, g, payload)
-    out_shape = [out_struct((k, ncol), jnp.float32, *ops_),
-                 out_struct((1, 1), jnp.float32, g)]
-    if payload is None:
-        stats, gn2 = pl.pallas_call(
-            _kernel, grid=grid, in_specs=[stripe, gspec],
-            out_specs=out_specs, out_shape=out_shape, interpret=interpret,
-            name="round_stats_pallas",
-        )(deltas, g[None, :])
+    k = deltas.shape[0]
+    g = g.reshape(deltas.shape[1:])
+    planes = (deltas,) if payload is None else (deltas, payload)
+    ncol = 1 + len(planes)
+    pdt = [x.dtype for x in planes]
+    blocks = None
+    if deltas.ndim >= 3:
+        _, ll, s, c = native_view(deltas.shape)
+        blocks = native_rows(k, s, c, pdt, [g.dtype], block_bytes)
+    if blocks is not None:
+        r = max(sublanes(dt) for dt in (*pdt, g.dtype))
+        view = lambda x: x.reshape((k, ll, s, c))
+        plane = pl.BlockSpec((k, None, blocks.size, c),
+                             lambda li, i: (0, li, i, 0))
+        in_specs = [plane, pl.BlockSpec((None, blocks.size, c),
+                                        lambda li, i: (li, i, 0))]
+        args = [view(deltas), g.reshape((ll, s, c))]
+        kern = functools.partial(_native_kernel, blocks, r)
+        grid = (ll, blocks.count)
+        acc = lambda li, i: (0, 0)
     else:
-        stats, gn2 = pl.pallas_call(
-            _kernel_payload, grid=grid, in_specs=[stripe, stripe, gspec],
-            out_specs=out_specs, out_shape=out_shape, interpret=interpret,
-            name="round_stats_pallas",
-        )(deltas, payload, g[None, :])
+        d2 = deltas.reshape((k, -1))
+        n = d2.shape[1]
+        blocks = stripe_lanes(k, n, pdt, [g.dtype], block_bytes)
+        view = lambda x: x.reshape((k, n))
+        plane = pl.BlockSpec((k, blocks.size), lambda i: (0, i))
+        in_specs = [plane, pl.BlockSpec((1, blocks.size), lambda i: (0, i))]
+        args = [d2, g.reshape((1, n))]
+        kern = functools.partial(_stripe_kernel, blocks)
+        grid = (blocks.count,)
+        acc = lambda i: (0, 0)
+    if payload is not None:
+        in_specs.append(plane)
+        args.append(view(payload))
+    stats, gn2 = pl.pallas_call(
+        kern, grid=grid, in_specs=in_specs,
+        out_specs=[pl.BlockSpec((k, ncol), acc),     # revisited accumulator
+                   pl.BlockSpec((1, 1), acc)],
+        out_shape=[out_struct((k, ncol), jnp.float32, *planes, g),
+                   out_struct((1, 1), jnp.float32, g)],
+        interpret=interpret, name="round_stats_pallas",
+    )(*args)
     return stats, gn2[0, 0]
 
 

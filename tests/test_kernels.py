@@ -5,11 +5,18 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.aircomp_sum import aircomp_sum_pallas
+from repro.kernels.aircomp_sum import superpose_normalize_pallas
 from repro.kernels.cosine_sim import cosine_partials_pallas
 from repro.kernels.swa_attention import swa_attention_pallas
 
 RNG = np.random.default_rng(42)
+
+
+def _aircomp_sum(x, bp, n):
+    """The raveled AirComp sum on the TPU path (``ops.aircomp_sum``): the
+    superposition kernel with the mask all ones, interpreted."""
+    return superpose_normalize_pallas(x, bp, jnp.ones_like(bp), n,
+                                      interpret=True)[0]
 
 
 def _tol(dtype):
@@ -23,7 +30,7 @@ def test_aircomp_sum_sweep(k, d, dtype):
     x = jnp.asarray(RNG.normal(size=(k, d)), dtype)
     bp = jnp.asarray(RNG.random(k), jnp.float32)
     n = jnp.asarray(RNG.normal(size=d), dtype)
-    got = aircomp_sum_pallas(x, bp, n, interpret=True)
+    got = _aircomp_sum(x, bp, n)
     want = ref.aircomp_sum_ref(x, bp, n)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
@@ -40,7 +47,7 @@ def test_aircomp_sum_bf16_payload_f32_aggregate():
     x = x32.astype(jnp.bfloat16)
     bp = jnp.asarray(RNG.random(k), jnp.float32)
     n = jnp.asarray(RNG.normal(size=d), jnp.float32)
-    got = aircomp_sum_pallas(x, bp, n, interpret=True)
+    got = _aircomp_sum(x, bp, n)
     assert got.dtype == jnp.float32
     # oracle on the SAME rounded payload but full-precision noise path: the
     # only error left is the bf16 storage rounding of x, not of the output
@@ -53,7 +60,7 @@ def test_aircomp_sum_masked_clients_ignored():
     x = jnp.asarray(RNG.normal(size=(8, 256)), jnp.float32)
     bp = jnp.asarray([1.0, 0, 2.0, 0, 0, 0.5, 0, 0], jnp.float32)
     n = jnp.zeros(256, jnp.float32)
-    got = aircomp_sum_pallas(x, bp, n, interpret=True)
+    got = _aircomp_sum(x, bp, n)
     want = (1.0 * x[0] + 2.0 * x[2] + 0.5 * x[5]) / 3.5
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-6,
                                atol=2e-6)

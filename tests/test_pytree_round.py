@@ -107,6 +107,30 @@ def test_tree_aggregate_noise_is_leaf_split_invariant():
                                np.asarray(agg_f), rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("key_kind", ["raw", "typed", "rbg"])
+def test_leaf_noise_draw_equals_flat_split(key_kind):
+    """The superposition's noise, drawn leaf by leaf in each leaf's own
+    shape, is the flat draw's split bit for bit: the AWGN realization
+    does not depend on how the model is split into leaves. A key whose
+    draw is not a hash of the flat index (``rbg``) takes the flat draw."""
+    from repro.core.aggregation import _counter_draw, stacked_tree_noise
+    key = {"raw": jax.random.fold_in(jax.random.PRNGKey(5), 3),
+           "typed": jax.random.key(5),
+           "rbg": jax.random.key(5, impl="rbg")}[key_kind]
+    leaves = [jnp.zeros((2, 3, 40, 57)), jnp.zeros((2, 1000)),
+              jnp.zeros((2, 7, 5)), jnp.zeros((2, 2, 3, 4, 5))]
+    assert _counter_draw(key, 8000) == (key_kind != "rbg")
+    flat = jax.jit(lambda k: 0.3 * jax.random.normal(k, (8000,)))(key)
+    mine = jax.jit(lambda k: stacked_tree_noise(k, leaves, 0.3))(key)
+    off = 0
+    for leaf, b in zip(leaves, mine):
+        size = int(np.prod(leaf.shape[1:]))
+        a = flat[off:off + size].reshape(leaf.shape[1:])
+        off += size
+        assert a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 # ---------------------------------------------------------------------------
 # fused pytree mode (single device)
 # ---------------------------------------------------------------------------

@@ -1,6 +1,8 @@
-"""PR-5 delta-plane tests: fused round-stats + superpose-and-normalize
-kernels vs the ref.py oracles (interpret mode on CPU), the chunked-jnp
-twin, bf16 pending storage error bounds, and donation safety."""
+"""Delta-plane tests: fused round-stats + superpose-and-normalize
+kernels vs the ref.py oracles and the jnp twins (interpret mode on CPU),
+over rank-2 stripes and over leaves read in their own layout, the
+chunked-jnp twin, bf16 pending storage error bounds, and donation
+safety."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -132,6 +134,120 @@ def test_superpose_normalize_zero_uploaders():
     assert float(vs) == 0.0
     np.testing.assert_allclose(np.asarray(agg), np.asarray(n) / 1e-12,
                                rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# native-view kernels: leaves of rank >= 3 read in their own layout
+# ---------------------------------------------------------------------------
+
+# (stacked leaf shape, rows per block): rank 3 and 4 (and 5: L = 6 from
+# two lead dims), C of 576, 192 and 128, S not a multiple of the block
+# rows (a ragged last block), S below one row tile (one block read past
+# the leaf's end), L > 1, K of 1, 4 and 100
+NATIVE = {
+    "k4_L2_S40_C576_tail8": ((4, 2, 40, 576), 16),
+    "k4_S30_C576_one_block": ((4, 30, 576), 64),
+    "k1_L3_S50_C192_tail2": ((1, 3, 50, 192), 16),
+    "k100_S20_C128_tail4": ((100, 20, 128), 16),
+    "k4_L6_S33_C128_rank5": ((4, 3, 2, 33, 128), 16),
+}
+
+
+def _block_bytes(shape, dtype, rows):
+    """The ``block_bytes`` that gives ``rows`` rows per native block."""
+    lanes = -(-shape[-1] // 128) * 128
+    return rows * shape[0] * lanes * jnp.dtype(dtype).itemsize
+
+
+@pytest.mark.parametrize("with_payload", [False, True])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", list(NATIVE))
+def test_round_stats_native_leaf(case, dtype, with_payload):
+    """Kernel over a leaf in its own shape == the jnp twin over the same
+    leaf (flattened), at f32 rounding."""
+    shape, rows = NATIVE[case]
+    de = jnp.asarray(RNG.normal(size=shape), dtype)
+    p = (jnp.asarray(RNG.normal(size=shape), dtype) if with_payload
+         else None)
+    g = jnp.asarray(RNG.normal(size=shape[1:]), jnp.float32)
+    stats, gn2 = round_stats_pallas(
+        de, g, p, block_bytes=_block_bytes(shape, dtype, rows),
+        interpret=True)
+    dots, dn2, pn2, wgn2 = round_stats_jnp(de, g, p)
+    want = jnp.stack([dots, dn2] + ([pn2] if with_payload else []), 1)
+    assert stats.shape == want.shape and stats.dtype == jnp.float32
+    # two f32 summation orders over up to 46k terms: ~sqrt(n) eps apart.
+    # The dots cancel, so their error scales with |delta| |g|.
+    scale = jnp.maximum(jnp.sqrt(dn2 * wgn2)[:, None], 1.0)
+    np.testing.assert_allclose(np.asarray(stats / scale),
+                               np.asarray(want / scale), rtol=5e-5,
+                               atol=5e-6)
+    assert float(gn2) == pytest.approx(float(wgn2), rel=5e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", list(NATIVE))
+def test_superpose_normalize_native_leaf(case, dtype):
+    """Kernel over a leaf and its noise in their own shape == the
+    superposition of the flattened leaf, written in the leaf's shape."""
+    shape, rows = NATIVE[case]
+    k = shape[0]
+    x = jnp.asarray(RNG.normal(size=shape), dtype)
+    powers = jnp.asarray(RNG.random(k), jnp.float32)
+    mask = jnp.asarray(RNG.random(k) < 0.6, jnp.float32).at[0].set(1.0)
+    n = jnp.asarray(RNG.normal(size=shape[1:]), jnp.float32)
+    agg, vs = superpose_normalize_pallas(
+        x, powers, mask, n, block_bytes=_block_bytes(shape, dtype, rows),
+        interpret=True)
+    want, wvs = ref.superpose_normalize_ref(x.reshape((k, -1)), powers,
+                                            mask, n.reshape(-1))
+    assert agg.shape == shape[1:] and agg.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(agg).reshape(-1),
+                               np.asarray(want), rtol=1e-5, atol=1e-6)
+    assert float(vs) == pytest.approx(float(wvs), rel=1e-6)
+
+
+@pytest.mark.parametrize("k,d", [(4, 1000), (100, 8070)])
+def test_stripe_kernels_mask_a_ragged_tail(k, d):
+    """A rank-2 leaf in several lane stripes whose last one is ragged
+    (masked in the kernel, no padded copy) == the oracles."""
+    de = jnp.asarray(RNG.normal(size=(k, d)), jnp.float32)
+    g = jnp.asarray(RNG.normal(size=d), jnp.float32)
+    small = 8 * 512 * 4 if k <= 8 else 104 * 512 * 4    # 512-lane stripes
+    stats, gn2 = round_stats_pallas(de, g, de, block_bytes=small,
+                                    interpret=True)
+    want, wgn2 = ref.round_stats_ref(de, g, de)
+    _assert_stats_close(stats, want)
+    assert float(gn2) == pytest.approx(float(wgn2), rel=3e-5)
+    powers = jnp.asarray(RNG.random(k), jnp.float32)
+    mask = jnp.ones((k,), jnp.float32)
+    agg, _ = superpose_normalize_pallas(de, powers, mask, g,
+                                        block_bytes=small, interpret=True)
+    wagg, _ = ref.superpose_normalize_ref(de, powers, mask, g)
+    np.testing.assert_allclose(np.asarray(agg), np.asarray(wagg),
+                               rtol=3e-5, atol=3e-5)
+
+
+def test_block_sizes_from_bytes():
+    """Blocks carry about PLANE_BLOCK_BYTES of one plane, fit VMEM
+    double-buffered, align to the row tile or to 128 lanes, and at large
+    K a stripe is no wider than 512 lanes and never below 128."""
+    from repro.kernels.tiling import (PLANE_BLOCK_BYTES, VMEM_BYTES,
+                                      native_rows, stripe_lanes)
+    bf, f32 = jnp.bfloat16, jnp.float32
+    for s, c in [(1536, 576), (49152, 576), (576, 192), (576, 1536)]:
+        b = native_rows(4, s, c, [bf, bf], [f32])
+        lanes = -(-c // 128) * 128
+        plane = 4 * b.size * lanes * 2
+        assert b.size % 16 == 0 and (b.count - 1) * b.size + b.tail == s
+        assert PLANE_BLOCK_BYTES // 2 < plane <= PLANE_BLOCK_BYTES
+        assert 2 * (2 * plane + b.size * lanes * 4) <= VMEM_BYTES
+    assert native_rows(4, 30, 576, [bf], [f32]) .size == 32   # one block
+    assert native_rows(10 ** 4, 64, 4096, [f32], [f32]) is None
+    for k in (1000, 4096, 10 ** 5):
+        b = stripe_lanes(k, 10 ** 6, [f32, f32], [f32])
+        assert b.size % 128 == 0 and 128 <= b.size <= 512
+    assert stripe_lanes(4, 576, [bf], [f32]).size == 576     # whole leaf
 
 
 # ---------------------------------------------------------------------------

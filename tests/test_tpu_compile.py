@@ -6,15 +6,19 @@ refuses here what interpret mode cannot see — blocks that do not tile,
 more VMEM than a kernel may use. Shapes are those of the paper
 federation (MLP 784-10-10-10, K=100, raveled d=8070 and its largest
 pytree leaf), the compressed int8 cohort (m=32, s=d/16), smollm-135m
-client leaves at K=4 in bf16, and a cohort plane of m=256, d=16384.
+client leaves at K=4 in bf16 (flattened, and in their own shapes, which
+the kernels read in their own layout), and a cohort plane of m=256,
+d=16384.
 
 The topology is described inside a module fixture: describing it loads
 the TPU library, which one process at a time may hold, so nothing here
 touches it while modules are imported.
 
-One whole program is compiled too: the one-round advance of a small
+Whole programs are compiled too: the one-round advance of a small
 paper federation, whose data plane must stay out of a cross-program
-prefetch (``repro.fl.fused.TPU_SCAN_OPTIONS``).
+prefetch (``repro.fl.fused.TPU_SCAN_OPTIONS``), and the scan of a
+two-layer federation of smollm-width clients, whose kernel operands
+must reach the kernels without relayout loops.
 """
 import jax
 import jax.numpy as jnp
@@ -33,6 +37,17 @@ PLANES = {
     "smollm_embed_k4": (4, 49152 * 576, BF16),
     "smollm_mlp_k4": (4, 576 * 1536, BF16),
     "cohort_m256": (256, 16384, F32),
+    "large_k1000": (1000, 8070, F32),
+}
+# smollm-135m leaves at K=4 in bf16, in their own shapes: the MLP's down
+# projection, the embedding, the K/V projections, the layers' norm scales
+# and the final norm
+LEAVES = {
+    "mlp_down": (4, 30, 1536, 576),
+    "embedding": (4, 49152, 576),
+    "attn_wk": (4, 30, 576, 192),
+    "layer_norms": (4, 30, 576),
+    "final_norm": (4, 576),
 }
 # (m, d, s, value dtype, int8 scale)
 COMPRESSED = {
@@ -90,6 +105,26 @@ def test_superpose_normalize_compiles(one_chip, plane):
         superpose_normalize_pallas, _spec(one_chip, (k, d), dt),
         _spec(one_chip, (k,)), _spec(one_chip, (k,)),
         _spec(one_chip, (d,))) == 1
+
+
+@pytest.mark.parametrize("payload", [False, True], ids=["delta", "model"])
+@pytest.mark.parametrize("leaf", list(LEAVES))
+def test_round_stats_compiles_native_leaf(one_chip, leaf, payload):
+    shape = LEAVES[leaf]
+    args = [_spec(one_chip, shape, BF16), _spec(one_chip, shape[1:])]
+    if payload:
+        args.append(_spec(one_chip, shape, BF16))
+    assert _compiled_kernels(
+        lambda de, g, *p: round_stats_pallas(de, g, *p), *args) == 1
+
+
+@pytest.mark.parametrize("leaf", list(LEAVES))
+def test_superpose_normalize_compiles_native_leaf(one_chip, leaf):
+    shape = LEAVES[leaf]
+    assert _compiled_kernels(
+        superpose_normalize_pallas, _spec(one_chip, shape, BF16),
+        _spec(one_chip, shape[:1]), _spec(one_chip, shape[:1]),
+        _spec(one_chip, shape[1:])) == 1
 
 
 @pytest.mark.parametrize("case", list(COMPRESSED))
@@ -193,3 +228,97 @@ def test_round_kernels_sit_under_their_stage_scopes(one_chip, case):
             # the stage
             assert f"/{scope}/" in op_name, op_name
             assert f"/{kernel}/pallas_call" in op_name, op_name
+
+
+@pytest.fixture(scope="module")
+def smollm_scan(one_chip):
+    """The compiled HLO text of the scan of a two-layer federation of
+    smollm-width clients (bf16 planes, a pytree carry, model transmit),
+    compiled for the described chip, and the computations in it that run
+    both round kernels."""
+    import dataclasses
+    import re
+
+    import numpy as np
+
+    from repro.configs.smollm_135m import CONFIG
+    from repro.core import ChannelConfig, SchedulerConfig
+    from repro.data.pipeline import ClientData
+    from repro.fl import FLClient, FusedPAOTA, PAOTAConfig
+    from repro.fl.fused import TPU_SCAN_OPTIONS
+    from repro.models.transformer import init_model, loss_fn
+
+    cfg = dataclasses.replace(CONFIG, num_layers=2, vocab_size=512)
+    rng = np.random.default_rng(0)
+    k = 4
+
+    def loss(p, b):
+        return loss_fn(p, {"tokens": b["x"]}, cfg)[0]
+
+    clients = [FLClient(ClientData(
+        rng.integers(0, cfg.vocab_size, (4, 32)).astype(np.int32),
+        np.zeros(4, np.int32), i), loss, batch_size=2, lr=0.01,
+        local_steps=1) for i in range(k)]
+    srv = FusedPAOTA(init_model(jax.random.PRNGKey(0), cfg), clients,
+                     ChannelConfig(), SchedulerConfig(n_clients=k, seed=0),
+                     PAOTAConfig(), params_mode="pytree",
+                     pending_dtype="bfloat16")
+    put = lambda t: jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, a.dtype), t)
+    carry = jax.eval_shape(srv._init_carry, srv._init_global,
+                           srv.engine._x, srv.engine._y)
+    text = srv._jit_scan.lower(
+        put(carry), put(srv.engine._x), put(srv.engine._y),
+        n_rounds=2).compile(compiler_options=TPU_SCAN_OPTIONS).as_text()
+    bodies = [c for c in re.split(r"\n(?=\S)", text)
+              if "round_stats_pallas" in c
+              and "superpose_normalize_pallas" in c]
+    assert bodies
+    return bodies
+
+
+def test_scan_feeds_the_round_kernels_without_relayout_loops(smollm_scan):
+    """In the scan of a two-layer federation of smollm-width clients
+    (bf16 planes, a pytree carry, model transmit) compiled for the chip,
+    the computation that runs the round kernels holds no ``while`` loop
+    without an ``op_name``: the program's own loops (the water-filling
+    search, the training's layer scans) carry the op_name of their
+    source; the loops the compiler writes to relay a tiled leaf out into
+    a flat (K, n) operand carry none. The kernels read each leaf in its
+    own layout, so there are none of those."""
+    import re
+
+    for body in smollm_scan:
+        loops = [line for line in body.splitlines()
+                 if re.search(r"\) while\(|= \S+ while\(", line)]
+        assert loops                      # the water-filling search
+        unnamed = [line[:120] for line in loops if "op_name=" not in line]
+        assert not unnamed, unnamed
+
+
+def test_scan_draws_the_superposition_noise_in_each_leafs_layout(
+        smollm_scan):
+    """No operand of a superposition kernel in that scan comes out of a
+    ``reshape``: in compiled TPU HLO a reshape that is left (not turned
+    into a bitcast) is a relayout copy. The AWGN is drawn in each leaf's
+    own shape; a flat draw's slices reshaped to the leaves would each be
+    relaid out here."""
+    import re
+
+    inst = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = .*? ([\w\-]+)\((.*?)\)")
+    through = ("bitcast", "get-tuple-element", "copy-start", "copy-done")
+    for body in smollm_scan:
+        defs = {}
+        for line in body.splitlines():
+            m = inst.match(line)
+            if m:
+                defs[m.group(1)] = (m.group(2),
+                                    re.findall(r"%([\w.\-]+)", m.group(3)))
+        calls = [n for n, (op, _) in defs.items() if op == "custom-call"
+                 and n.startswith("superpose_normalize_pallas")]
+        assert calls
+        for call in calls:
+            for x in defs[call][1]:
+                while x in defs and defs[x][0] in through:
+                    x = defs[x][1][0]
+                assert x not in defs or defs[x][0] != "reshape", (call, x)
