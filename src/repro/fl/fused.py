@@ -277,6 +277,10 @@ class FusedPAOTA:
             engine.set_heterogeneity(steps_k, batch_k)
         self._carry: RoundCarry | None = None
         self.history: List[dict] = []
+        # the round-0 init keeps the compiler's default options: with
+        # TPU_SCAN_OPTIONS the paper federation's init (K=100, a
+        # (100, 300, 784) data plane) hung on a TPU v5e, without them it
+        # runs, though it then prefetches that plane across programs
         self._jit_init = jax.jit(self._init_carry)
         # the round carry is DONATED into the scan: advance() hands its
         # K x d planes (pending/deltas stacks) back to XLA for in-place

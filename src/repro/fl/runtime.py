@@ -77,7 +77,8 @@ shared stage helpers (``eq25_factors`` / ``constraint7_powers``) so the
 three implementations cannot drift apart stage by stage.
 
 Each stage of the round runs under one of ``STAGE_SCOPES``
-(``jax.named_scope``), in the dense and the cohort step alike, so every
+(``jax.named_scope``), in the dense and the cohort step alike
+(``paota.compress`` only where a cohort compresses its slots), so every
 device operation of the compiled scan carries its stage in its
 ``op_name`` metadata and a profiler trace can be split by stage. The
 scopes are metadata only: without them the compiled program differs in
@@ -107,9 +108,12 @@ from repro.core.scheduler import sched_advance, sched_broadcast
 # The round's stages as named scopes: scheduler advance, broadcast and
 # slot turnover; the eq.-25 stats sweep and screening; water-filling and
 # the cap (7); superposition, AWGN, the guarded update and rollback; local
-# training; the writes of the trained rows into the carry's planes.
+# training; a compressed cohort's error feedback, support pick,
+# quantisation and residual re-sparsify; the writes of the trained rows
+# into the carry's planes.
 STAGE_SCOPES = ("paota.schedule", "paota.stats", "paota.power",
-                "paota.superpose", "paota.train", "paota.carry_write")
+                "paota.superpose", "paota.train", "paota.compress",
+                "paota.carry_write")
 
 
 class RoundCarry(NamedTuple):
@@ -1062,20 +1066,23 @@ def _cohort_round_step(carry: RoundCarry, x, y, *, rcfg: RoundCfg,
         msk = take.reshape((m,) + (1,) * (new.ndim - 1))
         return jnp.where(msk, new, old)
 
-    with jax.named_scope("paota.carry_write"):
-        if rcfg.compress:
+    if rcfg.compress:
+        with jax.named_scope("paota.compress"):
             # compressed store: the f32 delta rows are EF-compensated with
             # the resumed parked residuals (decompressed transiently — the
             # carry never holds an (m, d) plane), then support-selected,
-            # stored, and their exact f32 residual re-sparsified. Non-take
-            # rows keep every old slot plane (garbage residual gathers for
-            # them are discarded here). Raveled single-leaf: `trained` is a
-            # bare (m, d) array.
+            # stored, and their exact f32 residual re-sparsified.
+            # Raveled single-leaf: `trained` is a bare (m, d) array.
             comp = trained - new_global[None]
             if pr_val is not None:
                 comp = comp + scatter_rows(pr_val, pr_idx, d_model)
             stored, idx_new, scale_new, e_val, e_idx = _compress_plane(
                 comp, rcfg=rcfg, streams=streams, t=t_next)
+
+    with jax.named_scope("paota.carry_write"):
+        if rcfg.compress:
+            # non-take rows keep every old slot plane (garbage residual
+            # gathers for them are discarded here)
             pending = None
             deltas = row_select(stored, carry.deltas)
             slot_idx = row_select(idx_new, carry.slot_idx)

@@ -54,10 +54,17 @@ DRIVERS = {
 @pytest.mark.parametrize("driver", list(DRIVERS))
 def test_every_stage_scope_reaches_the_compiled_scan(driver):
     names = OP_NAME.findall(_fused(**DRIVERS[driver]).compiled_scan_hlo(2))
+    compressed = bool(DRIVERS[driver].get("compress"))
     for scope in STAGE_SCOPES:
-        assert any(scope in n for n in names), scope
+        # only a compressed cohort has a compression stage
+        want = compressed or scope != "paota.compress"
+        assert any(scope in n for n in names) == want, scope
     # the stages do not nest in one another
     assert not any(sum(s in n for s in STAGE_SCOPES) > 1 for n in names)
+    if compressed:
+        # the stochastic rounding and the residual's top-k are compression
+        for op in ("floor", "top_k"):
+            assert any(n.endswith(f"/paota.compress/{op}") for n in names), op
 
 
 def _events(trace_dir):
