@@ -46,6 +46,16 @@ from repro.data.pipeline import ClientData, counter_batch_plan, stack_federation
 from repro.fl.client import FLClient
 
 
+def ravel_rows(stacked):
+    """(K, d) raveled rows of a client-stacked params pytree: each (K, ...)
+    leaf reshaped to (K, d_leaf), concatenated in tree_flatten order."""
+    leaves = jax.tree_util.tree_leaves(stacked)
+    if len(leaves) == 1:
+        return leaves[0].reshape((leaves[0].shape[0], -1))
+    return jnp.concatenate(
+        [l.reshape((l.shape[0], -1)) for l in leaves], axis=1)
+
+
 class LegacyEngine:
     """Reference engine: per-client Python loop (the seed behaviour)."""
 
@@ -168,37 +178,45 @@ class BatchedEngine:
             return self._steps_k
         return self._steps_k[jnp.asarray(client_ids, jnp.int32)]
 
-    def _train_one(self, params, xc, yc, plan, n_steps=None):
-        """One client's M local SGD steps from the broadcast ``params``
-        pytree; returns the trained params pytree (no ravel).
+    def _sgd(self, params, inputs, batch_of, n_steps=None):
+        """M local SGD steps from the broadcast ``params`` pytree, one per
+        leading row of ``inputs`` (a pytree of (M, ...) leaves);
+        ``batch_of`` maps one row to the step's ``{"x", "y"}`` batch.
+        Returns the trained params pytree (no ravel).
 
-        ``n_steps`` (traced scalar) masks heterogeneous step counts: plan
-        rows at positions >= n_steps multiply their gradient by an exactly
+        ``n_steps`` (traced scalar) masks heterogeneous step counts: rows
+        at positions >= n_steps multiply their gradient by an exactly
         zero step size, so ``pp - 0 * gg == pp`` bit for bit and a client
         with n_steps = s equals the s-step homogeneous run on the same
         plan rows. ``None`` keeps the historical unmasked program."""
-        def step(p, sel):
-            batch = {"x": xc[sel], "y": yc[sel]}
-            g = jax.grad(self.loss_fn)(p, batch)
+        def step(p, inp):
+            g = jax.grad(self.loss_fn)(p, batch_of(inp))
             return jax.tree_util.tree_map(
                 lambda pp, gg: pp - self.lr * gg, p, g), None
 
         def masked_step(p, inp):
-            sel, i = inp
-            batch = {"x": xc[sel], "y": yc[sel]}
-            g = jax.grad(self.loss_fn)(p, batch)
+            inp, i = inp
+            g = jax.grad(self.loss_fn)(p, batch_of(inp))
             lr = jnp.float32(self.lr) * (i < n_steps)
             return jax.tree_util.tree_map(
                 lambda pp, gg: pp - lr * gg, p, g), None
         # M is small (a handful of local steps): full unroll lets XLA
         # fuse across steps instead of paying while-loop overhead
         if n_steps is None:
-            p, _ = jax.lax.scan(step, params, plan, unroll=True)
+            p, _ = jax.lax.scan(step, params, inputs, unroll=True)
         else:
-            pos = jnp.arange(plan.shape[0], dtype=jnp.int32)
-            p, _ = jax.lax.scan(masked_step, params, (plan, pos),
+            m_steps = jax.tree_util.tree_leaves(inputs)[0].shape[0]
+            pos = jnp.arange(m_steps, dtype=jnp.int32)
+            p, _ = jax.lax.scan(masked_step, params, (inputs, pos),
                                 unroll=True)
         return p
+
+    def _train_one(self, params, xc, yc, plan, n_steps=None):
+        """One client's M local SGD steps on its own (n_max, ...) data
+        rows: each step gathers its minibatch ``xc[sel]`` from the plan
+        row ``sel``."""
+        return self._sgd(params, plan,
+                         lambda sel: {"x": xc[sel], "y": yc[sel]}, n_steps)
 
     def _train_all(self, params, x, y, idx, n_steps=None):
         """params: pytree of (…) broadcast to every client; x/y: padded
@@ -212,12 +230,7 @@ class BatchedEngine:
         (same leaf order, same row-major ravel) but costs one (K, d)
         write instead of a vmapped per-client concatenate (~40% of the
         train call at transformer-scale d)."""
-        trained = self._train_all_tree(params, x, y, idx, n_steps)
-        leaves = jax.tree_util.tree_leaves(trained)
-        if len(leaves) == 1:
-            return leaves[0].reshape((leaves[0].shape[0], -1))
-        return jnp.concatenate(
-            [l.reshape((l.shape[0], -1)) for l in leaves], axis=1)
+        return ravel_rows(self._train_all_tree(params, x, y, idx, n_steps))
 
     def _train_all_tree(self, params, x, y, idx, n_steps=None):
         """Pytree twin of ``_train_all``: same local SGD, but the trained
@@ -232,6 +245,24 @@ class BatchedEngine:
             lambda xc, yc, plan, ns: self._train_one(params, xc, yc, plan,
                                                      ns)
         )(x, y, idx, n_steps)
+
+    def _train_gathered(self, params, x, y, ids, idx, n_steps=None):
+        """Cohort training: the clients at rows ``ids`` (m,) of the
+        padded (K, n_max, ...) data train from ``params`` on their (m, M,
+        B) minibatch plans ``idx``; ``n_steps``: optional (m,) step
+        counts. Returns the (m, ...) client-stacked params pytree.
+
+        Every minibatch of every slot comes out of the plane in ONE
+        gather, ``x[ids, idx]``: m x M x B sample rows. Gathering the
+        slots' whole client rows first (``x[ids]``, then ``xc[sel]`` per
+        step) reads the same samples, but on a TPU it lowered to a
+        relayout copy of the whole plane and a slice of every row in
+        each round."""
+        rows = ids[:, None, None]
+        batches = {"x": x[rows, idx], "y": y[rows, idx]}
+        # n_steps None is an empty pytree to vmap: the unmasked program
+        return jax.vmap(lambda b, ns: self._sgd(params, b, lambda s: s, ns)
+                        )(batches, n_steps)
 
     def enable_counter_plan(self, key) -> None:
         """Switch minibatch planning to the stateless counter scheme: the

@@ -44,7 +44,7 @@ from repro.core.scheduler import (TAG_CHANNEL, TAG_COMPRESS, TAG_NOISE,
                                   inject_payload_faults, round_tag_key,
                                   scenario_hyperparams, scenario_latencies,
                                   scenario_masks)
-from repro.fl.engine import BatchedEngine, make_engine
+from repro.fl.engine import BatchedEngine, make_engine, ravel_rows
 from repro.fl.runtime import (RoundCarry, RoundCfg, RoundStreams,
                               init_cohort_carry, init_round_carry,
                               scan_rounds)
@@ -312,21 +312,27 @@ class FusedPAOTA:
         return self.engine._train_all(params, x, y, idx, steps)
 
     def _cohort_train(self, global_state, x, y, broadcast_round, ids):
-        """Cohort twin of ``_local_train_all``: gather the (m,) scheduled
-        clients' data rows and train ONLY those — each client's minibatch
-        plan / heterogeneity traits key on its global id, so a client's
-        trained row is identical whichever slot (or dense row) computes
-        it."""
+        """Cohort twin of ``_local_train_all``: train ONLY the (m,)
+        scheduled clients at rows ``ids``."""
         ids = ids.astype(jnp.uint32)
-        idx = self.engine.round_plan(broadcast_round, client_ids=ids,
-                                     n_samples=self.engine._n_dev[ids])
-        steps = self.engine.steps_for(ids)
-        xs, ys = x[ids], y[ids]
+        return self._train_slots(global_state, x, y, broadcast_round, ids,
+                                 ids)
+
+    def _train_slots(self, global_state, x, y, broadcast_round, rows, gids):
+        """Train the cohort slots whose data sit at rows ``rows`` of
+        (x, y) and whose GLOBAL client ids are ``gids`` (the same ids on
+        one device, shard-offset ones under sharding). Each client's
+        minibatch plan and heterogeneity traits key on its global id, so
+        a client's trained row is identical whichever slot, shard (or
+        dense row) computes it."""
+        eng = self.engine
+        idx = eng.round_plan(broadcast_round, client_ids=gids,
+                             n_samples=eng._n_dev[gids])
+        steps = eng.steps_for(gids)
         if self.params_mode == "pytree":
-            return self.engine._train_all_tree(global_state, xs, ys, idx,
-                                               steps)
-        return self.engine._train_all(self.unravel(global_state), xs, ys,
-                                      idx, steps)
+            return eng._train_gathered(global_state, x, y, rows, idx, steps)
+        return ravel_rows(eng._train_gathered(self.unravel(global_state), x,
+                                              y, rows, idx, steps))
 
     def _faulty_local_train(self, global_state, x, y, broadcast_round):
         """``_local_train_all`` with the round's payload faults injected:

@@ -567,15 +567,7 @@ class ShardedPAOTA(FusedPAOTA):
             # whichever shard/slot computes it
             global_state = vary_over(global_state, self.client_axes)
             gids = (offset.astype(jnp.uint32) + ids.astype(jnp.uint32))
-            idx = self.engine.round_plan(r, client_ids=gids,
-                                         n_samples=n_dev[gids])
-            steps = self.engine.steps_for(gids)
-            xs, ys = x[ids], y[ids]
-            if self.params_mode == "pytree":
-                return self.engine._train_all_tree(global_state, xs, ys, idx,
-                                                   steps)
-            return self.engine._train_all(self.unravel(global_state), xs, ys,
-                                          idx, steps)
+            return self._train_slots(global_state, x, y, r, ids, gids)
 
         scn = self.scenario
         if scn is None:

@@ -16,9 +16,11 @@ touches it while modules are imported.
 
 Whole programs are compiled too: the one-round advance of a small
 paper federation, whose data plane must stay out of a cross-program
-prefetch (``repro.fl.fused.TPU_SCAN_OPTIONS``), and the scan of a
+prefetch (``repro.fl.fused.TPU_SCAN_OPTIONS``), the scan of a
 two-layer federation of smollm-width clients, whose kernel operands
-must reach the kernels without relayout loops.
+must reach the kernels without relayout loops, and the scan of a
+paper-shaped compressed cohort, whose slots must gather their
+minibatches without relaying out the data plane.
 """
 import jax
 import jax.numpy as jnp
@@ -322,3 +324,110 @@ def test_scan_draws_the_superposition_noise_in_each_leafs_layout(
                 while x in defs and defs[x][0] in through:
                     x = defs[x][1][0]
                 assert x not in defs or defs[x][0] != "reshape", (call, x)
+
+
+# the paper-shaped cohort: K clients of up to N samples of F features, m
+# slots training M local steps of B samples
+COHORT = dict(k=1000, n=300, f=784, m=64, steps=5, batch=32)
+
+
+@pytest.fixture(scope="module")
+def cohort_scan(one_chip):
+    """The compiled HLO text of the 2-period scan of a paper-shaped
+    compressed cohort (K=1000 MLP clients, a (1000, 300, 784) f32 data
+    plane, 64 slots, M=5 steps of B=32, randmask 1/16, int8 slots,
+    raveled, at HIGHEST), compiled for the described chip. The clients
+    are built with four samples each: only the planes' shapes are the
+    paper's."""
+    import numpy as np
+
+    from repro.core import ChannelConfig, SchedulerConfig
+    from repro.data.pipeline import ClientData
+    from repro.fl import FLClient, FusedPAOTA, PAOTAConfig
+    from repro.fl.fused import TPU_SCAN_OPTIONS
+    from repro.models.mlp import init_mlp_params, mlp_loss
+
+    c = COHORT
+    clients = [FLClient(ClientData(np.zeros((4, c["f"]), np.float32),
+                                   np.zeros(4, np.int32), i), mlp_loss,
+                        batch_size=c["batch"], lr=0.1,
+                        local_steps=c["steps"]) for i in range(c["k"])]
+    srv = FusedPAOTA(init_mlp_params(jax.random.PRNGKey(0)), clients,
+                     ChannelConfig(),
+                     SchedulerConfig(n_clients=c["k"], seed=1),
+                     PAOTAConfig(transmit="delta"), cohort_size=c["m"],
+                     compress="randmask", compress_ratio=1 / 16,
+                     slot_dtype="int8")
+    x = _spec(one_chip, (c["k"], c["n"], c["f"]))
+    y = _spec(one_chip, (c["k"], c["n"]), jnp.int32)
+    put = lambda t: jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, a.dtype), t)
+    with jax.default_matmul_precision("highest"):
+        carry = jax.eval_shape(srv._init_carry, srv._init_global, x, y)
+        return srv._jit_scan.lower(put(carry), x, y, n_rounds=2).compile(
+            compiler_options=TPU_SCAN_OPTIONS).as_text()
+
+
+def _loop_computations(text):
+    """Name -> text of every computation a ``while`` loop of the compiled
+    HLO ``text`` runs: the loops' bodies and conditions and all that they
+    call, however deep."""
+    import re
+
+    comps = {}
+    for block in re.split(r"\n(?=\S)", text):
+        m = re.match(r"(?:ENTRY )?%([\w.\-]+) ", block)
+        if m:
+            comps[m.group(1)] = block
+    refs = r"(?:calls|to_apply|body|condition)=%([\w.\-]+)"
+    todo = re.findall(r"while\(.*?body=%([\w.\-]+)", text)
+    seen = {}
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen[name] = comps[name]
+        todo += re.findall(refs, comps[name])
+        for group in re.findall(r"branch_computations=\{([^}]*)\}",
+                                comps[name]):
+            todo += re.findall(r"%([\w.\-]+)", group)
+    return seen
+
+
+def test_cohort_gathers_minibatches_without_relaying_the_plane(
+        cohort_scan, smollm_scan):
+    """In the paper-shaped cohort's scan, no instruction that the loop
+    runs, but a parameter and its pass-through (get-tuple-element), has
+    the data plane's (K, N, 784) shape, nor a (K, N, <784) slice of it:
+    no period copies the plane into another layout or slices it row by
+    row. The slots' minibatches come out of one gather of m x M x B
+    rows. Outside the loop, the plane is relaid out at most once per
+    call. The dense smollm scan, whose training keeps its per-step
+    gather, still holds no relayout loop."""
+    import re
+
+    c = COHORT
+    plane = re.compile(rf"^\s*(?:ROOT )?%\S+ = f32\[{c['k']},{c['n']},(\d+)\]"
+                       rf"\S* ([\w\-]+)\(")
+    passes = ("parameter", "get-tuple-element")
+    loop = _loop_computations(cohort_scan)
+    assert loop
+    bad = []
+    for body in loop.values():
+        for line in body.splitlines():
+            m = plane.match(line)
+            if m and (m.group(2) not in passes or int(m.group(1)) != c["f"]):
+                bad.append(line.strip()[:160])
+    assert not bad, bad
+    made = [m.group(2) for m in map(plane.match, cohort_scan.splitlines())
+            if m and m.group(2) not in passes]
+    assert len(made) <= 1, made
+    rows = c["m"] * c["steps"] * c["batch"]
+    gathers = [line for body in loop.values() for line in body.splitlines()
+               if re.match(rf"^\s*(?:ROOT )?%\S+ = f32\[{rows},{c['f']}\]",
+                           line) and "paota.train/gather" in line]
+    assert gathers
+    for body in smollm_scan:
+        loops = [line for line in body.splitlines()
+                 if re.search(r"\) while\(|= \S+ while\(", line)]
+        assert not [line for line in loops if "op_name=" not in line]
