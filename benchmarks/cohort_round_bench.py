@@ -94,8 +94,8 @@ def _synth_scan(k: int, m: int, rounds: int = _ROUNDS, *,
                                       TAG_QUANT, TAG_SCHED, ScenarioConfig,
                                       counter_latencies, round_tag_key,
                                       scenario_masks)
-    from repro.fl.runtime import (RoundCfg, RoundStreams, init_cohort_carry,
-                                  init_round_carry, scan_rounds)
+    from repro.fl.runtime import (RoundCfg, RoundStreams, init_round_carry,
+                                  scan_rounds)
 
     d = _SYNTH_D
     key = jax.random.PRNGKey(0)
@@ -106,7 +106,7 @@ def _synth_scan(k: int, m: int, rounds: int = _ROUNDS, *,
     s = min(d, max(1, round(d * ratio)))
     rcfg = RoundCfg(omega=3.0, c1=c1, c0=c0, p_max_watts=chan.p_max_watts,
                     sigma_n=chan.sigma_n, delta_t=8.0, transmit_delta=True,
-                    cohort_size=m, compress=compress,
+                    compress=compress,
                     compress_s=s if compress else 0,
                     slot_dtype=((slot_dtype or "float32") if compress
                                 else ""),
@@ -141,14 +141,9 @@ def _synth_scan(k: int, m: int, rounds: int = _ROUNDS, *,
     x = y = jnp.zeros((1,), jnp.float32)
 
     t0 = time.perf_counter()
-    if m:
-        carry = jax.jit(lambda v: init_cohort_carry(
-            v, x, y, streams=streams, k=k, m=m, pending_dtype="float32",
-            keep_pending=False, rcfg=rcfg))(g0)
-    else:
-        carry = jax.jit(lambda v: init_round_carry(
-            v, x, y, streams=streams, pending_dtype="float32",
-            keep_pending=False))(g0)
+    carry = jax.jit(lambda v: init_round_carry(
+        v, x, y, streams=streams, pending_dtype="float32",
+        keep_pending=False, rcfg=rcfg, m=m))(g0)
     nbytes = _carry_bytes(carry)
     scan = jax.jit(lambda c: scan_rounds(c, x, y, rounds, rcfg=rcfg,
                                          streams=streams),

@@ -78,8 +78,8 @@ def _synth_scan(k: int, m: int, rounds: int = _ROUNDS, *,
                                       fault_payload_masks,
                                       inject_payload_faults, round_tag_key,
                                       scenario_masks)
-    from repro.fl.runtime import (RoundCfg, RoundStreams, init_cohort_carry,
-                                  init_round_carry, scan_rounds)
+    from repro.fl.runtime import (RoundCfg, RoundStreams, init_round_carry,
+                                  scan_rounds)
 
     d = _SYNTH_D
     key = jax.random.PRNGKey(0)
@@ -89,7 +89,7 @@ def _synth_scan(k: int, m: int, rounds: int = _ROUNDS, *,
     c1, c0 = p2_constants(10.0, 0.05, k, d, chan.sigma_n2)
     rcfg = RoundCfg(omega=3.0, c1=c1, c0=c0, p_max_watts=chan.p_max_watts,
                     sigma_n=chan.sigma_n, delta_t=8.0, transmit_delta=True,
-                    cohort_size=m, screen=bool(screen))
+                    screen=bool(screen))
 
     def fan(g, r, ids):
         # tag 12: clear of the scheduler's reserved draw tags (0-10)
@@ -122,14 +122,9 @@ def _synth_scan(k: int, m: int, rounds: int = _ROUNDS, *,
     x = y = jnp.zeros((1,), jnp.float32)
 
     t0 = time.perf_counter()
-    if m:
-        carry = jax.jit(lambda v: init_cohort_carry(
-            v, x, y, streams=streams, k=k, m=m, pending_dtype="float32",
-            keep_pending=False, rcfg=rcfg))(g0)
-    else:
-        carry = jax.jit(lambda v: init_round_carry(
-            v, x, y, streams=streams, pending_dtype="float32",
-            keep_pending=False, rcfg=rcfg))(g0)
+    carry = jax.jit(lambda v: init_round_carry(
+        v, x, y, streams=streams, pending_dtype="float32",
+        keep_pending=False, rcfg=rcfg, m=m))(g0)
     nbytes = _carry_bytes(carry)
     scan = jax.jit(lambda c: scan_rounds(c, x, y, rounds, rcfg=rcfg,
                                          streams=streams),

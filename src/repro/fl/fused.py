@@ -7,9 +7,11 @@ factors, P2 solve, channel draw, power cap (7), AirComp — which caps
 simulation throughput far below hardware speed at K = 1000+. Here every
 stage is pure jnp over array state: the round transition itself lives in
 ``repro.fl.runtime.paota_round_step`` (``RoundCarry`` in, ``RoundCarry``
-out — one functional core shared with the mesh-sharded driver
-``repro.fl.sharded.ShardedPAOTA``), and this driver runs it single-device
-with ``lax.scan`` over R rounds and zero host round-trips inside the scan.
+out — one functional core for the dense and the active-cohort carry,
+shared with the mesh-sharded driver ``repro.fl.sharded.ShardedPAOTA``,
+with its round-0 carry from ``init_round_carry``), and this driver runs it
+single-device with ``lax.scan`` over R rounds and zero host round-trips
+inside the scan.
 
 Randomness is counter-based (repro.core.scheduler.round_tag_key): latency,
 channel, noise, and minibatch draws are keyed on (seed, round, tag), never
@@ -46,8 +48,7 @@ from repro.core.scheduler import (TAG_CHANNEL, TAG_COMPRESS, TAG_NOISE,
                                   scenario_masks)
 from repro.fl.engine import BatchedEngine, make_engine, ravel_rows
 from repro.fl.runtime import (RoundCarry, RoundCfg, RoundStreams,
-                              init_cohort_carry, init_round_carry,
-                              scan_rounds)
+                              init_round_carry, scan_rounds)
 from repro.fl.server import PAOTAConfig
 
 __all__ = ["FusedPAOTA", "RoundCarry"]
@@ -255,7 +256,6 @@ class FusedPAOTA:
                               delta_t=sched_cfg.delta_t,
                               transmit_delta=cfg.transmit == "delta",
                               pending_dtype=pending_dtype,
-                              cohort_size=self.cohort_size,
                               compress=self.compress,
                               compress_s=self.compress_s,
                               slot_dtype=((sd or pending_dtype)
@@ -430,17 +430,10 @@ class FusedPAOTA:
     def _init_carry(self, vec, x, y) -> RoundCarry:
         # transmit='delta' never reads the full local models: the carry is
         # the delta plane alone (half the K x d working set)
-        if self.cohort_size:
-            return init_cohort_carry(
-                vec, x, y, streams=self._streams(), k=self.k,
-                m=self.cohort_size,
-                pending_dtype=self._rcfg.pending_dtype,
-                keep_pending=not self._rcfg.transmit_delta,
-                rcfg=self._rcfg)
         return init_round_carry(vec, x, y, streams=self._streams(),
                                 pending_dtype=self._rcfg.pending_dtype,
                                 keep_pending=not self._rcfg.transmit_delta,
-                                rcfg=self._rcfg)
+                                rcfg=self._rcfg, m=self.cohort_size)
 
     def _run_scan(self, carry: RoundCarry, x, y, n_rounds: int):
         return scan_rounds(carry, x, y, n_rounds, rcfg=self._rcfg,

@@ -56,9 +56,10 @@ update -> broadcast + local train. It is parameterized by
   the P2 water-filling reductions, and the round metrics cross shards as
   ``psum``/``pmin``/``pmax`` collectives.
 
-Active-cohort mode (``RoundCfg.cohort_size`` m >= 1) splits the carry
-into TWO planes: a dense (K,) client-state plane — scheduler bits,
-staleness clocks, and the vectorized scenario simulator
+Active-cohort mode (``init_round_carry(..., m=m)``, m >= 1: the carry
+then holds ``slot_client``) splits the carry into TWO planes: a dense
+(K,) client-state plane — scheduler bits, staleness clocks, and the
+vectorized scenario simulator
 (``repro.core.scheduler.ScenarioConfig``: availability cycles, dropouts,
 lognormal responsiveness), all O(K) scalars advanced inside the scan —
 and an (m, ...) active-cohort payload plane holding model-sized rows for
@@ -66,8 +67,13 @@ the in-flight cohort only (``slot_client`` / ``slot_live``). Freed slots
 refill from the available idle pool by counter-RNG priority. The K x d
 carry stops scaling with K: a K = 10^6 federation advances its state
 plane on one host while only m payload rows materialize
-(benchmarks/cohort_round_bench.py). ``cohort_size=0`` (the default) is
-the historical dense program, bit for bit.
+(benchmarks/cohort_round_bench.py). The same ``paota_round_step`` and
+``init_round_carry`` serve both carries: every stage is written once, and
+the few places where a slot plane differs (how a row sees the (K,)
+state, the P2 row order and channel gather, compressed stats and
+superposition, slot turnover with the residual hand-off, the compressed
+store) are trace-time branches on the carry's structure, so the dense
+program emits none of them.
 
 Consumers: ``repro.fl.fused.FusedPAOTA`` (single device, scan over
 rounds, carry donated between scans), ``repro.fl.sharded.ShardedPAOTA``
@@ -77,7 +83,7 @@ shared stage helpers (``eq25_factors`` / ``constraint7_powers``) so the
 three implementations cannot drift apart stage by stage.
 
 Each stage of the round runs under one of ``STAGE_SCOPES``
-(``jax.named_scope``), in the dense and the cohort step alike
+(``jax.named_scope``), on the dense and the cohort carry alike
 (``paota.compress`` only where a cohort compresses its slots), so every
 device operation of the compiled scan carries its stage in its
 ``op_name`` metadata and a profiler trace can be split by stage. The
@@ -165,13 +171,14 @@ class RoundCarry(NamedTuple):
                               # replicated intra-pod, zeroed at every sync.
                               # None on the flat path.
     slot_client: jnp.ndarray = None  # active-cohort mode only
-                              # (cohort_size m >= 1): (m,) i32 — which client
-                              # occupies each payload slot (shard-LOCAL row
-                              # index under sharding). The (K,) state plane
-                              # stays dense and tiny; `pending`/`deltas`
-                              # shrink to (m, ...) rows gathered for the
-                              # in-flight cohort only, so the K x d carry
-                              # stops scaling with K. None on the dense path.
+                              # (init_round_carry's m >= 1): (m,) i32 —
+                              # which client occupies each payload slot
+                              # (shard-LOCAL row index under sharding). The
+                              # (K,) state plane stays dense and tiny;
+                              # `pending`/`deltas` shrink to (m, ...) rows
+                              # gathered for the in-flight cohort only, so
+                              # the K x d carry stops scaling with K. None
+                              # on the dense path.
     slot_live: jnp.ndarray = None    # (m,) bool — slot holds a real
                               # in-flight client (False = phantom row:
                               # b_k = 0 through every reduction, exactly the
@@ -224,15 +231,10 @@ class RoundCfg(NamedTuple):
                               # style): 0 = flat (cross-shard sync every
                               # period); N >= 1 = intra-pod partials every
                               # period, ONE cross-pod psum every N periods
-    cohort_size: int = 0      # active-cohort mode: 0 = dense (every client
-                              # carries a payload row — bit-identical to the
-                              # historical round); m >= 1 = at most m clients
-                              # in flight, payload planes are (m, ...) slot
-                              # rows (gather on schedule, scatter on upload)
     compress: str = ""        # compressed cohort payloads: "" = off (the
-                              # PR 7 program, bit for bit); "topk" /
+                              # uncompressed program, bit for bit); "topk" /
                               # "randmask" = slots carry an (m, s) plane on
-                              # per-slot supports. Requires cohort_size,
+                              # per-slot supports. Requires a cohort carry,
                               # transmit_delta, raveled params.
     compress_s: int = 0       # static compressed width s; s == d routes
                               # the dense stats/AirComp stages statically
@@ -532,6 +534,16 @@ def _slot_dtype(rcfg: RoundCfg) -> str:
     return rcfg.slot_dtype or rcfg.pending_dtype
 
 
+class _SlotPlanes(NamedTuple):
+    """``_compress_plane``'s outputs, named by the carry fields they fill;
+    the planes a scheme does not keep stay None."""
+    deltas: jnp.ndarray                 # (m, s) stored values
+    slot_idx: jnp.ndarray               # (m, s) i32 supports
+    slot_scale: jnp.ndarray = None      # (m,) f32, int8 storage only
+    slot_resid: jnp.ndarray = None      # (m, s) f32 EF residuals
+    slot_resid_idx: jnp.ndarray = None  # (m, s) i32 residual supports
+
+
 def _compress_plane(comp, *, rcfg: RoundCfg, streams: RoundStreams, t):
     """Compress freshly trained (m, d) f32 rows (EF-compensated deltas)
     into the carry's slot planes.
@@ -545,8 +557,7 @@ def _compress_plane(comp, *, rcfg: RoundCfg, streams: RoundStreams, t):
     EF residual is the exact f32 complement of the row against its stored
     reconstruction, re-sparsified to width s for the carry.
 
-    Returns (stored (m, s), idx (m, s) i32, scale (m,) f32 | None,
-    resid (m, s) f32 | None, resid_idx (m, s) i32 | None)."""
+    Returns the ``_SlotPlanes``."""
     m, d = comp.shape
     s = rcfg.compress_s
     if s >= d:
@@ -570,15 +581,145 @@ def _compress_plane(comp, *, rcfg: RoundCfg, streams: RoundStreams, t):
     else:
         stored = v_hat = vals
     if not rcfg.error_feedback:
-        return stored, idx, scale, None, None
+        return _SlotPlanes(stored, idx, scale)
     e = ef_residual(comp, idx, v_hat)
-    e_val, e_idx = sparsify(e, s)
-    return stored, idx, scale, e_val, e_idx
+    return _SlotPlanes(stored, idx, scale, *sparsify(e, s))
 
 
 # ---------------------------------------------------------------------------
 # the round transition
 # ---------------------------------------------------------------------------
+
+# Where a cohort's slot plane differs from the dense client plane: how a
+# row sees the (K,) state, the compressed stats and superposition, slot
+# turnover and the residual hand-off. Each is chosen at trace time from
+# the carry's structure (``slot_client`` set) or ``rcfg.compress``, and
+# the dense plane takes the branch that emits nothing.
+
+def _row_view(carry: RoundCarry, upl, stal):
+    """b and the staleness of each payload row. Dense rows are the
+    clients; a cohort slot reads its occupant's (K,) state, and a dead
+    slot reads b = 0, exactly the sharded drivers' phantom-row masking."""
+    if carry.slot_client is None:
+        return (upl.astype(jnp.float32),
+                jnp.where(upl, stal, 0).astype(jnp.float32))
+    occ, live = carry.slot_client, carry.slot_live
+    return ((live & upl[occ]).astype(jnp.float32),
+            jnp.where(live, stal[occ], 0).astype(jnp.float32))
+
+
+def _identity_support(carry: RoundCarry, rcfg: RoundCfg) -> bool:
+    """Compressed slots at the static s == d identity: the dense stats
+    and superposition stages run on the (dequantized) rows."""
+    return rcfg.compress_s >= carry.global_vec.shape[0]
+
+
+def _compressed_factors(carry: RoundCarry, stal, rcfg: RoundCfg):
+    """Stage 2 over compressed slots: the stats sweep runs on the (m, s)
+    values + EF residuals, never a dense (m, d) row; at the identity
+    support the dense sweep runs on the dequantized rows (f32/bf16
+    identity rows pass through untouched, so compress off and s == d
+    agree bit for bit). Returns (rho, theta, w_norm2, rows, packed): the
+    dequantized rows the dense stages read, and the stored (values,
+    scales) the compressed superposition reads."""
+    v_id = (carry.deltas if carry.slot_scale is None
+            else dequantize_int8(carry.deltas, carry.slot_scale))
+    if _identity_support(carry, rcfg):
+        rho, theta, w_norm2 = round_factors(
+            v_id, None, carry.global_vec, carry.prev_global, stal,
+            rcfg.omega)
+    else:
+        rho, theta, w_norm2 = compressed_round_factors(
+            carry.deltas, carry.slot_idx, carry.slot_resid,
+            carry.slot_resid_idx, carry.global_vec, carry.prev_global, stal,
+            rcfg.omega, scale=carry.slot_scale)
+    return rho, theta, w_norm2, v_id, (carry.deltas, carry.slot_scale)
+
+
+def _zero_packed(packed, ok):
+    """Screened rows out of the compressed slots' stored values and their
+    dequantization scales, so an int8 slot with a NaN absmax scale
+    contributes 0 * 0, never 0 * NaN. None (uncompressed) stays None."""
+    if packed is None:
+        return None
+    vals, scale = packed
+    vals = _zero_rows(vals, ok)
+    return vals, (None if scale is None else jnp.where(ok, scale, 0.0))
+
+
+def _superpose(payload, packed, powers, b, carry: RoundCarry, *,
+               rcfg: RoundCfg, streams: RoundStreams, axis_name, tp):
+    """Eqs. 6+8 on the flat path: one superposition + AWGN +
+    normalization. Compressed slots (``packed`` set, s < d) decompress
+    inside the gather-superpose kernel (eq. 8 in d-space), the stored int8
+    plane feeding it directly with its scale folded into the weights."""
+    key = streams.noise_key(carry.t)
+    if packed is not None and not _identity_support(carry, rcfg):
+        vals, scale = packed
+        return paota_aggregate_compressed(
+            vals, carry.slot_idx, powers, b, key, rcfg.sigma_n,
+            carry.global_vec.shape[0], scale=scale, axis_name=axis_name)
+    return paota_aggregate_stacked(payload, powers, b, key, rcfg.sigma_n,
+                                   axis_name=axis_name, tp=tp)
+
+
+def _refill_slots(carry: RoundCarry, restart, avail, streams: RoundStreams):
+    """Slot turnover: departing occupants (uploaded, or upload dropped in
+    transit) free their slots, and available idle clients fill them in
+    counter-RNG priority order (``streams.sched_priority``, descending
+    ``lax.top_k``: an O(K log K) sort-plane op, no priority queue). Dead
+    slots contribute nothing anywhere (live = False).
+
+    Returns (depart, take, new_occ, new_live, sched_k): the departing
+    slots, the slots that take a newly scheduled client, the new slot
+    map, and the (K,) mask of the scheduled clients."""
+    k_local = carry.ready.shape[0]
+    occ, live = carry.slot_client, carry.slot_live
+    m = occ.shape[0]
+    depart = live & restart[occ]
+    stay = live & ~depart
+    in_flight = jnp.zeros((k_local,), bool).at[occ].max(stay, mode="drop")
+    prio = streams.sched_priority(carry.t)
+    score = jnp.where(avail & ~in_flight, prio, -jnp.inf)
+    top_score, top_ids = jax.lax.top_k(score, m)
+    n_cand = jnp.sum((top_score > -jnp.inf).astype(jnp.int32))
+    free = ~stay
+    free_rank = jnp.cumsum(free.astype(jnp.int32)) - 1
+    take = free & (free_rank < n_cand)
+    new_occ = jnp.where(take, top_ids[jnp.clip(free_rank, 0, m - 1)],
+                        occ).astype(jnp.int32)
+    new_live = stay | take
+    sched_k = jnp.zeros((k_local,), bool).at[new_occ].max(take, mode="drop")
+    return depart, take, new_occ, new_live, sched_k
+
+
+def _hand_off_residuals(carry: RoundCarry, depart, take, new_occ,
+                        rcfg: RoundCfg):
+    """EF residuals on slot turnover: FIRST every departing slot parks its
+    residual on the owning client's (K, s) row, THEN the newly scheduled
+    occupants pick their parked rows back up (a same-round depart ->
+    reschedule resumes the residual it just parked), THEN the consumed
+    rows zero — the parked plane only ever holds errors nobody is
+    currently training against.
+
+    Returns (resid_val, resid_idx, resumed values, resumed supports)."""
+    k_local = carry.ready.shape[0]
+    park_row = jnp.where(depart, carry.slot_client, k_local)  # OOB: no write
+    resid_val = carry.resid_val.at[park_row].set(carry.slot_resid,
+                                                 mode="drop")
+    resid_idx = carry.resid_idx.at[park_row].set(carry.slot_resid_idx,
+                                                 mode="drop")
+    pr_val = jnp.where(take[:, None], resid_val[new_occ], 0.0)
+    if rcfg.screen:
+        # a screened slot's parked residual may be the corrupt row's NaN
+        # complement — resuming it would re-poison every later round of
+        # an otherwise-recovered client
+        pr_val = jnp.where(jnp.isfinite(pr_val), pr_val, 0.0)
+    pr_idx = resid_idx[new_occ]
+    consumed = jnp.where(take, new_occ, k_local)
+    resid_val = resid_val.at[consumed].set(0.0, mode="drop")
+    return resid_val, resid_idx, pr_val, pr_idx
+
 
 def paota_round_step(carry: RoundCarry, x, y, *, rcfg: RoundCfg,
                      streams: RoundStreams, axis_name=None,
@@ -589,6 +730,22 @@ def paota_round_step(carry: RoundCarry, x, y, *, rcfg: RoundCfg,
     ``axis_name=None`` is the single-device form. With a mesh axis name
     (or tuple of names), the (K,) / (K, d) carry rows are this shard's
     clients and the cross-client reductions go through collectives.
+
+    Active-cohort carry (``carry.slot_client`` set): the payload planes
+    are (m, ...) slot rows instead of (K, ...). The scheduler/simulator
+    state stays a dense (K,) plane; the slots gather their occupants'
+    b, staleness and channel from it, run the same stats / water-filling
+    / AirComp stages over m rows, and scatter the scheduler effects back.
+    Idle clients sit at ``busy_lat = +inf`` (``slot_ready`` never flips
+    them), and freed slots refill from the available idle pool
+    (``_refill_slots``). Only the scheduled slots train
+    (``streams.cohort_train``). Compressed slots (``rcfg.compress``) keep
+    an (m, s) plane on per-slot supports with error-feedback residuals
+    handed off through a (K, s) parked plane. At m = K (every client
+    permanently slotted) the step is the dense round up to slot
+    permutation — same uploader sets, same per-client draws, float
+    reduction order the only difference; an all-masked cohort hits the
+    same zero-uploader guard, holding w_g bit-identically.
 
     Intra-client TP (``tp``: ``repro.sharding.tp.TPTopology``, sharded
     pytree mode only): the payload planes additionally hold only this
@@ -614,43 +771,11 @@ def paota_round_step(carry: RoundCarry, x, y, *, rcfg: RoundCfg,
     period is a sync with held == 0, and since x + 0 is exact the program
     is op-for-op the flat path — grouped N=1 equals flat by construction.
 
-    Active-cohort mode (``rcfg.cohort_size`` m >= 1): the payload planes
-    are (m, ...) slot rows instead of (K, ...) — the round gathers channel/
-    staleness state for the in-flight cohort, runs the identical stats /
-    water-filling / AirComp stages over m rows, and scatters the scheduler
-    effects back into the dense-but-tiny (K,) state plane
-    (``_cohort_round_step``). Incompatible with grouped aggregation.
+    TP and grouped aggregation run on dense planes only; the drivers
+    refuse the other combinations before any step is traced.
 
     Returns (next_carry, per-round metrics dict of replicated scalars)."""
-    if rcfg.cohort_size:
-        if grouping is not None:
-            raise NotImplementedError(
-                f"active-cohort mode (cohort_size={rcfg.cohort_size}) does "
-                f"not compose with grouped aggregation (group_period="
-                f"{rcfg.group_period}) yet — the held cross-pod partial "
-                f"would need per-slot staleness bookkeeping; the nearest "
-                f"supported configurations are cohort_size="
-                f"{rcfg.cohort_size} with group_period=0 (flat sync every "
-                f"period) or group_period={rcfg.group_period} with "
-                f"cohort_size=0 (dense payload planes)")
-        if tp is not None:
-            raise NotImplementedError(
-                f"active-cohort mode (cohort_size={rcfg.cohort_size}) does "
-                f"not compose with intra-client TP (tp axes {tp.axes}) yet "
-                f"— the (m, s) slot planes are raveled and the TP split is "
-                f"per-leaf; the nearest supported configurations are "
-                f"cohort_size={rcfg.cohort_size} on a client-axes-only "
-                f"mesh, or TP with cohort_size=0 (dense payload planes)")
-        return _cohort_round_step(carry, x, y, rcfg=rcfg, streams=streams,
-                                  axis_name=axis_name)
-    if tp is not None and grouping is not None:
-        raise NotImplementedError(
-            f"grouped aggregation (group_period={rcfg.group_period}) does "
-            f"not compose with intra-client TP (tp axes {tp.axes}) yet — "
-            f"the held intra-pod partial is not TP-split; the nearest "
-            f"supported configurations are group_period="
-            f"{rcfg.group_period} with TP extent 1, or TP with "
-            f"group_period=0 (flat sync every period)")
+    cohort = carry.slot_client is not None
     k_local = carry.ready.shape[0]
     grouped = grouping is not None and rcfg.group_period >= 1
     sync = (not grouped) or (window_j == rcfg.group_period - 1)
@@ -663,14 +788,15 @@ def paota_round_step(carry: RoundCarry, x, y, *, rcfg: RoundCfg,
     # The finished test is the exact relative slot predicate over the
     # carried latency draws (repro.core.scheduler.slot_ready) — one f32
     # rounding, bit-identical to the host reference's mask at any horizon;
-    # `time` is report-only.
+    # `time` is report-only. On a cohort only the in-flight clients carry
+    # a finite latency draw, so only they can finish.
     with jax.named_scope("paota.schedule"):
         time = (carry.t + 1).astype(jnp.float32) * jnp.float32(rcfg.delta_t)
         ready, stal = sched_advance(carry.ready, carry.busy_lat,
                                     carry.model_round, carry.t, rcfg.delta_t)
         if streams.scenario is None:
-            # no scenario: uploaders = restarters = the ready set — this
-            # branch is the historical program, bit-identical op for op
+            # no scenario: uploaders = restarters = the ready set
+            avail = jnp.ones((k_local,), bool) if cohort else None
             upl = restart = ready
         else:
             # scenario masks (trace-time branch: the callback is None
@@ -681,20 +807,24 @@ def paota_round_step(carry: RoundCarry, x, y, *, rcfg: RoundCfg,
             avail, drop = streams.scenario(carry.t)
             upl = ready & avail & ~drop
             restart = ready & avail
-        b = upl.astype(jnp.float32)
-        stal = jnp.where(upl, stal, 0).astype(jnp.float32)
+        b, stal = _row_view(carry, upl, stal)
 
     # 2. staleness + gradient-similarity factors (eq. 25) + the payload
     # norms for constraint (7): ONE sweep over the carried delta plane
     # (sweep 1 of 2)
     with jax.named_scope("paota.stats"):
-        payload = carry.deltas if rcfg.transmit_delta else carry.pending
-        rho, theta, w_norm2 = round_factors(
-            carry.deltas, None if rcfg.transmit_delta else carry.pending,
-            carry.global_vec, carry.prev_global, stal, rcfg.omega, tp=tp)
+        packed = None
+        if rcfg.compress:
+            rho, theta, w_norm2, payload, packed = _compressed_factors(
+                carry, stal, rcfg)
+        else:
+            payload = carry.deltas if rcfg.transmit_delta else carry.pending
+            rho, theta, w_norm2 = round_factors(
+                carry.deltas, None if rcfg.transmit_delta else carry.pending,
+                carry.global_vec, carry.prev_global, stal, rcfg.omega, tp=tp)
 
         # 2b. containment (trace-time branch — screen=False emits the
-        # historical program op for op): rows the stats sweep exposed as
+        # unscreened program op for op): rows the stats sweep exposed as
         # corrupt (non-finite) or norm-fenced are masked out of this
         # round's superposition exactly like phantom clients — b = 0, the
         # payload row zeroed so every contraction sees exact +0.0, and the
@@ -707,6 +837,7 @@ def paota_round_step(carry: RoundCarry, x, y, *, rcfg: RoundCfg,
             ok, theta, w_norm2 = _screen_ok(theta, w_norm2, rcfg)
             n_screened = ksum(b * (~ok).astype(jnp.float32))
             b = b * ok.astype(jnp.float32)
+            packed = _zero_packed(packed, ok)
             payload = _zero_rows(payload, ok)
 
     # 3. P2 -> beta -> powers (exact water-filling, pure jnp; the grid and
@@ -716,14 +847,29 @@ def paota_round_step(carry: RoundCarry, x, y, *, rcfg: RoundCfg,
     # collective outside the sync.
     with jax.named_scope("paota.power"):
         wf_axes = axis_name if sync else (grouping.intra_axes or None)
-        p_max = jnp.full((k_local,), rcfg.p_max_watts, jnp.float32)
-        beta, p2_obj = waterfill_beta_jnp(rho, theta, p_max, b, rcfg.c1,
-                                          rcfg.c0, axis_name=wf_axes)
+        p_max = jnp.full((b.shape[0],), rcfg.p_max_watts, jnp.float32)
+        p2_rows = (rho, theta, b)
+        if cohort:
+            # P2 is solved over the slots in client-id order. Its objective
+            # is flat near the optimum (cells a float ulp apart), so the
+            # K-sums' association order picks beta; in slot order that
+            # order followed the refill history and the host's SIMD width,
+            # not the client set.
+            order = jnp.argsort(jnp.where(carry.slot_live, carry.slot_client,
+                                          k_local))
+            p2_rows = tuple(v[order] for v in p2_rows)
+        beta, p2_obj = waterfill_beta_jnp(p2_rows[0], p2_rows[1], p_max,
+                                          p2_rows[2], rcfg.c1, rcfg.c0,
+                                          axis_name=wf_axes)
+        if cohort:
+            beta = jnp.zeros_like(beta).at[order].set(beta)
         powers = power_from_beta(beta, rho, theta, p_max)
 
         # 4. instantaneous power constraint (7) under the sampled channel —
         # the payload norms came with the stats sweep, no extra pass
         h = streams.channel(carry.t)
+        if cohort:
+            h = jnp.where(carry.slot_live, h[carry.slot_client], 0.0)
         powers = constraint7_powers(powers, payload, h, rcfg.p_max_watts,
                                     w_norm2=w_norm2)
 
@@ -735,9 +881,9 @@ def paota_round_step(carry: RoundCarry, x, y, *, rcfg: RoundCfg,
             # flat path: the superposition is ONE psum over the client axes
             # (or the single-device einsum) with the noise joining once
             # after
-            agg, varsigma = paota_aggregate_stacked(
-                payload, powers, b, streams.noise_key(carry.t), rcfg.sigma_n,
-                axis_name=axis_name, tp=tp)
+            agg, varsigma = _superpose(payload, packed, powers, b, carry,
+                                       rcfg=rcfg, streams=streams,
+                                       axis_name=axis_name, tp=tp)
             new_global, new_prev = guarded_global_update(
                 carry.global_vec, carry.prev_global, agg, varsigma,
                 delta=rcfg.transmit_delta)
@@ -783,24 +929,73 @@ def paota_round_step(carry: RoundCarry, x, y, *, rcfg: RoundCfg,
     # 7. broadcast w^{r+1}: every restarter — uploader, or dropped uploader
     # whose update was lost in transit — begins fresh local training (at a
     # grouped non-sync period the rebroadcast model is the held global).
-    # The carry's delta rows are refreshed as f32 ``trained - w_g^{r+1}``
-    # BEFORE the storage cast.
+    # On a cohort the restarters leave their slots and the newly scheduled
+    # clients take the broadcast instead.
+    fields = {}
+    fresh = restart
     with jax.named_scope("paota.schedule"):
+        if cohort:
+            depart, fresh, new_occ, new_live, sched_k = _refill_slots(
+                carry, restart, avail, streams)
+            fields.update(slot_client=new_occ, slot_live=new_live)
         t_next = carry.t + 1
         lat = streams.latencies(t_next)
+        busy = carry.busy_lat
+        if cohort:
+            # departed-but-unscheduled clients go idle (busy_lat = +inf —
+            # never ready again until rescheduled)
+            departed = jnp.zeros((k_local,), bool).at[carry.slot_client].max(
+                depart, mode="drop")
+            idle = departed & ~sched_k
+            ready = jnp.where(idle, False, ready)
+            busy = jnp.where(idle, jnp.asarray(jnp.inf, busy.dtype), busy)
+            restart = sched_k
         n_ready, n_lat, n_model = sched_broadcast(
-            ready, carry.busy_lat, carry.model_round, restart, lat, t_next)
+            ready, busy, carry.model_round, restart, lat, t_next)
+        resumed = None
+        if rcfg.compress and rcfg.error_feedback:
+            resid_val, resid_idx, *resumed = _hand_off_residuals(
+                carry, depart, fresh, new_occ, rcfg)
+            fields.update(resid_val=resid_val, resid_idx=resid_idx)
+
+    # 8. local training: the carry's rows that take the broadcast (every
+    # dense restarter, or the newly scheduled slots) get their trained
+    # rows; the others keep their in-flight payload, and dead slots keep
+    # masked garbage
     with jax.named_scope("paota.train"):
-        trained = streams.local_train(new_global, x, y, t_next)
+        if cohort:
+            trained = streams.cohort_train(new_global, x, y, t_next, new_occ)
+        else:
+            trained = streams.local_train(new_global, x, y, t_next)
     dtype = _storage_dtype(rcfg)
 
     def row_select(new, old):
-        m = restart.reshape((k_local,) + (1,) * (new.ndim - 1))
+        m = fresh.reshape((fresh.shape[0],) + (1,) * (new.ndim - 1))
         return jnp.where(m, new, old)
 
+    if rcfg.compress:
+        with jax.named_scope("paota.compress"):
+            # compressed store: the f32 delta rows are EF-compensated with
+            # the resumed parked residuals (decompressed transiently — the
+            # carry never holds an (m, d) plane), then support-selected,
+            # stored, and their exact f32 residual re-sparsified.
+            # Raveled single-leaf: `trained` is a bare (m, d) array.
+            comp = trained - new_global[None]
+            if resumed is not None:
+                comp = comp + scatter_rows(*resumed, comp.shape[1])
+            planes = _compress_plane(comp, rcfg=rcfg, streams=streams,
+                                     t=t_next)
+
     with jax.named_scope("paota.carry_write"):
-        pending, deltas = _carry_write(trained, new_global, carry,
-                                       row_select, dtype, tp)
+        if rcfg.compress:
+            # the planes a scheme does not keep stay None; rows that take
+            # no broadcast keep every old slot plane
+            fields.update({f: row_select(v, getattr(carry, f))
+                           for f, v in planes._asdict().items()
+                           if v is not None})
+        else:
+            fields["pending"], fields["deltas"] = _carry_write(
+                trained, new_global, carry, row_select, dtype, tp)
 
     n_upl = ksum(b)
     denom = jnp.maximum(n_upl, 1.0)
@@ -836,403 +1031,85 @@ def paota_round_step(carry: RoundCarry, x, y, *, rcfg: RoundCfg,
         "n_screened": n_screened,
         "rolled_back": rolled,
     }
-    carry = RoundCarry(t=t_next, time=time, ready=n_ready,
-                       busy_lat=n_lat, model_round=n_model,
-                       global_vec=new_global, prev_global=new_prev,
-                       pending=pending, deltas=deltas, held=held,
-                       good_global=good, good_norm2=good_n2)
-    return carry, out
-
-
-def _cohort_round_step(carry: RoundCarry, x, y, *, rcfg: RoundCfg,
-                       streams: RoundStreams, axis_name=None):
-    """Active-cohort form of the round: (K,) state plane + (m, d) payload
-    plane.
-
-    The scheduler/simulator state (``ready``, ``busy_lat``,
-    ``model_round`` — plus the scenario masks) stays a dense (K,) plane:
-    tiny, O(K) not O(K d). Model-sized rows exist ONLY for the m slots of
-    the in-flight cohort (``slot_client`` maps slot -> client row,
-    ``slot_live`` masks unfilled slots exactly like the sharded drivers'
-    phantom clients), so the eq.-25 stats, water-filling, constraint (7),
-    and AirComp stages — unchanged, shape-agnostic in their leading axis —
-    run over m rows. Idle clients sit at ``busy_lat = +inf`` (the
-    ``slot_ready`` predicate can never flip them), and freed slots are
-    refilled from the available idle pool by counter-RNG priority
-    (``streams.sched_priority``; descending ``lax.top_k``) — an O(K log K)
-    sort-plane op, no Python priority queue.
-
-    Equivalences: at m = K (every client permanently slotted) the step is
-    the dense round up to slot permutation — same uploader sets, same
-    per-client draws, float reduction order the only difference. An
-    all-masked cohort (b = 0 everywhere) hits the same zero-uploader guard
-    as the dense path, holding w_g bit-identically."""
-    k_local = carry.ready.shape[0]
-    occ, live = carry.slot_client, carry.slot_live
-    m = occ.shape[0]
-
-    def ksum(v, axis=None):
-        s = jnp.sum(v, axis=axis)
-        return s if axis_name is None else jax.lax.psum(s, axis_name)
-
-    # 1. (K,) state plane advance + scenario masks (same stages as the
-    # dense step — sched_advance only ever flips clients whose carried
-    # latency draw is finite, i.e. the in-flight cohort)
-    with jax.named_scope("paota.schedule"):
-        time = (carry.t + 1).astype(jnp.float32) * jnp.float32(rcfg.delta_t)
-        ready, stal_k = sched_advance(carry.ready, carry.busy_lat,
-                                      carry.model_round, carry.t,
-                                      rcfg.delta_t)
-        if streams.scenario is None:
-            avail = jnp.ones((k_local,), bool)
-            upl_k = depart_k = ready
-        else:
-            avail, drop = streams.scenario(carry.t)
-            upl_k = ready & avail & ~drop
-            depart_k = ready & avail
-
-        # slot view of the (K,) state: gather by occupant, mask dead slots
-        b = (live & upl_k[occ]).astype(jnp.float32)
-        stal = jnp.where(live, stal_k[occ], 0).astype(jnp.float32)
-
-    # 2-4. identical per-row stages over the m cohort rows (sweep 1: fused
-    # stats; P2 water-filling; constraint (7) under the gathered channel).
-    # Compressed payloads (rcfg.compress, a trace-time branch — off emits
-    # the PR 7 program op for op): the stats sweep runs on the (m, s)
-    # compressed rows + EF residuals; at the static s == d identity the
-    # dense formulations route unchanged (bit-identity with compress off).
-    with jax.named_scope("paota.stats"):
-        payload = carry.deltas if rcfg.transmit_delta else carry.pending
-        if rcfg.compress:
-            d_model = carry.global_vec.shape[0]
-            identity = rcfg.compress_s >= d_model
-            # identity support + int8: the dense stages need the
-            # dequantized rows (f32/bf16 identity rows pass through
-            # untouched — the bit-identity claim is about THOSE)
-            v_id = (carry.deltas if carry.slot_scale is None
-                    else dequantize_int8(carry.deltas, carry.slot_scale))
-            if identity:
-                rho, theta, w_norm2 = round_factors(
-                    v_id, None, carry.global_vec, carry.prev_global,
-                    stal, rcfg.omega)
-            else:
-                rho, theta, w_norm2 = compressed_round_factors(
-                    carry.deltas, carry.slot_idx, carry.slot_resid,
-                    carry.slot_resid_idx, carry.global_vec,
-                    carry.prev_global, stal, rcfg.omega,
-                    scale=carry.slot_scale)
-        else:
-            rho, theta, w_norm2 = round_factors(
-                carry.deltas, None if rcfg.transmit_delta else carry.pending,
-                carry.global_vec, carry.prev_global, stal, rcfg.omega)
-
-        # 2b. containment over the cohort slots (same contract as the dense
-        # step's: corrupt/fenced rows leave the superposition as exact
-        # zeros — the phantom-slot masking — and the per-row scalars are
-        # sanitized before water-filling; trace-time branch, screen=False
-        # is the unscreened program op for op). Compressed slots zero both
-        # the value rows and the dequantization scales, so an int8 slot
-        # with a NaN absmax scale contributes 0 * 0, never 0 * NaN.
-        n_screened = jnp.float32(0.0)
-        vals_s, scale_s = carry.deltas, carry.slot_scale
-        if rcfg.screen:
-            ok, theta, w_norm2 = _screen_ok(theta, w_norm2, rcfg)
-            n_screened = ksum(b * (~ok).astype(jnp.float32))
-            b = b * ok.astype(jnp.float32)
-            if rcfg.compress:
-                vals_s = _zero_rows(vals_s, ok)
-                if scale_s is not None:
-                    scale_s = jnp.where(ok, scale_s, 0.0)
-                v_id = _zero_rows(v_id, ok)
-            else:
-                payload = _zero_rows(payload, ok)
-    with jax.named_scope("paota.power"):
-        p_max = jnp.full((m,), rcfg.p_max_watts, jnp.float32)
-        # P2 is solved over the slots in client-id order. Its objective is
-        # flat near the optimum (cells a float ulp apart), so the K-sums'
-        # association order picks beta; in slot order that order followed
-        # the refill history and the host's SIMD width, not the client set.
-        order = jnp.argsort(jnp.where(live, occ, k_local))
-        beta_o, p2_obj = waterfill_beta_jnp(rho[order], theta[order], p_max,
-                                            b[order], rcfg.c1, rcfg.c0,
-                                            axis_name=axis_name)
-        beta = jnp.zeros_like(beta_o).at[order].set(beta_o)
-        powers = power_from_beta(beta, rho, theta, p_max)
-        h = jnp.where(live, streams.channel(carry.t)[occ], 0.0)
-        powers = constraint7_powers(powers, payload, h, rcfg.p_max_watts,
-                                    w_norm2=w_norm2)
-
-    # 5+6. AirComp over the cohort rows (sweep 2) + the guarded update —
-    # an all-masked cohort degenerates to the zero-uploader hold exactly
-    # like the dense path (varsigma below the guard threshold). Compressed:
-    # the gather-superpose kernel decompresses INTO the superposition
-    # (eq. 8 in d-space) before the global update — the stored int8 plane
-    # feeds it directly with its scale folded into the weights.
-    with jax.named_scope("paota.superpose"):
-        if rcfg.compress and not identity:
-            agg, varsigma = paota_aggregate_compressed(
-                vals_s, carry.slot_idx, powers, b,
-                streams.noise_key(carry.t), rcfg.sigma_n, d_model,
-                scale=scale_s, axis_name=axis_name)
-        else:
-            agg, varsigma = paota_aggregate_stacked(
-                v_id if rcfg.compress else payload, powers, b,
-                streams.noise_key(carry.t), rcfg.sigma_n,
-                axis_name=axis_name)
-        new_global, new_prev = guarded_global_update(
-            carry.global_vec, carry.prev_global, agg, varsigma,
-            delta=rcfg.transmit_delta)
-
-        # 6b. divergence rollback (trace-time branch) — before the
-        # broadcast, so a rolled-back round reschedules/trains from the
-        # restored model
-        good, good_n2 = carry.good_global, carry.good_norm2
-        rolled = jnp.float32(0.0)
-        if rcfg.divergence_factor > 0.0:
-            new_global, new_prev, good, good_n2, rolled = \
-                _divergence_rollback(new_global, new_prev, carry, rcfg)
-
-    with jax.named_scope("paota.schedule"):
-        # 7a. slot turnover: departing occupants (uploaded, or upload
-        # dropped in transit) free their slots; available idle clients fill
-        # them in priority order. `in_flight` scatters the retained
-        # occupancy back to (K,); dead slots contribute nothing anywhere
-        # (live = False).
-        depart = live & depart_k[occ]
-        stay = live & ~depart
-        in_flight = jnp.zeros((k_local,), bool).at[occ].max(stay,
-                                                            mode="drop")
-        prio = streams.sched_priority(carry.t)
-        score = jnp.where(avail & ~in_flight, prio, -jnp.inf)
-        top_score, top_ids = jax.lax.top_k(score, m)
-        n_cand = jnp.sum((top_score > -jnp.inf).astype(jnp.int32))
-        free = ~stay
-        free_rank = jnp.cumsum(free.astype(jnp.int32)) - 1
-        take = free & (free_rank < n_cand)
-        new_occ = jnp.where(take, top_ids[jnp.clip(free_rank, 0, m - 1)],
-                            occ).astype(jnp.int32)
-        new_live = stay | take
-
-        # 7b. (K,) plane bookkeeping: departed-but-unscheduled clients go
-        # idle (busy_lat = +inf — never ready again until rescheduled),
-        # scheduled clients get the fresh broadcast via the SAME
-        # sched_broadcast masked update the dense path uses
-        sched_k = jnp.zeros((k_local,), bool).at[new_occ].max(take,
-                                                              mode="drop")
-        t_next = carry.t + 1
-        lat_full = streams.latencies(t_next)
-        departed_k = jnp.zeros((k_local,), bool).at[occ].max(depart,
-                                                             mode="drop")
-        idle = departed_k & ~sched_k
-        ready = jnp.where(idle, False, ready)
-        busy = jnp.where(idle, jnp.asarray(jnp.inf, carry.busy_lat.dtype),
-                         carry.busy_lat)
-        n_ready, n_lat, n_model = sched_broadcast(
-            ready, busy, carry.model_round, sched_k, lat_full, t_next)
-
-        # EF residual hand-off on slot turnover (trace-time branch): FIRST
-        # every departing slot parks its residual on the owning client's
-        # (K, s) row (the scatter half of the tentpole's "(K, s) residual
-        # row"), THEN the newly scheduled occupants pick their parked rows
-        # back up (a same-round depart -> reschedule resumes the residual
-        # it just parked), THEN the consumed rows zero — the parked plane
-        # only ever holds errors nobody is currently training against.
-        resid_val = resid_idx = pr_val = pr_idx = None
-        if rcfg.compress and rcfg.error_feedback:
-            park_row = jnp.where(depart, occ, k_local)      # OOB = no write
-            resid_val = carry.resid_val.at[park_row].set(carry.slot_resid,
-                                                         mode="drop")
-            resid_idx = carry.resid_idx.at[park_row].set(
-                carry.slot_resid_idx, mode="drop")
-            pr_val = jnp.where(take[:, None], resid_val[new_occ], 0.0)
-            if rcfg.screen:
-                # a screened slot's parked residual may be the corrupt
-                # row's NaN complement — resuming it would re-poison every
-                # later round of an otherwise-recovered client
-                pr_val = jnp.where(jnp.isfinite(pr_val), pr_val, 0.0)
-            pr_idx = resid_idx[new_occ]
-            consumed = jnp.where(take, new_occ, k_local)
-            resid_val = resid_val.at[consumed].set(0.0, mode="drop")
-
-    # 7c. cohort training: ONLY the m slot rows materialize model-sized
-    # work — the newly scheduled slots take their trained rows (f32 delta
-    # before the storage cast, same rules as the dense path); retained
-    # slots keep their in-flight payload; dead slots keep masked garbage
-    with jax.named_scope("paota.train"):
-        trained = streams.cohort_train(new_global, x, y, t_next, new_occ)
-    dtype = _storage_dtype(rcfg)
-
-    def row_select(new, old):
-        msk = take.reshape((m,) + (1,) * (new.ndim - 1))
-        return jnp.where(msk, new, old)
-
-    if rcfg.compress:
-        with jax.named_scope("paota.compress"):
-            # compressed store: the f32 delta rows are EF-compensated with
-            # the resumed parked residuals (decompressed transiently — the
-            # carry never holds an (m, d) plane), then support-selected,
-            # stored, and their exact f32 residual re-sparsified.
-            # Raveled single-leaf: `trained` is a bare (m, d) array.
-            comp = trained - new_global[None]
-            if pr_val is not None:
-                comp = comp + scatter_rows(pr_val, pr_idx, d_model)
-            stored, idx_new, scale_new, e_val, e_idx = _compress_plane(
-                comp, rcfg=rcfg, streams=streams, t=t_next)
-
-    with jax.named_scope("paota.carry_write"):
-        if rcfg.compress:
-            # non-take rows keep every old slot plane (garbage residual
-            # gathers for them are discarded here)
-            pending = None
-            deltas = row_select(stored, carry.deltas)
-            slot_idx = row_select(idx_new, carry.slot_idx)
-            slot_scale = (None if scale_new is None
-                          else jnp.where(take, scale_new, carry.slot_scale))
-            slot_resid = (None if e_val is None
-                          else row_select(e_val, carry.slot_resid))
-            slot_resid_idx = (None if e_idx is None
-                              else row_select(e_idx, carry.slot_resid_idx))
-        else:
-            pending, deltas = _carry_write(trained, new_global, carry,
-                                           row_select, dtype)
-            slot_idx = slot_scale = slot_resid = slot_resid_idx = None
-
-    n_upl = ksum(b)
-    denom = jnp.maximum(n_upl, 1.0)
-    out = {
-        "n_participants": n_upl,
-        "time": time,
-        "mean_staleness": ksum(stal * b) / denom,
-        "beta_mean": ksum(beta * b) / denom,
-        "varsigma": jnp.where(varsigma > VARSIGMA_MIN, varsigma, 0.0),
-        "p2_objective": jnp.where(n_upl > 0, p2_obj, jnp.inf),
-        "n_screened": n_screened,
-        "rolled_back": rolled,
-    }
-    carry = RoundCarry(t=t_next, time=time, ready=n_ready,
-                       busy_lat=n_lat, model_round=n_model,
-                       global_vec=new_global, prev_global=new_prev,
-                       pending=pending, deltas=deltas, held=None,
-                       slot_client=new_occ, slot_live=new_live,
-                       slot_idx=slot_idx, slot_scale=slot_scale,
-                       slot_resid=slot_resid,
-                       slot_resid_idx=slot_resid_idx,
-                       resid_val=resid_val, resid_idx=resid_idx,
-                       good_global=good, good_norm2=good_n2)
+    carry = carry._replace(t=t_next, time=time, ready=n_ready,
+                           busy_lat=n_lat, model_round=n_model,
+                           global_vec=new_global, prev_global=new_prev,
+                           held=held, good_global=good, good_norm2=good_n2,
+                           **fields)
     return carry, out
 
 
 def init_round_carry(vec, x, y, *, streams: RoundStreams,
                      pending_dtype: str = "float32",
                      keep_pending: bool = True,
-                     rcfg: RoundCfg | None = None) -> RoundCarry:
-    """Round-0 kick-off: broadcast w_g^0 to everyone and precompute their
-    local training (mirrors ``PAOTAServer.__init__``). ``vec`` is the
-    params pytree (raveled = single (d,) leaf); shapes follow the streams'
-    view of the federation (all K single-device; K/n per shard). The f32
-    delta (``trained - w_g^0``) is formed before the optional storage
-    cast. ``keep_pending=False`` (transmit='delta') carries the delta
-    plane only. ``rcfg`` (only its divergence knob is read) seeds the
-    last-good rollback slot from w_g^0 when the detector is on."""
-    trained = streams.local_train(vec, x, y, 0)
-    k_local = jax.tree_util.tree_leaves(trained)[0].shape[0]
+                     rcfg: RoundCfg | None = None, m: int = 0,
+                     n_real=None) -> RoundCarry:
+    """Round-0 kick-off: broadcast w_g^0 and precompute the local training
+    (mirrors ``PAOTAServer.__init__``). ``vec`` is the params pytree
+    (raveled = single (d,) leaf); shapes follow the streams' view of the
+    federation (all K single-device; K/n per shard). The f32 delta
+    (``trained - w_g^0``) is formed before the optional storage cast.
+    ``keep_pending=False`` (transmit='delta') carries the delta plane
+    only.
+
+    ``m`` >= 1 builds the active-cohort carry: the first
+    ``min(m, n_real)`` clients (in id order) fill the m slots and receive
+    the broadcast; everyone else idles at ``busy_lat = +inf`` until a
+    slot frees. ``m`` is this shard's slot count under sharding, and
+    ``n_real`` (static or traced; default all) caps the live slots below
+    the phantom padding. At m = K with no phantoms this is the dense
+    carry plus the identity slot map.
+
+    ``rcfg`` seeds the last-good rollback slot from w_g^0 when the
+    divergence detector is on, and on compressed slots runs the round-0
+    deltas through the scan's ``_compress_plane`` stage, with zeroed
+    (K, s) parked-residual planes under error feedback."""
+    busy, slots = None, {}
+    if m:
+        lat = streams.latencies(0)
+        k = lat.shape[0]
+        occ = jnp.arange(m, dtype=jnp.int32)
+        n_real = k if n_real is None else n_real
+        live = occ < jnp.minimum(jnp.asarray(m, jnp.int32),
+                                 jnp.asarray(n_real, jnp.int32))
+        sched_k = jnp.zeros((k,), bool).at[occ].max(live, mode="drop")
+        busy = jnp.where(sched_k, lat, jnp.asarray(jnp.inf, lat.dtype))
+        trained = streams.cohort_train(vec, x, y, 0, occ)
+        slots.update(slot_client=occ, slot_live=live)
+    else:
+        trained = streams.local_train(vec, x, y, 0)
+        k = jax.tree_util.tree_leaves(trained)[0].shape[0]
     dtype = jnp.dtype(pending_dtype)
     diverg = bool(rcfg is not None and rcfg.divergence_factor > 0.0)
-    return RoundCarry(
-        t=jnp.int32(0),
-        time=jnp.float32(0.0),
-        ready=jnp.zeros((k_local,), bool),
-        busy_lat=streams.latencies(0),
-        model_round=jnp.zeros((k_local,), jnp.int32),
-        global_vec=vec,
-        prev_global=vec,
-        pending=_cast_rows(trained, dtype) if keep_pending else None,
-        deltas=jax.tree_util.tree_map(
-            lambda tr, g: (tr - g[None]).astype(dtype), trained, vec),
-        good_global=vec if diverg else None,
-        good_norm2=_tree_sq_norm(vec) if diverg else None,
-    )
-
-
-def init_cohort_carry(vec, x, y, *, streams: RoundStreams, k: int, m: int,
-                      n_real=None, pending_dtype: str = "float32",
-                      keep_pending: bool = True,
-                      rcfg: RoundCfg | None = None) -> RoundCarry:
-    """Round-0 kick-off of the active-cohort carry: the first
-    ``min(m, n_real)`` clients (in id order) fill the slots and receive
-    the broadcast; everyone else idles at ``busy_lat = +inf`` until a slot
-    frees. ``k``/``m`` are this shard's local extents under sharding;
-    ``n_real`` (static or traced) caps the live slots below the phantom
-    padding — phantom rows must never occupy a live slot. At m = K with
-    no phantoms this is exactly ``init_round_carry`` plus the identity
-    slot map, which is what makes cohort_size=K allclose to the dense
-    path from round 0.
-
-    ``rcfg`` (only its compression knobs are read) switches the payload
-    plane to the compressed (m, s) form: the round-0 deltas run through
-    the same ``_compress_plane`` stage the scan uses, with empty (K, s)
-    parked-residual planes when error feedback is on."""
-    if m > k:
-        raise ValueError(f"cohort_size={m} exceeds the client-plane extent "
-                         f"{k}")
-    occ = jnp.arange(m, dtype=jnp.int32)
-    n_real = k if n_real is None else n_real
-    live = occ < jnp.minimum(jnp.asarray(m, jnp.int32),
-                             jnp.asarray(n_real, jnp.int32))
-    sched_k = jnp.zeros((k,), bool).at[occ].max(live, mode="drop")
-    lat_full = streams.latencies(0)
-    busy = jnp.where(sched_k, lat_full,
-                     jnp.asarray(jnp.inf, lat_full.dtype))
-    trained = streams.cohort_train(vec, x, y, 0, occ)
-    dtype = jnp.dtype(pending_dtype)
-    compress = bool(rcfg is not None and rcfg.compress)
-    diverg = bool(rcfg is not None and rcfg.divergence_factor > 0.0)
-    good = vec if diverg else None
-    good_n2 = _tree_sq_norm(vec) if diverg else None
-    if compress:
+    planes = None
+    if rcfg is not None and rcfg.compress:
         # compressed payloads ride transmit='delta' (driver-enforced);
         # raveled single-leaf, so `trained` is a bare (m, d) array
-        stored, idx, scale, e_val, e_idx = _compress_plane(
-            trained - vec[None], rcfg=rcfg, streams=streams, t=0)
-        s = stored.shape[1]
-        ef = rcfg.error_feedback
-        return RoundCarry(
-            t=jnp.int32(0),
-            time=jnp.float32(0.0),
-            ready=jnp.zeros((k,), bool),
-            busy_lat=busy,
-            model_round=jnp.zeros((k,), jnp.int32),
-            global_vec=vec,
-            prev_global=vec,
-            pending=None,
-            deltas=stored,
-            slot_client=occ,
-            slot_live=live,
-            slot_idx=idx,
-            slot_scale=scale,
-            slot_resid=e_val,
-            slot_resid_idx=e_idx,
-            resid_val=jnp.zeros((k, s), jnp.float32) if ef else None,
-            resid_idx=jnp.zeros((k, s), jnp.int32) if ef else None,
-            good_global=good,
-            good_norm2=good_n2,
-        )
-    return RoundCarry(
+        planes = _compress_plane(trained - vec[None], rcfg=rcfg,
+                                 streams=streams, t=0)
+    carry = RoundCarry(
         t=jnp.int32(0),
         time=jnp.float32(0.0),
         ready=jnp.zeros((k,), bool),
-        busy_lat=busy,
+        busy_lat=streams.latencies(0) if busy is None else busy,
         model_round=jnp.zeros((k,), jnp.int32),
         global_vec=vec,
         prev_global=vec,
-        pending=_cast_rows(trained, dtype) if keep_pending else None,
-        deltas=jax.tree_util.tree_map(
-            lambda tr, g: (tr - g[None]).astype(dtype), trained, vec),
-        slot_client=occ,
-        slot_live=live,
-        good_global=good,
-        good_norm2=good_n2,
-    )
+        pending=(_cast_rows(trained, dtype)
+                 if keep_pending and planes is None else None),
+        deltas=(jax.tree_util.tree_map(
+            lambda tr, g: (tr - g[None]).astype(dtype), trained, vec)
+            if planes is None else None),
+        good_global=vec if diverg else None,
+        good_norm2=_tree_sq_norm(vec) if diverg else None,
+        **slots)
+    if planes is not None:
+        carry = carry._replace(**planes._asdict())
+        if rcfg.error_feedback:
+            s = planes.deltas.shape[1]
+            carry = carry._replace(resid_val=jnp.zeros((k, s), jnp.float32),
+                                   resid_idx=jnp.zeros((k, s), jnp.int32))
+    return carry
 
 
 def scan_rounds(carry: RoundCarry, x, y, n_rounds: int, *, rcfg: RoundCfg,

@@ -111,7 +111,7 @@ from repro.core.scheduler import (TAG_CHANNEL, TAG_COMPRESS, TAG_NOISE,
                                   scenario_latencies, scenario_masks)
 from repro.fl.fused import FusedPAOTA
 from repro.fl.runtime import (GroupTopology, RoundCarry, RoundStreams,
-                              init_cohort_carry, scan_rounds, scan_windows)
+                              init_round_carry, scan_rounds, scan_windows)
 from repro.fl.server import PAOTAConfig
 from repro.launch.mesh import data_axes
 from repro.sharding.rules import batch_specs, stack_client_specs
@@ -686,12 +686,11 @@ class ShardedPAOTA(FusedPAOTA):
                 offset = self._shard_offset()
                 n_real = jnp.clip(jnp.int32(self.k) - offset, 0,
                                   self.k_local)
-                return init_cohort_carry(
+                return init_round_carry(
                     v, xs, ys, streams=self._shard_streams(offset),
-                    k=self.k_local, m=self.m_local, n_real=n_real,
                     pending_dtype=self._rcfg.pending_dtype,
                     keep_pending=not self._rcfg.transmit_delta,
-                    rcfg=self._rcfg)
+                    rcfg=self._rcfg, m=self.m_local, n_real=n_real)
 
             smap = jax.shard_map(body, mesh=self.mesh,
                                  in_specs=(glob_spec, self._x_spec,

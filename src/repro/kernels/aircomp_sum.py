@@ -21,9 +21,9 @@ raveled ``aircomp_sum`` (``repro.kernels.ops``) is the same kernel with
 the mask all ones.
 
 The leading (client) axis is whatever plane the round carries: all K
-clients on the dense path, or the (m, d) active-cohort slot rows under
-``RoundCfg.cohort_size`` — dead/masked slots superpose with b*p = 0, so
-the same kernel serves both layouts unchanged.
+clients on the dense path, or the (m, d) active-cohort slot rows of a
+cohort carry (``RoundCarry.slot_client`` set) — dead/masked slots
+superpose with b*p = 0, so the same kernel serves both layouts unchanged.
 """
 from __future__ import annotations
 

@@ -40,10 +40,10 @@ computation is lowered for;
 ``repro.kernels.ref.round_stats_ref`` is the allclose oracle.
 
 The leading axis is whatever client plane the round carries: the dense
-(K, d) delta stack, or — in active-cohort mode (``RoundCfg.cohort_size``)
-— the (m, d) cohort slot rows, m = |in-flight cohort| << K. The kernel is
-shape-agnostic there; masked slot rows arrive with ``stal = 0`` exactly
-like the sharded drivers' phantom clients.
+(K, d) delta stack, or — on a cohort carry (``RoundCarry.slot_client``
+set) — the (m, d) cohort slot rows, m = |in-flight cohort| << K. The
+kernel is shape-agnostic there; masked slot rows arrive with ``stal = 0``
+exactly like the sharded drivers' phantom clients.
 """
 from __future__ import annotations
 

@@ -28,9 +28,11 @@ reductions then become TP-aware:
     branches vanish at trace time when no topology is passed).
 
 ``TPTopology`` is a static (hashable) description threaded through
-``paota_round_step`` exactly like the grouped ``GroupTopology``; the
-sharded driver derives ``leaf_dims`` from the computed pend_spec tree so
-slicing and GSPMD placement can never disagree.
+``paota_round_step`` exactly like the grouped ``GroupTopology``, and like
+it reaches dense payload planes only (the sharded driver refuses a TP
+mesh with a cohort); the sharded driver derives ``leaf_dims`` from the
+computed pend_spec tree so slicing and GSPMD placement can never
+disagree.
 """
 from __future__ import annotations
 

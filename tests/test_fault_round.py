@@ -135,9 +135,16 @@ def test_screened_faulty_round_equals_dropped_uploads(data):
 # NaN storm: unscreened stalls (finite guard), screened converges
 # ---------------------------------------------------------------------------
 
-def test_nan_storm_unscreened_stalls_screened_progresses(data):
+# the shared screening and rollback stages, through the dense client plane
+# and a cohort's slot plane (cohort_size < K)
+PLANES = pytest.mark.parametrize("plane", [{}, {"cohort_size": K // 2}],
+                                 ids=["dense", "cohort"])
+
+
+@PLANES
+def test_nan_storm_unscreened_stalls_screened_progresses(data, plane):
     storm = FaultConfig(nan_frac=0.9, start=1)
-    unscr = _fused(data, faults=storm)
+    unscr = _fused(data, faults=storm, **plane)
     unscr.advance(1)                      # round 0: faults not yet active
     g1 = np.array(unscr.global_vec, copy=True)
     unscr.advance(4)
@@ -146,7 +153,7 @@ def test_nan_storm_unscreened_stalls_screened_progresses(data):
     np.testing.assert_array_equal(unscr.global_vec, g1)
     assert np.isfinite(unscr.global_vec).all()
 
-    scr = _fused(data, faults=storm, screen=True)
+    scr = _fused(data, faults=storm, screen=True, **plane)
     scr.advance(1)
     s1 = np.array(scr.global_vec, copy=True)
     scr.advance(4)
@@ -192,27 +199,31 @@ def test_byzantine_unscreened_corrupts_fence_contains(data):
     assert sum(r["n_screened"] for r in fence.history) > 0
 
 
-def test_rollback_restores_last_good_on_divergence(data):
+@PLANES
+def test_rollback_restores_last_good_on_divergence(data, plane):
     """The second line of defense: with screening off, a one-round payload
     blowup (every round-3 local model scaled 100x — past what the power
     cap can attenuate, since EVERY uploader carries it) jumps ||w_g|| by
     orders of magnitude. Unguarded, w_g stays corrupted; with
     divergence_factor the detector fires exactly once, restores the
     last-good global, and the trajectory recovers."""
-    def make(**kw):
-        srv = _fused(data, transmit="model", **kw)
-        base = srv._streams()
-
-        def blowup_train(g, x, y, r):
-            tr = base.local_train(g, x, y, r)
+    def blowup(train):
+        def blowup_train(g, x, y, r, *ids):
+            tr = train(g, x, y, r, *ids)
             s = jnp.where(jnp.asarray(r) == 3, jnp.float32(100.0),
                           jnp.float32(1.0))
             return jax.tree_util.tree_map(lambda l: l * s, tr)
+        return blowup_train
 
-        srv._streams = lambda: base._replace(local_train=blowup_train)
+    def make(**kw):
+        srv = _fused(data, transmit="model", **plane, **kw)
+        base = srv._streams()
+        cohort_train = base.cohort_train and blowup(base.cohort_train)
+        srv._streams = lambda: base._replace(
+            local_train=blowup(base.local_train), cohort_train=cohort_train)
         return srv
 
-    clean = _fused(data, transmit="model")
+    clean = _fused(data, transmit="model", **plane)
     clean.advance(6)
     ref = float(np.linalg.norm(clean.global_vec))
 

@@ -31,7 +31,8 @@ LAT_LO, LAT_HI = 0.15, 0.35      # finishes land 2-4 slots out — every
 def _device_masks(seed):
     """The fused-round scheduler transition alone (sched_advance +
     sched_broadcast in a lax.scan over counter draws) — exactly what
-    ``paota_round_step`` stages 1 and 7 run."""
+    ``paota_round_step`` runs in its schedule stages on a dense carry (a
+    cohort carry adds slot refill and idling between the two)."""
     key = jax.random.PRNGKey(seed)
 
     def step(c, t):

@@ -2,7 +2,7 @@
 
 Every stage of the round runs under one of ``runtime.STAGE_SCOPES``, and
 the scope reaches the compiled scan as ``op_name`` metadata, in the dense
-and the cohort step alike. ``advance`` marks its host work with
+and the cohort carry alike. ``advance`` marks its host work with
 ``paota.advance`` around ``paota.dispatch``, ``paota.fetch`` and
 ``paota.rows`` in the profiler's trace.
 """
