@@ -36,12 +36,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.round_stats import out_struct
-from repro.kernels.tiling import (PLANE_BLOCK_BYTES, native_rows, native_view,
-                                  per_block, stripe_lanes, sublanes)
+from repro.kernels.tiling import (LANES, PLANE_BLOCK_BYTES,
+                                  SCATTER_BLOCK_BYTES, native_rows,
+                                  native_view, per_block, scatter_blocks,
+                                  stripe_lanes, sublanes)
 
 
-# d-stripe of ``gather_superpose_pallas``, the compressed cohort's kernel
-DEFAULT_BLOCK_D = 512
 # Every superposition contracts f32 weights b_k p_k at full precision. At
 # a TPU's default precision the MXU takes f32 operands as one bf16 pass,
 # which rounds the weights (and an f32 payload) to 8 mantissa bits.
@@ -362,49 +362,75 @@ def aircomp_sum_tree_psum_tp(stacked_leaves, bp: jnp.ndarray, noise_leaves,
 # plane without ever materializing the dense (m, d) payload
 # ---------------------------------------------------------------------------
 
-def _gather_superpose_kernel(vs_min, n_blocks, block_n, block_d,
-                             bp_ref, w_ref, val_ref, idx_ref, noise_ref,
-                             out_ref, vs_ref):
-    i = pl.program_id(0)                        # d stripe
-    j = pl.program_id(1)                        # flattened (m*s) block
-    raw = jnp.sum(bp_ref[...])                  # (1, m) raw b*p
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = noise_ref[...].astype(jnp.float32)
-
-    # per-element weighted payload: w already folds b*p (masked) and any
-    # int8 dequantization scale, repeated across each row's s entries —
-    # so dead slots and padding contribute exact zeros
-    a = w_ref[...] * val_ref[...].astype(jnp.float32)        # (BLOCK_N, 1)
-    cols = i * block_d + jax.lax.broadcasted_iota(
-        jnp.int32, (block_n, block_d), 1)
-    onehot = (idx_ref[...] == cols).astype(jnp.float32)      # (BLOCK_N, BLOCK_D)
-    # scatter-as-matmul: contracting the flattened-element axis of the
-    # one-hot support drops each a_e into its column of the stripe (MXU
-    # shape, f32 accumulation) — the revisited out stripe accumulates
-    # across the j blocks
-    out_ref[...] += jax.lax.dot_general(
-        a, onehot, (((0,), (0,)), ((), ())), precision=HIGHEST,
-        preferred_element_type=jnp.float32)                  # (1, BLOCK_D)
-
-    @pl.when(j == n_blocks - 1)
-    def _normalize():
-        out_ref[...] = out_ref[...] / jnp.maximum(raw, vs_min)
+def _gather_superpose_kernel(vs_min, blocks, m, s, scaled, *refs):
+    """One (hb, 128) stripe of the aggregate against one (bm, bs) block
+    of the slot plane. Each slot row's elements e, on lanes, scatter as
+    one MXU matmul (hb, bs) x (128, bs)^T: the row one-hot carries the
+    weighted value a_e = w_row * v_e where the stripe's row matches
+    idx_e // 128, the lane one-hot is 1 where the lane matches
+    idx_e % 128. Lanes past the plane's end are zeroed; rows past it are
+    never read."""
+    if scaled:
+        bp_ref, sc_ref, val_ref, idx_ref, noise_ref, out_ref, vs_ref, \
+            v32_ref = refs
+    else:
+        bp_ref, val_ref, idx_ref, noise_ref, out_ref, vs_ref, v32_ref = refs
+    t, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    hb, bs = out_ref.shape[0], val_ref.shape[1]
+    last = (i == blocks.rows.count - 1) & (j == blocks.lanes.count - 1)
 
     @pl.when((i == 0) & (j == 0))
-    def _emit_vs():
-        vs_ref[...] = raw[None, None]
+    def _init():
+        out_ref[...] = noise_ref[...]
+
+    # the block widened to f32 once (a row of a packed bf16 or int8 block
+    # cannot be loaded at a dynamic offset); lanes past s hold whatever
+    # VMEM held, indices that may match a stripe row, so they are zeroed
+    v = val_ref[...].astype(jnp.float32)
+    if blocks.lanes.tail < bs:
+        v = jnp.where(j * bs + jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+                      < s, v, 0.0)
+    v32_ref[...] = v
+    tile_row = t * hb + jax.lax.broadcasted_iota(jnp.int32, (hb, bs), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (LANES, bs), 0)
+
+    def block(valid_rows):
+        def row(r, carry):
+            k = i * blocks.rows.size + r
+            # w folds b*p (masked) and any int8 dequantization scale, so
+            # dead and masked slots contribute exact zeros
+            w = bp_ref[k] * sc_ref[k] if scaled else bp_ref[k]
+            a = w * v32_ref[pl.ds(r, 1), :]                      # (1, bs)
+            e = idx_ref[pl.ds(r, 1), :]
+            rows_oh = jnp.where(tile_row == e // LANES, a, 0.0)
+            lanes_oh = (lane == e % LANES).astype(jnp.float32)
+            out_ref[...] += jax.lax.dot_general(
+                rows_oh, lanes_oh, (((1,), (1,)), ((), ())),
+                precision=HIGHEST, preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, valid_rows, row, 0)
+
+    per_block(blocks.rows, i, block)
+
+    @pl.when(last)
+    def _normalize():
+        raw = jax.lax.fori_loop(0, m, lambda k, acc: acc + bp_ref[k],
+                                jnp.float32(0.0), unroll=m <= 8)
+        out_ref[...] = out_ref[...] / jnp.maximum(raw, vs_min)
+
+        @pl.when(t == 0)
+        def _emit_vs():
+            vs_ref[0] = raw
 
 
-@functools.partial(jax.jit, static_argnames=("d", "vs_min", "block_d",
-                                             "block_n", "interpret"))
+@functools.partial(jax.jit, static_argnames=("d", "vs_min", "block_bytes",
+                                             "interpret"))
 def gather_superpose_pallas(values: jnp.ndarray, idx: jnp.ndarray,
                             bp: jnp.ndarray, noise: jnp.ndarray, *, d: int,
                             scale: jnp.ndarray | None = None,
                             vs_min: float = 1e-12,
-                            block_d: int = DEFAULT_BLOCK_D,
-                            block_n: int = 1024,
+                            block_bytes: int = SCATTER_BLOCK_BYTES,
                             interpret: bool = False):
     """AirComp over compressed cohort rows, fused: slot gather + b*p
     masking + compressed superposition + AWGN + varsigma in one pass.
@@ -413,58 +439,49 @@ def gather_superpose_pallas(values: jnp.ndarray, idx: jnp.ndarray,
     idx: (m, s) int32 support (each row's coordinates in [0, d));
     bp: (m,) masked transmit powers b_k p_k; noise: (d,) AWGN;
     scale: optional (m,) int8 dequantization factors, folded into the
-    per-element weight so the stored int8 plane feeds the MXU directly
-    with f32 accumulation — varsigma stays the RAW sum of b*p.
+    per-row weight so the stored int8 plane is read as it is, with f32
+    products and accumulation — varsigma stays the RAW sum of b*p.
 
-    Grid: (d stripes) x (flattened m*s element blocks); each (BLOCK_N, 1)
-    element column scatters into its stripe through a one-hot
-    (BLOCK_N, BLOCK_D) contraction, initialized with the noise stripe and
-    normalized on the last block — the dense (m, d) plane never exists.
-    Returns ((d,) f32 aggregate, raw varsigma).
+    The aggregate is built as an (H, 128) tile, H = cdiv(d, 128): each
+    index splits into a row idx // 128 and a lane idx % 128, and a slot
+    row's scatter is the matmul of its row one-hot, weighted, with its
+    lane one-hot — (hb, bs) x (128, bs)^T at HIGHEST on the MXU, m*s*(hb
+    + 128) one-hot elements per stripe where a one-hot over d lanes
+    would take m*s*d. Grid: (stripes of hb aggregate rows) x (blocks of
+    slot rows) x (blocks of slot lanes), sized from ``block_bytes``
+    (``repro.kernels.tiling.scatter_blocks``); the values, indices and
+    (m,) weights are read in their own layouts. Each stripe starts from
+    its noise and is normalized on its last block — the dense (m, d)
+    plane never exists. Returns ((d,) f32 aggregate, raw varsigma).
 
     ``interpret=True`` runs the kernel body in the Pallas interpreter
     (tests on hosts without a TPU)."""
     m, s = values.shape
-    n = m * s
+    h = -(-d // LANES)
+    blocks = scatter_blocks(m, s, h, values.dtype, block_bytes)
     bp32 = bp.astype(jnp.float32)
-    w = bp32 if scale is None else bp32 * scale.astype(jnp.float32)
-    wflat = jnp.repeat(w, s).reshape(n, 1)
-    vflat = values.reshape(n, 1)
-    iflat = idx.reshape(n, 1).astype(jnp.int32)
-    pad_n = (-n) % block_n
-    if pad_n:
-        wflat = jnp.pad(wflat, ((0, pad_n), (0, 0)))
-        vflat = jnp.pad(vflat, ((0, pad_n), (0, 0)))
-        # idx pads with -1: matches no stripe column, and the zero weight
-        # kills the product anyway
-        iflat = jnp.pad(iflat, ((0, pad_n), (0, 0)), constant_values=-1)
-    noise = noise.astype(jnp.float32)
-    pad_d = (-d) % block_d
-    if pad_d:
-        noise = jnp.pad(noise, (0, pad_d))
-    np_, dp = n + pad_n, d + pad_d
-    n_blocks = np_ // block_n
-    kern = functools.partial(_gather_superpose_kernel, float(vs_min),
-                             n_blocks, block_n, block_d)
-    ops_ = (wflat, vflat, iflat, noise)
+    weights = [bp32] if scale is None else [bp32, scale.astype(jnp.float32)]
+    noise = jnp.pad(noise.astype(jnp.float32),
+                    (0, h * LANES - d)).reshape((h, LANES))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    slots = pl.BlockSpec((blocks.rows.size, blocks.lanes.size),
+                         lambda t, i, j: (i, j))
+    stripe = pl.BlockSpec((blocks.tiles.size, LANES), lambda t, i, j: (t, 0))
     agg, vs = pl.pallas_call(
-        kern,
-        grid=(dp // block_d, n_blocks),
-        in_specs=[
-            pl.BlockSpec((1, m), lambda i, j: (0, 0)),           # raw bp
-            pl.BlockSpec((block_n, 1), lambda i, j: (j, 0)),     # weights
-            pl.BlockSpec((block_n, 1), lambda i, j: (j, 0)),     # values
-            pl.BlockSpec((block_n, 1), lambda i, j: (j, 0)),     # support
-            pl.BlockSpec((1, block_d), lambda i, j: (0, i)),     # noise stripe
-        ],
-        out_specs=[pl.BlockSpec((1, block_d), lambda i, j: (0, i)),
-                   pl.BlockSpec((1, 1), lambda i, j: (0, 0))],
-        out_shape=[out_struct((1, dp), jnp.float32, *ops_),
-                   out_struct((1, 1), jnp.float32, bp32)],
+        functools.partial(_gather_superpose_kernel, float(vs_min), blocks,
+                          m, s, scale is not None),
+        grid=(blocks.tiles.count, blocks.rows.count, blocks.lanes.count),
+        in_specs=[smem] * len(weights) + [slots, slots, stripe],
+        out_specs=[stripe, smem],
+        out_shape=[out_struct((h, LANES), jnp.float32, values, idx, noise,
+                              *weights),
+                   out_struct((1,), jnp.float32, bp32)],
+        scratch_shapes=[pltpu.VMEM((blocks.rows.size, blocks.lanes.size),
+                                   jnp.float32)],
         interpret=interpret,
         name="gather_superpose_pallas",
-    )(bp32[None, :], wflat, vflat, iflat, noise[None, :])
-    return agg[0, :d], vs[0, 0]
+    )(*weights, values, idx.astype(jnp.int32), noise)
+    return agg.reshape(-1)[:d], vs[0]
 
 
 def gather_superpose_psum(values: jnp.ndarray, idx: jnp.ndarray,

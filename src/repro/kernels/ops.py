@@ -106,10 +106,11 @@ def superpose_normalize(stacked: jnp.ndarray, powers: jnp.ndarray,
 def gather_superpose(values, idx, bp, noise, *, d: int, scale=None,
                      vs_min: float = 1e-12):
     """Fused gather-superpose-decompress over the (m, s) compressed cohort
-    plane: ((d,) f32 aggregate, raw varsigma). Compiled one-hot-scatter
-    kernel on TPU; the scatter + f32 einsum twin elsewhere (the twin's
-    decompressed (m, d) rows exist only transiently inside this op — the
-    round carry never holds them). ``scale`` folds int8 dequantization
+    plane: ((d,) f32 aggregate, raw varsigma). On TPU the compiled
+    kernel scatters each slot row into an (H, 128) aggregate tile as one
+    matmul of a (row, lane) pair of one-hots; elsewhere the scatter + f32
+    einsum twin (the twin's decompressed (m, d) rows exist only
+    transiently inside this op — the round carry never holds them). ``scale`` folds int8 dequantization
     into the contraction weights; varsigma is the RAW sum of b*p."""
     def twin():
         bp32 = bp.astype(jnp.float32)

@@ -13,6 +13,10 @@ two views:
   cohort slot rows) is read in (K, block_d) lane stripes; the grid runs
   over cdiv(n, block_d).
 
+The compressed cohort's scatter (``gather_superpose_pallas``) blocks
+three axes instead (``scatter_blocks``): the (H, 128) aggregate in
+stripes of rows, and the (m, s) slot plane in blocks of rows and lanes.
+
 Block sizes come from bytes, not a constant: about ``PLANE_BLOCK_BYTES``
 per block of one (K, ...) plane, and every operand, double-buffered, in
 ``VMEM_BYTES``. A grid step costs a fixed fraction of a microsecond, so a
@@ -36,6 +40,9 @@ PLANE_BLOCK_BYTES = 2 << 20
 # every operand's block, double-buffered; Mosaic's scoped VMEM limit on a
 # v5e is 16 MiB, and the kernel bodies keep their temporaries in vregs
 VMEM_BYTES = 12 << 20
+# the one-hot operands of one slot row in ``gather_superpose_pallas``:
+# the (hb, bs) row one-hot and the (128, bs) lane one-hot, f32
+SCATTER_BLOCK_BYTES = 1 << 20
 
 
 def _round_up(x: int, m: int) -> int:
@@ -109,6 +116,42 @@ def stripe_lanes(k: int, n: int, planes, vectors,
     if n <= most:
         return Blocks(n, 1, n)
     return _split(n, most, LANES)
+
+
+@dataclass(frozen=True)
+class ScatterBlocks:
+    """How ``gather_superpose_pallas`` blocks its axes: ``tiles``, rows of
+    one (hb, 128) stripe of the (H, 128) aggregate; ``rows`` and
+    ``lanes``, one (bm, bs) block of the (m, s) slot plane."""
+    tiles: Blocks
+    rows: Blocks
+    lanes: Blocks
+
+
+def _whole_or_split(extent: int, most: int, align: int) -> Blocks:
+    return Blocks(extent, 1, extent) if extent <= most else _split(
+        extent, most, align)
+
+
+def scatter_blocks(m: int, s: int, h: int, value_dtype,
+                   onehot_bytes: int = SCATTER_BLOCK_BYTES) -> ScatterBlocks:
+    """Blocks of the compressed scatter from bytes: the lane one-hot
+    (128, bs) takes at most half of ``onehot_bytes`` and the row one-hot
+    (hb, bs) the rest, so a slot row's one-hot work stays bounded for any
+    d; the slot block's values and indices, double-buffered, and its
+    values widened to f32 take at most ``onehot_bytes`` too. Each axis is
+    whole where it fits, else split into aligned blocks (a multiple of
+    128 lanes, of 8 aggregate rows, of the values' row tile)."""
+    most = max(LANES, onehot_bytes // (2 * LANES * 4))
+    lanes = _whole_or_split(s, most - most % LANES, LANES)
+    bs = _round_up(lanes.size, LANES)
+    most = max(8, onehot_bytes // (4 * bs) - LANES)
+    tiles = _whole_or_split(h, most - most % 8, 8)
+    tile = max(sublanes(value_dtype), sublanes(jnp.int32))
+    most = max(tile, onehot_bytes // (
+        bs * (2 * (jnp.dtype(value_dtype).itemsize + 4) + 4)))
+    rows = _whole_or_split(m, most - most % tile, tile)
+    return ScatterBlocks(tiles, rows, lanes)
 
 
 def per_block(blocks: Blocks, i, body):

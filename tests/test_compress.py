@@ -37,6 +37,7 @@ from repro.fl import FLClient, FusedPAOTA, PAOTAConfig
 from repro.kernels.aircomp_sum import gather_superpose_pallas
 from repro.kernels.ref import gather_superpose_ref
 from repro.kernels.round_stats import compressed_round_stats
+from repro.kernels.tiling import SCATTER_BLOCK_BYTES
 from repro.models.mlp import init_mlp_params, mlp_loss
 
 
@@ -123,13 +124,23 @@ def test_int8_rounding_error_bounded_by_one_step():
 # gather-superpose kernel vs twin vs dense oracle
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("dtype,with_scale", [
-    ("float32", False), ("bfloat16", False), ("int8", True)])
-def test_gather_superpose_matches_reference(dtype, with_scale):
-    m, d, s = 5, 1000, 37          # d not a multiple of block_d, m*s odd
+# (m, d, s, block bytes): d and s off the 128 grid; the compressed
+# cohort cell's shape; small blocks that split the (H, 128) aggregate into
+# several stripes and the slot plane into ragged row and lane blocks
+_SMALL = (5, 1000, 37, SCATTER_BLOCK_BYTES)
+_CELL = (64, 8070, 504, SCATTER_BLOCK_BYTES)
+_STRIPES = (40, 8070, 504, 16 << 10)
+_RAGGED = (37, 1000, 300, 16 << 10)
+
+
+def _compressed_cohort(shape, dtype, with_scale):
+    """Slot rows on a top-k support that also holds coordinates 0 and
+    d - 1 and, in every slot, one shared coordinate (collisions sum)."""
+    m, d, s, _ = shape
     key = jax.random.PRNGKey(9)
     comp = _plane(11, m, d)
     idx = topk_support(comp, s)
+    idx = idx.at[:, 0].set(d // 3).at[0, 1].set(0).at[m - 1, 2].set(d - 1)
     vals = gather_rows(comp, idx)
     scale = None
     if with_scale:
@@ -138,11 +149,29 @@ def test_gather_superpose_matches_reference(dtype, with_scale):
         vals = vals.astype(jnp.dtype(dtype))
     bp = jax.random.uniform(jax.random.fold_in(key, 1), (m,), jnp.float32)
     noise = jax.random.normal(jax.random.fold_in(key, 2), (d,), jnp.float32)
+    return vals, idx, bp, noise, scale
+
+
+@pytest.mark.parametrize("dtype,with_scale,shape", [
+    pytest.param("float32", False, _SMALL, id="float32-False"),
+    pytest.param("bfloat16", False, _SMALL, id="bfloat16-False"),
+    pytest.param("int8", True, _SMALL, id="int8-True"),
+    pytest.param("int8", True, _CELL, id="int8-cell"),
+    pytest.param("float32", False, _STRIPES, id="float32-stripes"),
+    pytest.param("bfloat16", False, _STRIPES, id="bfloat16-stripes"),
+    pytest.param("int8", True, _STRIPES, id="int8-stripes"),
+    pytest.param("float32", False, _RAGGED, id="float32-ragged"),
+    pytest.param("int8", True, _RAGGED, id="int8-ragged")])
+def test_gather_superpose_matches_reference(dtype, with_scale, shape):
+    d, block_bytes = shape[1], shape[3]
+    vals, idx, bp, noise, scale = _compressed_cohort(shape, dtype,
+                                                     with_scale)
     agg_ref, vs_ref = gather_superpose_ref(vals, idx, bp, noise, d,
                                            scale=scale)
     agg_k, vs_k = gather_superpose_pallas(vals, idx, bp, noise, d=d,
-                                          scale=scale, block_d=256,
-                                          block_n=64, interpret=True)
+                                          scale=scale,
+                                          block_bytes=block_bytes,
+                                          interpret=True)
     from repro.kernels import ops
     agg_t, vs_t = ops.gather_superpose(vals, idx, bp, noise, d=d,
                                        scale=scale)
@@ -154,6 +183,21 @@ def test_gather_superpose_matches_reference(dtype, with_scale):
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(agg_t), np.asarray(agg_ref),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_gather_superpose_with_every_slot_masked_is_the_noise():
+    """bp = 0 on every slot: each stripe keeps its noise, normalized by
+    vs_min, and the raw varsigma is 0."""
+    d = _STRIPES[1]
+    vals, idx, _, noise, scale = _compressed_cohort(_STRIPES, "int8", True)
+    bp = jnp.zeros((_STRIPES[0],), jnp.float32)
+    agg, vs = gather_superpose_pallas(vals, idx, bp, noise, d=d,
+                                      scale=scale, vs_min=1e-3,
+                                      block_bytes=_STRIPES[3],
+                                      interpret=True)
+    assert float(vs) == 0.0
+    np.testing.assert_array_equal(
+        np.asarray(agg), np.asarray(noise) / np.float32(1e-3))
 
 
 def test_gather_superpose_masked_rows_do_not_contribute():

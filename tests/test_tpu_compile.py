@@ -5,7 +5,8 @@ that is described, not attached: the chip's kernel compiler (Mosaic)
 refuses here what interpret mode cannot see — blocks that do not tile,
 more VMEM than a kernel may use. Shapes are those of the paper
 federation (MLP 784-10-10-10, K=100, raveled d=8070 and its largest
-pytree leaf), the compressed int8 cohort (m=32, s=d/16), smollm-135m
+pytree leaf), the compressed int8 cohort (m=32 and 64, s=d/16, and
+m=16 at d=2^24), smollm-135m
 client leaves at K=4 in bf16 (flattened, and in their own shapes, which
 the kernels read in their own layout), and a cohort plane of m=256,
 d=16384.
@@ -51,11 +52,15 @@ LEAVES = {
     "layer_norms": (4, 30, 576),
     "final_norm": (4, 576),
 }
-# (m, d, s, value dtype, int8 scale)
+# (m, d, s, value dtype, int8 scale); the benchmark's cohort cell
+# (m=64); a cohort of d = 2^24, whose (H, 128) aggregate the kernel must
+# split into stripes to stay within VMEM
 COMPRESSED = {
     "paper_randmask16_int8": (32, 8070, 504, jnp.int8, True),
+    "paper_cohort64_randmask16_int8": (64, 8070, 504, jnp.int8, True),
     "cohort_m256_int8": (256, 16384, 1024, jnp.int8, True),
     "cohort_m256_f32": (256, 16384, 1024, F32, False),
+    "large_d_m16_int8": (16, 1 << 24, 1 << 20, jnp.int8, True),
 }
 
 
